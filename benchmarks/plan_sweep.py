@@ -199,13 +199,12 @@ def main():
     write_residuals = "--no-residuals" not in argv
 
     # the virtual multi-device CPU mesh must be pinned BEFORE the jax
-    # backend initializes (collective_bench idiom)
-    if os.environ.get("JAX_PLATFORMS", "") == "cpu":
-        from paddle_tpu.jax_compat import set_cpu_device_count
-        set_cpu_device_count(N_CPU_DEVICES)
-    from bench import backend_or_skip
-    backend_or_skip("plan_sweep", retries=2)   # exits 0 on dead backend
+    # backend initializes
     import jax
+    if os.environ.get("JAX_PLATFORMS", "") == "cpu":
+        jax.config.update("jax_num_cpu_devices", N_CPU_DEVICES)
+    from paddle_tpu.chip import enable_compile_cache
+    enable_compile_cache()
     from paddle_tpu.cost_model import (Calibration, EngineSpec,
                                        search_plan)
     from paddle_tpu.models import LlamaConfig

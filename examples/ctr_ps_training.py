@@ -20,6 +20,8 @@ def main():
     jax.config.update("jax_platforms", "cpu")  # PS demo: tables live on
     #                                            the server, not the chip
 
+    from paddle_tpu.chip import enable_compile_cache
+    enable_compile_cache()
     import paddle_tpu as paddle
     import paddle_tpu.nn as nn
     from paddle_tpu import optimizer

@@ -3,10 +3,10 @@
 
 Covers the four serving tiers end to end:
   1. paged-KV generation through LLMEngine (device-side decode loop:
-     the WHOLE generation is one compiled dispatch — BASELINE.md measured
-     30-38x over per-token dispatch on a real v5e);
-  2. int8 weight-only serving (the win arrives at 7B+, where decode is
-     weight-streaming-bound; at 350M it is ~8-15% slower — BASELINE.md);
+     the WHOLE generation is one compiled dispatch; its gain over
+     per-token dispatch on the chip: not measured on this stack);
+  2. int8 weight-only serving (expected to pay at 7B+, where decode is
+     weight-streaming-bound; on the chip: not measured on this stack);
   3. checkpoint-scale loading: a LazyGuard (meta-init) model materializes
      leaf-by-leaf straight to the serving dtype at engine construction,
      so a 7B reaches a 16 GB chip as 13.5 GB bf16 / 6.7 GB int8 without
@@ -16,7 +16,8 @@ Covers the four serving tiers end to end:
      prefill, prefix-cached prompt pages (docs/serving.md).
 
 Run anywhere (CPU smoke):  python examples/serve_llama.py [--scheduler]
-On a TPU host the same code runs unchanged on the chip.
+On a TPU host the same code runs unchanged on the chip (chip_smoke.py
+drives the 7B int8 --scheduler shape there and checks what comes out).
 
 ref journey: Paddle's inference deployment (AnalysisPredictor +
 fused_multi_transformer serving); the paged-KV engine is this
@@ -284,6 +285,8 @@ def main():
                          "all-greedy block (needs --temperature)")
     args = ap.parse_args()
 
+    from paddle_tpu.chip import enable_compile_cache
+    enable_compile_cache()
     import paddle_tpu as paddle
     from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
     from paddle_tpu.inference.serving import LLMEngine

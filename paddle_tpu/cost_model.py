@@ -294,7 +294,8 @@ _NOMINAL = {
     "tpu": dict(peak_flops=197e12, hbm_gbps=819.0, hbm_cap_gb=16.0,
                 coll_gbps=45.0, coll_lat_ms=0.004, host_block_ms=0.35,
                 mfu=0.45),
-    # CPU: bench.py's nominal 1 TF peak; hbm = typical measured memcpy;
+    # CPU: a nominal 1 TF peak (ranks plans on the virtual mesh, not a
+    # measurement); hbm = typical measured memcpy;
     # cap generous (host RAM) so CPU searches are not memory-pruned
     "cpu": dict(peak_flops=1e12, hbm_gbps=12.0, hbm_cap_gb=64.0,
                 coll_gbps=2.0, coll_lat_ms=0.08, host_block_ms=3.0,

@@ -234,7 +234,7 @@ class Engine:
         once-annotated loss program is completed, planned against the
         cluster bandwidth table, partitioned onto the mesh with explicit
         reshard chains, and compiled as one shard_map step."""
-        from ...jax_compat import shard_map
+        from jax import shard_map
         from .partitioner import Partitioner, _axes
 
         if self._process_mesh is None:

@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ...jax_compat import axis_size as _axis_size
+from jax.lax import axis_size as _axis_size
 
 PARTIAL = "__partial__"  # pseudo entry: spec[0] may carry ("partial", axis)
 

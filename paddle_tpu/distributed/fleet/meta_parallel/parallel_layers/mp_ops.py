@@ -18,7 +18,7 @@ from jax import lax
 from .....ops import apply
 from .....tensor.tensor import Tensor
 from ....mesh import in_spmd_region
-from .....jax_compat import axis_size as _axis_size
+from jax.lax import axis_size as _axis_size
 
 
 @functools.lru_cache(maxsize=None)
@@ -47,7 +47,10 @@ def _allreduce_fn(axis):
         return lax.psum(x, axis), None
 
     def bwd(_, g):
-        return (g,)
+        # the primal is per-shard (varying over `axis`); the psum's
+        # cotangent arrives invariant, and shard_map(check_vma=True)
+        # type-checks the two — same values, cast to varying
+        return (lax.pcast(g, axis, to="varying"),)
 
     f.defvjp(fwd, bwd)
     return f

@@ -19,7 +19,7 @@ from jax import lax
 from .....ops import apply
 from .....tensor.tensor import Tensor
 from ....mesh import in_spmd_region
-from .....jax_compat import axis_size as _axis_size
+from jax.lax import axis_size as _axis_size
 
 NEG_INF = -1e30
 

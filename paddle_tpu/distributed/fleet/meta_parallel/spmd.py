@@ -16,7 +16,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from ....jax_compat import shard_map
+from jax import shard_map
 
 from ....autograd import tape
 from ....framework import random as frnd

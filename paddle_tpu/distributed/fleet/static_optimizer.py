@@ -81,7 +81,7 @@ def run_train_step(exe, prog, feed, fetch_ids, fetch_slots):
     static.Executor.run when prog._train is set)."""
     from ...static.distributed_passes import build_train_callable
     from ..mesh import global_mesh, spmd_axes
-    from ...jax_compat import shard_map
+    from jax import shard_map
 
     info = prog._train
     opt = info["optimizer"]

@@ -23,7 +23,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ....ops import apply
-from ....jax_compat import axis_size as _axis_size
+from jax.lax import axis_size as _axis_size
 from ...mesh import in_spmd_region
 
 

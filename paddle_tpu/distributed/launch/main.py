@@ -7,8 +7,10 @@ collective.py:184).
 
 TPU-native shape: one process per HOST (a single controller drives all
 local chips — unlike the reference's one-proc-per-GPU), rendezvous via
-jax.distributed (coordinator = rank-0 host). `--nproc_per_node` is honored
-for CPU-backend tests. Production pieces:
+jax.distributed (coordinator = rank-0 host). With `--nproc_per_node` > 1
+every worker is bound to ONE local chip before it imports jax
+(chip.chip_env; `--devices` picks which), because a chip belongs to one
+process; the launcher itself never touches the backend. Production pieces:
   - multi-node: rank-0 hosts an HTTP master (launch/master.py); every node
     syncs its endpoint list through it before spawning workers
     (ref: _build_pod_with_master, collective.py:96);
@@ -24,6 +26,8 @@ import socket
 import subprocess
 import sys
 import time
+
+from ...chip import chip_env, enable_compile_cache
 
 
 def _parse():
@@ -108,6 +112,14 @@ def _sync_nodes(args):
 
 
 def _build_containers(args, nproc, world, master_ep):
+    # one chip per worker when a node runs several: --devices names the
+    # local chip ids (in order), else worker i takes chip i
+    chips = [int(c) for c in args.devices.split(",")] if args.devices \
+        else list(range(nproc))
+    if len(chips) < nproc:
+        print(f"[launch] --devices names {len(chips)} chips for "
+              f"--nproc_per_node {nproc}", file=sys.stderr)
+        sys.exit(2)
     containers = []
     for local_rank in range(nproc):
         rank = args.rank * nproc + local_rank
@@ -122,6 +134,8 @@ def _build_containers(args, nproc, world, master_ep):
         }
         if args.devices:
             env["FLAGS_selected_tpus"] = args.devices
+        if nproc > 1:
+            env.update(chip_env(chips[local_rank]))
         cmd = [sys.executable, args.script] + args.script_args
         log_path = os.path.join(args.log_dir, f"workerlog.{rank}")
         containers.append(Container(cmd, env, log_path))
@@ -130,6 +144,7 @@ def _build_containers(args, nproc, world, master_ep):
 
 def launch():
     args = _parse()
+    enable_compile_cache()      # exported: every worker shares one cache
     nproc = args.nproc_per_node
     world = args.nnodes * nproc
 

@@ -12,10 +12,11 @@ server-side SGD/Adagrad/Adam rules (ref: ps/table/sparse_sgd_rule.h).
 """
 import ctypes
 import os
-import subprocess
 import threading
 
 import numpy as np
+
+from ...utils.cpp_extension import build_if_stale
 
 _LIB = None
 _BUILD_LOCK = threading.Lock()
@@ -34,13 +35,7 @@ def _lib():
             os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
         src = os.path.join(here, "csrc", "ps_service.cc")
         so = os.path.join(here, "csrc", "libps.so")
-        if (not os.path.exists(so)
-                or os.path.getmtime(so) < os.path.getmtime(src)):
-            subprocess.run(
-                ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-o", so,
-                 src, "-lpthread"],
-                check=True, capture_output=True)
-        lib = ctypes.CDLL(so)
+        lib = ctypes.CDLL(build_if_stale(src, so))
         u64p = ctypes.POINTER(ctypes.c_uint64)
         f32p = ctypes.POINTER(ctypes.c_float)
         lib.ps_server_start.restype = ctypes.c_void_p

@@ -1,12 +1,19 @@
 """paddle.distributed.spawn (ref: python/paddle/distributed/spawn.py:472).
 
-Single-controller JAX note: one process drives all local TPU chips, so the
-common reason to spawn (1 proc/GPU) doesn't apply. Multi-host jobs use the
-launcher (paddle_tpu.distributed.launch). spawn is kept for CPU-process
-tests and API parity.
+Single-controller JAX note: one process can drive all local TPU chips, so
+the common reason to spawn (1 proc/GPU) doesn't apply to training.
+Multi-host jobs use the launcher (paddle_tpu.distributed.launch). spawn
+serves one-engine-per-chip replica fleets, CPU-process tests and API
+parity.
+
+A chip belongs to one process: with more than one child, child `rank` is
+bound to local chip `rank` (chip.chip_env, set before the child touches
+jax), and the parent must itself stay off the jax backend.
 """
 import multiprocessing
 import os
+
+from ..chip import chip_env
 
 
 def spawn(func, args=(), nprocs=-1, join=True, daemon=False, **options):
@@ -17,6 +24,8 @@ def spawn(func, args=(), nprocs=-1, join=True, daemon=False, **options):
     for rank in range(nprocs):
         env = {"PADDLE_TRAINER_ID": str(rank),
                "PADDLE_TRAINERS_NUM": str(nprocs)}
+        if nprocs > 1:
+            env.update(chip_env(rank))
         p = ctx.Process(target=_wrap, args=(func, args, env), daemon=daemon)
         p.start()
         procs.append(p)

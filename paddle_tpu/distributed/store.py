@@ -6,8 +6,9 @@ not in this image). The native library is built on first use with g++.
 """
 import ctypes
 import os
-import subprocess
 import threading
+
+from ..utils.cpp_extension import build_if_stale
 
 _LIB = None
 _BUILD_LOCK = threading.Lock()
@@ -23,13 +24,7 @@ def _lib():
         here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
         src = os.path.join(here, "csrc", "tcp_store.cc")
         so = os.path.join(here, "csrc", "libtcpstore.so")
-        if (not os.path.exists(so)
-                or os.path.getmtime(so) < os.path.getmtime(src)):
-            subprocess.run(
-                ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-o", so,
-                 src, "-lpthread"],
-                check=True, capture_output=True)
-        lib = ctypes.CDLL(so)
+        lib = ctypes.CDLL(build_if_stale(src, so, opt="-O2"))
         lib.pts_server_start.restype = ctypes.c_void_p
         lib.pts_server_start.argtypes = [ctypes.c_int]
         lib.pts_server_port.restype = ctypes.c_int

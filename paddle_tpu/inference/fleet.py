@@ -1035,6 +1035,12 @@ def _worker_entry(cfg):
     sets picks this worker's name."""
     rank = int(os.environ.get("PADDLE_TRAINER_ID", "0"))
     name = cfg["names"][rank]
+    # a chip belongs to one process: worker `rank` owns local chip
+    # `rank` — first spawn, respawn and scale-out alike — bound here,
+    # before anything below touches the jax backend
+    from ..chip import chip_env, enable_compile_cache
+    os.environ.update(chip_env(rank))
+    enable_compile_cache()
     from ..distributed.store import TCPStore
     store = TCPStore(cfg["store_host"], cfg["store_port"])
     engine = resolve_factory(cfg["factory"])()
@@ -1259,6 +1265,8 @@ def main(argv=None):
                     help="import-path engine factory (overrides "
                          "--spec-json)")
     args = ap.parse_args(argv)
+    from ..chip import enable_compile_cache
+    enable_compile_cache()
     host_s, _, port_s = args.store.partition(":")
     from ..distributed.store import TCPStore
     store = TCPStore(host_s, int(port_s))

@@ -55,7 +55,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..jax_compat import shard_map
+from jax import shard_map
 
 AXIS = "mp"                    # the serving model-parallel mesh axis
 REPL = P()                     # replicated spec (tables, lens, tokens…)
@@ -96,7 +96,7 @@ class TPContext:
             raise ValueError(
                 f"tp={tp} needs {tp} devices but only {len(devs)} are "
                 f"visible (backend {jax.default_backend()!r}); on CPU "
-                "set --xla_force_host_platform_device_count")
+                "set jax_num_cpu_devices before the backend starts")
         self.tp = tp
         self.mode = mode
         self.compress = compress

@@ -74,7 +74,7 @@ class GPTEmbeddings(Layer):
             # context parallelism: this shard holds a contiguous SLICE of
             # the global sequence — learned positions need the per-rank
             # global offset (same contract as the LLaMA rope offsets)
-            from ..jax_compat import axis_size as _axis_size
+            from jax.lax import axis_size as _axis_size
             n_sep = _axis_size("sep")
             max_pos = self.position_embeddings.weight.shape[0]
             if s * n_sep > max_pos:

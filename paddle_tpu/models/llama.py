@@ -159,7 +159,7 @@ class LlamaAttention(Layer):
             ka = ka.reshape(b, s, ka.shape[-1] // hd, hd)
             va = va.reshape(b, s, va.shape[-1] // hd, hd)
             if sp:
-                from ..jax_compat import axis_size as _axis_size
+                from jax.lax import axis_size as _axis_size
                 n_sep = _axis_size("sep")
                 if s * n_sep > cos.shape[0]:
                     raise ValueError(

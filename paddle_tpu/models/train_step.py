@@ -34,7 +34,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from ..jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P, NamedSharding
 
 from ..autograd import tape

@@ -56,8 +56,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...jax_compat import enable_x64, tpu_compiler_params
-from .paged_attention import NEG_INF, ragged_causal_mask, wv_diag
+from .paged_attention import (NEG_INF, ragged_causal_mask, vmem_limit,
+                              wv_diag)
 from .quantized_matmul import dot_tile_f32, scale_emit
 from .rms_norm import rms_rows as _rms_rows
 
@@ -214,19 +214,29 @@ def megakernel_supported(nh, nh_kv, hd, hidden, ffn):
             and (nh_kv * hd) % 128 == 0)
 
 
-def _rope_flat(x, c, s, n_heads, hd):
+def _rope_flat(x, c, s, n_heads, hd, cdtype):
     """Rope over the FLAT [rows, n_heads*hd] layout: per-head unrolled
     half-pair rotation (heads are small and static at decode — the same
-    unroll the paged-attention kernels use). c/s: [rows, hd//2], already
-    in x.dtype (matching _layer_qkv's cast-then-multiply order); with
-    tq > 1 each feed row carries its own position's rope row."""
+    unroll the paged-attention kernels use). x/c/s arrive as f32 holding
+    cdtype-representable values (c/s: [rows, hd//2] rope rows at each
+    ROW's position); every product and sum is rounded to cdtype where
+    _layer_qkv's cdtype arithmetic rounds, so the result is the op
+    chain's — and with cdtype == f32 the roundings are no-ops and the
+    expression is literally the same. The math stays on 32-bit vectors
+    because that is the form Mosaic lowers: the row-addressed scratch is
+    f32 (see decode_megakernel)."""
+    f32 = jnp.float32
+
+    def rnd(t):
+        return t.astype(cdtype).astype(f32)
+
     hd2 = hd // 2
     outs = []
     for g in range(n_heads):
         x1 = x[:, g * hd:g * hd + hd2]
         x2 = x[:, g * hd + hd2:(g + 1) * hd]
-        outs.append(x1 * c - x2 * s)
-        outs.append(x2 * c + x1 * s)
+        outs.append(rnd(rnd(x1 * c) - rnd(x2 * s)))
+        outs.append(rnd(rnd(x2 * c) + rnd(x1 * s)))
     return jnp.concatenate(outs, axis=1)
 
 
@@ -326,7 +336,7 @@ def _mk_kernel(*args, names, seg, stacked, counts, bks, bns, dims,
     # -- shared matmul step: acc += x_tile @ w_tile; emit at last k ----
     def seg_write(tgt):
         def emit(out, bn):
-            tgt[:, pl.ds(a1 * bn, bn)] = out
+            tgt[:, pl.ds(a1 * bn, bn)] = out.astype(tgt.dtype)
         return emit
 
     def seg_add(tgt):
@@ -382,28 +392,28 @@ def _mk_kernel(*args, names, seg, stacked, counts, bks, bns, dims,
     if PH_Q in counts:
         @pl.when(_phase_end(PH_Q))
         def _rope_q():
-            c = refs["cos"][...]
-            sn = refs["sin"][...]
+            c = refs["cos"][...].astype(jnp.float32)
+            sn = refs["sin"][...].astype(jnp.float32)
             refs["q_scr"][:, :NQ] = _rope_flat(refs["q_scr"][:, :NQ],
-                                               c, sn, nh, hd)
+                                               c, sn, nh, hd, cdtype)
 
         @pl.when(_phase_end(PH_K))
         def _rope_k():
-            c = refs["cos"][...]
-            sn = refs["sin"][...]
+            c = refs["cos"][...].astype(jnp.float32)
+            sn = refs["sin"][...].astype(jnp.float32)
             refs["k_scr"][:, :NK] = _rope_flat(refs["k_scr"][:, :NK],
-                                               c, sn, nh_kv, hd)
+                                               c, sn, nh_kv, hd, cdtype)
             if stacked:
-                refs["kn"][0] = refs["k_scr"][...]
+                refs["kn"][0] = refs["k_scr"][...].astype(cdtype)
             else:
-                refs["kn"][...] = refs["k_scr"][...]
+                refs["kn"][...] = refs["k_scr"][...].astype(cdtype)
 
         @pl.when(_phase_end(PH_V))
         def _emit_v():
             if stacked:
-                refs["vn"][0] = refs["v_scr"][...]
+                refs["vn"][0] = refs["v_scr"][...].astype(cdtype)
             else:
-                refs["vn"][...] = refs["v_scr"][...]
+                refs["vn"][...] = refs["v_scr"][...].astype(cdtype)
 
     if PH_O in counts:
         @pl.when(_phase_end(PH_O))
@@ -427,7 +437,7 @@ def _mk_kernel(*args, names, seg, stacked, counts, bks, bns, dims,
 
     # -- paged attention phase (a0 = slot, a1 = page) ------------------
     if PH_ATTN in SEG_PHASES[seg]:
-        attn_tgt = refs["attn_out"] if seg == "qkv" else refs["attn_scr"]
+        attn_tgt = refs["attn_scr"]
         m_scr, l_scr, aacc = refs["m_scr"], refs["l_scr"], refs["aacc_scr"]
         tblr, lensr, actr = refs["tbl"], refs["lens"], refs["act"]
         wmr = refs["wm"]
@@ -517,7 +527,11 @@ def _mk_kernel(*args, names, seg, stacked, counts, bks, bns, dims,
                     # + qi = q head g*rep+j at feed offset qi) — the
                     # ragged kernel's row convention, one contiguous
                     # [rep*T, d] slice per kv head
-                    qs = refs["q_scr"][pl.ds(slot * T, T), :][:, :NQ] \
+                    # (one dynamic ROW per load: Mosaic proves no
+                    # alignment for a dynamic multi-row window)
+                    qs = jnp.concatenate(
+                        [refs["q_scr"][pl.ds(slot * T + j, 1), :]
+                         for j in range(T)], axis=0)[:, :NQ] \
                         .astype(jnp.float32) * jnp.float32(scale)
                     logits = jnp.concatenate([
                         jax.lax.dot_general(
@@ -548,7 +562,8 @@ def _mk_kernel(*args, names, seg, stacked, counts, bks, bns, dims,
             @pl.when(page == mp - 1)
             def _emit():
                 l_fin = jnp.maximum(l_scr[:, :1], jnp.float32(1e-30))
-                res = (aacc[...] / l_fin).astype(cdtype)
+                res = (aacc[...] / l_fin).astype(cdtype).astype(
+                    jnp.float32)
                 if T == 1:
                     row = res.reshape(1, NQ)               # [nh, hd]
                     if NQp != NQ:     # scratch pads must be exact zeros
@@ -562,6 +577,11 @@ def _mk_kernel(*args, names, seg, stacked, counts, bks, bns, dims,
                             row = jnp.pad(row,
                                           ((0, 0), (0, NQp - NQ)))
                         attn_tgt[pl.ds(slot * T + qi, 1), :] = row
+                if seg == "qkv":        # segment ends here: emit it
+                    @pl.when(slot == b - 1)
+                    def _():
+                        refs["attn_out"][...] = attn_tgt[...].astype(
+                            cdtype)
 
     # -- whole-step tail: final norm + lm_head tiles + running argmax --
     if head:
@@ -957,15 +977,19 @@ def decode_megakernel(h, mk, k_pages=None, v_pages=None, page_table=None,
     if has_attn:
         scr_names += ["q_scr", "k_scr", "v_scr", "m_scr", "l_scr",
                       "aacc_scr"]
-        scratch += [pltpu.VMEM((R, NQp), cdtype),
-                    pltpu.VMEM((R, NKp), cdtype),
-                    pltpu.VMEM((R, NKp), cdtype),
+        # q/k/v (and the attention rows below) are addressed one
+        # dynamic ROW at a time; Mosaic proves no alignment for a
+        # dynamic sublane index into a packed (sub-32-bit) buffer, so
+        # these hold the cdtype-rounded values in f32 containers
+        scratch += [pltpu.VMEM((R, NQp), jnp.float32),
+                    pltpu.VMEM((R, NKp), jnp.float32),
+                    pltpu.VMEM((R, NKp), jnp.float32),
                     pltpu.VMEM((nh * T, 128), jnp.float32),
                     pltpu.VMEM((nh * T, 128), jnp.float32),
                     pltpu.VMEM((nh * T, hd), jnp.float32)]
-    if seg == "full":
+    if has_attn:
         scr_names += ["attn_scr"]
-        scratch += [pltpu.VMEM((R, NQp), cdtype)]
+        scratch += [pltpu.VMEM((R, NQp), jnp.float32)]
     if seg in ("full", "tail"):
         scr_names += ["g_scr", "u_scr", "act_scr"]
         scratch += [pltpu.VMEM((R, Fg), cdtype)] * 3
@@ -989,13 +1013,25 @@ def decode_megakernel(h, mk, k_pages=None, v_pages=None, page_table=None,
         out_specs=out_specs,
         scratch_shapes=scratch,
     )
-    with enable_x64(False):
+    # seven or eight double-buffered weight streams, two page blocks and
+    # their f32 copies: past the 16 MiB default at 7B width
+    f32 = jnp.float32
+    limit = vmem_limit(
+        blocks=[(sp.block_shape, op.dtype)
+                for sp, op in zip(in_specs, operands)]
+        + [(sp.block_shape, sd.dtype)
+           for sp, sd in zip(out_specs, out_shapes)],
+        scratch=[(m.shape, m.dtype) for m in scratch],
+        temps=([((p, nh_kv, hd), f32)] * 4 if has_attn else [])
+        + [((R, bn_max), f32)] * 2)
+    with jax.enable_x64(False):
         outs = pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
             out_shape=out_shapes,
-            compiler_params=tpu_compiler_params(
-                dimension_semantics=("arbitrary",)),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",),
+                vmem_limit_bytes=limit),
             interpret=interpret,
         )(*pre_ops, *operands)
     res = dict(zip(out_names, outs))

@@ -50,7 +50,6 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...jax_compat import enable_x64, tpu_compiler_params
 
 NEG_INF = -1e30
 ROW_LANES = 8  # lane replication for per-row stats (lse/delta) in HBM
@@ -370,7 +369,7 @@ def _flash_fwd(q, k, v, mask, h, causal, scale, bq, bk, s_true, interpret,
         mask_batched=mask_batched, nheads=h, dropout_p=dropout_p)
     # x64 must be off while tracing the kernel/index maps: Mosaic rejects
     # i64 grid indices (the package enables x64 globally for API parity).
-    with enable_x64(False):
+    with jax.enable_x64(False):
         out, lse = pl.pallas_call(
             kernel,
             grid=(B // nb, h, nq, nk),
@@ -392,7 +391,7 @@ def _flash_fwd(q, k, v, mask, h, causal, scale, bq, bk, s_true, interpret,
                 pltpu.VMEM((nb, bq, ROW_LANES), jnp.float32),
                 pltpu.VMEM((nb, bq, d), jnp.float32),
             ],
-            compiler_params=tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "parallel",
                                      "arbitrary")),
             interpret=interpret,
@@ -567,7 +566,7 @@ def _flash_bwd(q, k, v, o, lse_l, do, mask, h, causal, scale, bq, bk,
         in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
         args.append(jnp.asarray(seed, jnp.int32).reshape(1))
 
-    with enable_x64(False):
+    with jax.enable_x64(False):
         dq_part, dk, dv = pl.pallas_call(
             functools.partial(_fused_bwd_kernel, nb=nb, bq=bq, bk=bk,
                               nq=nq, s_true=s_true, causal=causal,
@@ -595,7 +594,7 @@ def _flash_bwd(q, k, v, o, lse_l, do, mask, h, causal, scale, bq, bk,
             ],
             scratch_shapes=[pltpu.VMEM((nb, bk, d), jnp.float32),
                             pltpu.VMEM((nb, bk, d), jnp.float32)],
-            compiler_params=tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "parallel",
                                      "arbitrary")),
             interpret=interpret,

@@ -31,9 +31,26 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...jax_compat import enable_x64, tpu_compiler_params
 
 NEG_INF = -1e30
+
+
+def vmem_limit(blocks, scratch=(), temps=()):
+    """vmem_limit_bytes for a pallas_call whose footprint grows with the
+    model's width: Mosaic's default scoped limit is 16 MiB on v5e, which
+    the 7B geometry passes (a [128 x 32 x 128] page block alone is 1 MiB
+    and its f32 copy 2). Counts every pipelined in/out block twice
+    (double buffering), the scratch, and the body's large temporaries —
+    each a (shape, dtype) pair — adds half again for what the compiler
+    keeps beside them, and stays between the default and 100 MiB (a v5e
+    core holds 128)."""
+    def nbytes(shape, dtype):
+        return math.prod(shape) * jnp.dtype(dtype).itemsize
+
+    need = (2 * sum(nbytes(*b) for b in blocks)
+            + sum(nbytes(*x) for x in scratch)
+            + sum(nbytes(*t) for t in temps))
+    return int(min(max(need * 3 // 2, 16 << 20), 100 << 20))
 
 
 def _decode_kernel(page_table_ref, seq_lens_ref, active_ref, q_ref, k_ref,
@@ -181,13 +198,19 @@ def paged_attention(q, k_pages, v_pages, page_table, seq_lens, scale=None,
             pltpu.VMEM((h, d), jnp.float32),
         ],
     )
-    with enable_x64(False):
+    f32 = jnp.float32
+    limit = vmem_limit(
+        blocks=[((h, d), q.dtype)] * 2 + [((p, h_kv, d), k_pages.dtype)] * 2,
+        scratch=[((h, 128), f32)] * 2 + [((h, d), f32)],
+        temps=[((p, h_kv, d), f32)] * 2)
+    with jax.enable_x64(False):
         out = pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
-            compiler_params=tpu_compiler_params(
-                dimension_semantics=("parallel", "arbitrary")),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary"),
+                vmem_limit_bytes=limit),
             interpret=interpret,
         )(table, lens, act, q, k_pages, v_pages)
     return out
@@ -339,13 +362,23 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, ctx_lens,
             pltpu.VMEM((h * tq, d), jnp.float32),
         ],
     )
-    with enable_x64(False):
+    f32 = jnp.float32
+    limit = vmem_limit(
+        blocks=[((h * tq, d), q.dtype)] * 2
+        + [((p, h_kv, d), k_pages.dtype)] * 2,
+        scratch=[((h * tq, 128), f32)] * 2 + [((h * tq, d), f32)],
+        # the body's f32 copies: q, k, v, and the [rows, p] logits,
+        # weights and mask
+        temps=[((h * tq, d), f32)] + [((p, h_kv, d), f32)] * 2
+        + [((h * tq, p), f32)] * 3)
+    with jax.enable_x64(False):
         out = pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((b, h * tq, d), q.dtype),
-            compiler_params=tpu_compiler_params(
-                dimension_semantics=("parallel", "arbitrary")),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary"),
+                vmem_limit_bytes=limit),
             interpret=interpret,
         )(table, lens, starts, act, qr, k_pages, v_pages)
     return jnp.swapaxes(out.reshape(b, h, tq, d), 1, 2)

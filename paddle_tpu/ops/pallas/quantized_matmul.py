@@ -17,11 +17,6 @@ decode megakernel (ops/pallas/decode_megakernel) runs the SAME ops in
 the same order: its streamed per-layer matmuls are bit-identical to this
 standalone kernel because they share these definitions, not because two
 copies happen to agree.
-
-jax-compat audit (PR 6): every version-sensitive API here routes through
-paddle_tpu.jax_compat (enable_x64, tpu_compiler_params); the remaining
-pallas surface (pl.BlockSpec(block_shape, index_map), pl.when, pl.cdiv,
-pltpu.VMEM scratch) is present and identical on the baked jax 0.4.37.
 """
 import functools
 
@@ -30,7 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...jax_compat import enable_x64, tpu_compiler_params
 
 
 def dot_tile_f32(x_tile, w_tile):
@@ -91,7 +85,7 @@ def quantized_matmul(x, w_int8, scales, out_dtype=None, bm=256, bn=256,
     _, np_ = wp.shape
     nk = kp // bk
 
-    with enable_x64(False):
+    with jax.enable_x64(False):
         out = pl.pallas_call(
             functools.partial(_qmm_kernel, nk=nk),
             grid=(mp // bm, np_ // bn, nk),
@@ -103,7 +97,7 @@ def quantized_matmul(x, w_int8, scales, out_dtype=None, bm=256, bn=256,
             out_specs=pl.BlockSpec((bm, bn), lambda i, j, kb: (i, j)),
             out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
             scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-            compiler_params=tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=interpret,
         )(xp, wp, sp.reshape(1, -1))

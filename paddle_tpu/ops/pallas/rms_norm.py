@@ -11,10 +11,6 @@ Two cast orders live here on purpose:
     — inference/serving._rms's order, which the decode megakernel must
     reproduce bit-for-bit. Identical for f32; different roundings for
     bf16, so they are NOT interchangeable.
-
-jax-compat audit (PR 6): version-sensitive APIs route through
-paddle_tpu.jax_compat (enable_x64, tpu_compiler_params); the remaining
-pallas surface used here is identical on the baked jax 0.4.37.
 """
 import functools
 
@@ -23,7 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...jax_compat import enable_x64, tpu_compiler_params
 
 
 def rms_rows(x, w_row, eps, d_real=None):
@@ -52,7 +47,7 @@ def _rms_fwd_kernel(x_ref, w_ref, o_ref, *, eps):
 def _rms_fwd(x2d, w, eps, rows, interpret):
     n, d = x2d.shape
     br = min(rows, n)
-    with enable_x64(False):
+    with jax.enable_x64(False):
         return pl.pallas_call(
         functools.partial(_rms_fwd_kernel, eps=eps),
         grid=(pl.cdiv(n, br),),

@@ -27,7 +27,7 @@ from jax import lax
 
 from .passes import PassBase, register_pass
 from ..distributed.mesh import in_spmd_region
-from ..jax_compat import axis_size as _axis_size
+from jax.lax import axis_size as _axis_size
 
 
 @register_pass("data_parallel_gradient_sync")

@@ -5,10 +5,39 @@ csrc/ convention: tcp_store.cc, ps_service.cc build via g++ on first
 import) or Pallas kernels; the reference's CUDAExtension tier does not
 apply. load() compiles a .cc into a shared library and returns the
 ctypes handle."""
+import hashlib
 import os
 import subprocess
 
 __all__ = ["load", "get_build_directory"]
+
+
+def build_if_stale(src, out, opt="-O3"):
+    """Compile `src` into the shared library `out` unless `out` was
+    built from exactly this source with these flags. Keyed on a content
+    hash kept beside the library, not on mtimes: a copy of the tree (the
+    chip tool's, a fresh checkout beside a leftover .so) does not keep
+    them. Builds to a temporary name and renames, so processes that
+    start together never load a half-written library."""
+    cmd = ["g++", opt, "-std=c++17", "-shared", "-fPIC"]
+    with open(src, "rb") as f:
+        digest = hashlib.sha256(
+            f.read() + " ".join(cmd).encode()).hexdigest()
+    stamp = out + ".srchash"
+    try:
+        with open(stamp) as f:
+            fresh = os.path.exists(out) and f.read().strip() == digest
+    except FileNotFoundError:
+        fresh = False
+    if not fresh:
+        tmp = f"{out}.{os.getpid()}.tmp"
+        subprocess.run(cmd + ["-o", tmp, src, "-lpthread"], check=True,
+                       capture_output=True)
+        os.replace(tmp, out)
+        with open(tmp, "w") as f:
+            f.write(digest)
+        os.replace(tmp, stamp)
+    return out
 
 
 def get_build_directory():

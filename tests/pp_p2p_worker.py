@@ -9,10 +9,7 @@ import sys
 if __name__ == "__main__":
     import jax
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", 1)
-    except AttributeError:
-        pass  # 0.4.x stack: single host device is already the default
+    jax.config.update("jax_num_cpu_devices", 1)
 
 import numpy as np  # noqa: E402
 

@@ -7,7 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from paddle_tpu.jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from paddle_tpu.distributed.auto_parallel.reshard import (

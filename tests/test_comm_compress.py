@@ -12,7 +12,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 import paddle_tpu as paddle
-from paddle_tpu.jax_compat import shard_map
+from jax import shard_map
 from paddle_tpu.distributed.mesh import build_mesh, set_global_mesh, \
     spmd_axes
 from paddle_tpu.distributed import comm_compress as cc

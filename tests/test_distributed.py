@@ -58,7 +58,7 @@ class TestCollectivesSPMD:
         import jax
         import jax.numpy as jnp
         from jax.sharding import Mesh, PartitionSpec as P
-        from paddle_tpu.jax_compat import shard_map
+        from jax import shard_map
         from paddle_tpu.distributed.mesh import spmd_axes, set_global_mesh, build_mesh
         from paddle_tpu.distributed.collective import all_reduce, new_group
         from paddle_tpu.tensor.tensor import Tensor
@@ -90,7 +90,7 @@ class TestBatchIsendIrecv:
     def test_shift_by_one_ring(self):
         import jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from paddle_tpu.jax_compat import shard_map
+        from jax import shard_map
         from paddle_tpu.distributed.mesh import spmd_axes, set_global_mesh, \
             build_mesh
         from paddle_tpu.distributed.collective import (P2POp, isend, irecv,
@@ -124,7 +124,7 @@ class TestBatchIsendIrecv:
         # group-local coordinates before computing the ring offset
         import jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from paddle_tpu.jax_compat import shard_map
+        from jax import shard_map
         from paddle_tpu.distributed.mesh import spmd_axes, set_global_mesh, \
             build_mesh
         from paddle_tpu.distributed.collective import (P2POp, isend, irecv,

@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 from jax import lax
 
-from paddle_tpu.jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from paddle_tpu.ops.fused_ce import fused_linear_ce, vocab_parallel_ce_rows

@@ -1,15 +1,18 @@
-"""Static Mosaic (real TPU) lowering of every Pallas kernel, run on CPU.
+"""TPU-dialect lowering of every Pallas kernel, run on CPU.
 
-VERDICT r4 weak #2: kernels proven only under the CPU interpreter can
-still fail Mosaic's layout/tiling rules on real hardware (caught live in
-round 5: a squeezed head dim in sublane position rejects h > 1).
-`jax.export(..., platforms=["tpu"])` runs the REAL Mosaic kernel
-compiler during lowering, so every tiling/layout/geometry violation
-surfaces here without a chip. Numeric on-chip validation rides the
-watcher's benchmarks/kernel_sweep.py; this suite pins the compile side
-in CI. (The reference trusts only device-tested kernels — OpTest runs
-on GPU, test/legacy_test/op_test.py:326 — this is the no-hardware
-analog.)"""
+`jax.export(..., platforms=["tpu"])` traces each kernel and lowers it to
+the Mosaic TPU dialect, so anything Pallas itself rejects for TPU —
+unsupported primitives, bad block shapes or index maps, i64 leaking in
+through the package-wide x64 — surfaces here without a chip. That is
+all it proves. On the installed jax the export only SERIALISES the
+Mosaic module (jax/_src/tpu_custom_call.py runs mosaic-serde and nothing
+else); layout inference, tiling checks and VMEM allocation — Mosaic
+proper — run inside libtpu when XLA compiles, i.e. only on the chip.
+A kernel can pass here and still be refused there (PR 21: the decode
+megakernel's dynamic row index into a bf16 scratch, the ragged kernel's
+scoped-VMEM footprint at 7B width). benchmarks/kernel_sweep.py is the
+on-chip check; this suite only keeps a refactor from losing even the
+lowering."""
 import functools
 
 import numpy as np
@@ -22,7 +25,8 @@ import paddle_tpu  # noqa: F401  (config init)
 
 
 def _lower_tpu(fn, *avals):
-    """Export for TPU: traces + Mosaic-compiles all Pallas calls."""
+    """Export for TPU: traces every Pallas call and lowers it to the
+    Mosaic dialect (no layout, tiling or VMEM checks — see above)."""
     return jexport.export(jax.jit(fn), platforms=["tpu"])(*avals)
 
 
@@ -156,3 +160,83 @@ class TestOtherKernelsLowering:
         table = _sds((b, max_pages), jnp.int32)
         lens = _sds((b,), jnp.int32)
         _lower_tpu(paged_attention, q, pages, pages, table, lens)
+
+    def test_ragged_and_spec_verify_attention(self):
+        from paddle_tpu.ops.pallas.paged_attention import (
+            ragged_paged_attention, spec_verify_attention)
+        b, h, d, p, n_pages, max_pages = 4, 8, 128, 128, 32, 8
+        pages = _sds((n_pages, p, h, d), jnp.bfloat16)
+        table = _sds((b, max_pages), jnp.int32)
+        lens = _sds((b,), jnp.int32)
+        _lower_tpu(ragged_paged_attention,
+                   _sds((b, 128, h, d), jnp.bfloat16), pages, pages, table,
+                   lens, lens)
+        _lower_tpu(spec_verify_attention,
+                   _sds((b, 4, h, d), jnp.bfloat16), pages, pages, table,
+                   lens)
+
+
+class TestDecodeMegakernelLowering:
+    """decode_megakernel layer/multi x dense/int8 at a lane-aligned
+    geometry (what megakernel_supported admits on a chip)."""
+    H, NH, HD, FFN, V, P, MP, W = 512, 4, 128, 1024, 1024, 128, 4, 4
+
+    def _layer(self, quant):
+        from paddle_tpu.ops.pallas.decode_megakernel import \
+            pack_decode_layer
+        from paddle_tpu.ops.pallas.quantized_matmul import quantize_weights
+        H, F = self.H, self.FFN
+
+        def w(k, n):
+            a = jnp.ones((k, n), jnp.float32)
+            return quantize_weights(a) if quant else a
+        ws = dict(ln1=jnp.ones((H,), jnp.float32),
+                  ln2=jnp.ones((H,), jnp.float32), wq=w(H, H),
+                  wk=w(H, H), wv=w(H, H), wo=w(H, H), wg=w(H, F),
+                  wu=w(H, F), wd=w(F, H))
+        return pack_decode_layer(ws, cdtype=jnp.bfloat16)
+
+    def _args(self, stacked_layers=None):
+        n_pages = self.W * self.MP
+        pshape = (n_pages, self.P, self.NH, self.HD)
+        if stacked_layers:
+            pshape = (stacked_layers,) + pshape
+        return (_sds((self.W, self.H), jnp.bfloat16),
+                _sds(pshape, jnp.bfloat16),
+                _sds((self.W, self.MP), jnp.int32),
+                _sds((self.W,), jnp.int32),
+                _sds((self.W, self.HD // 2), jnp.bfloat16))
+
+    @pytest.mark.parametrize("quant", [False, True])
+    def test_layer(self, quant):
+        from paddle_tpu.ops.pallas.decode_megakernel import \
+            decode_megakernel
+        pack = self._layer(quant)
+        h, pages, table, lens, rope = self._args()
+
+        def f(h_, kp, vp, tbl, ln, cos, sin):
+            return decode_megakernel(h_, pack, kp, vp, tbl, ln, None, cos,
+                                     sin, nh=self.NH, nh_kv=self.NH,
+                                     hd=self.HD, eps=1e-6)
+
+        _lower_tpu(f, h, pages, pages, table, lens, rope, rope)
+
+    @pytest.mark.parametrize("quant", [False, True])
+    def test_multi_with_head(self, quant):
+        from paddle_tpu.ops.pallas.decode_megakernel import (
+            decode_megakernel, pack_lm_head, stack_packed)
+        from paddle_tpu.ops.pallas.quantized_matmul import quantize_weights
+        pack = stack_packed([self._layer(quant)] * 2)
+        head = jnp.ones((self.H, self.V), jnp.float32)
+        hpack = pack_lm_head(quantize_weights(head) if quant else head,
+                             jnp.ones((self.H,), jnp.float32),
+                             cdtype=jnp.bfloat16)
+        h, pages, table, lens, rope = self._args(stacked_layers=2)
+
+        def f(h_, kp, vp, tbl, ln, cos, sin):
+            return decode_megakernel(h_, pack, kp, vp, tbl, ln, None, cos,
+                                     sin, nh=self.NH, nh_kv=self.NH,
+                                     hd=self.HD, eps=1e-6, head=hpack,
+                                     head_v=self.V)
+
+        _lower_tpu(f, h, pages, pages, table, lens, rope, rope)

@@ -3,7 +3,7 @@ import numpy as np
 import pytest
 import jax
 import jax.numpy as jnp
-from paddle_tpu.jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 import paddle_tpu  # noqa: F401

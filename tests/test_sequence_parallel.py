@@ -120,7 +120,7 @@ def test_gpt_sep2_matches_dense():
 def test_sdpa_under_sep_rejects_masks_and_non_causal():
     """Unsupported sdpa configs under a live 'sep' axis must raise, not
     silently compute block-diagonal attention."""
-    from paddle_tpu.jax_compat import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
     import paddle_tpu.nn.functional as F
     from paddle_tpu.distributed.mesh import spmd_axes
@@ -153,7 +153,7 @@ def test_sdpa_under_sep_rejects_masks_and_non_causal():
 def test_ring_attention_dropout_drops_and_is_deterministic_per_seed():
     """In-ring attention dropout: nonzero p changes the output (vs p=0),
     the same framework seed reproduces it, and outputs stay finite."""
-    from paddle_tpu.jax_compat import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
     from paddle_tpu.distributed.fleet.meta_parallel.parallel_layers \
         .ring_attention import ring_attention

@@ -47,8 +47,7 @@ import jax.numpy as jnp
 
 from ..failsafe import InjectedFault, fault_point
 from ..failsafe import armed as _faults_armed
-from ..profiler import RecordEvent as _RecordEvent
-from ..profiler import spans_active as _spans_active
+from ..profiler import RecordEvent as _span
 from .adapters import AdapterError, UnknownAdapterError
 from .sampling import (GREEDY, NEG, SamplingParams, TokenMaskAutomaton,
                        apply_penalties, fold_keys, select_from_topk,
@@ -60,17 +59,12 @@ from ..ops.pallas.paged_attention import (expand_kv_heads, paged_attention,
                                           ragged_paged_attention,
                                           spec_verify_attention)
 
+# The engine's one span call is profiler.RecordEvent (`_span`), always on:
+# every host phase of a step lands on the host plane of any active
+# jax.profiler session (TraceAnnotation, the device lines' clock) and in
+# profiler.span_totals(), session or not. Names, extents and the metric
+# each is for: docs/observability.md "Profiler integration".
 _NULL_SPAN = contextlib.nullcontext()
-
-
-def _prof_span(name):
-    """profiler.RecordEvent around a compiled dispatch while a Profiler
-    is RECORDING (profiler.spans_active()); a shared no-op context
-    otherwise — one function call + one global read per dispatch when
-    profiling is off. The spans lower to jax.profiler.TraceAnnotation,
-    so they render next to the XPlane device trace in Perfetto
-    (docs/observability.md "Profiler integration")."""
-    return _RecordEvent(name) if _spans_active() else _NULL_SPAN
 
 QUEUED, PREFILL, DECODE, DONE, FAILED, CANCELLED = \
     "queued", "prefill", "decode", "done", "failed", "cancelled"
@@ -548,6 +542,7 @@ class ContinuousBatchingEngine(LLMEngine):
     gone), and even then queued requests survive the rebuild.
     """
 
+    @_span("setup.engine")
     def __init__(self, model, max_len=1024, page_size=128, max_batch=8,
                  prefill_chunk=None, slot_buckets=None, prefix_cache=True,
                  queue_limit=None, default_deadline_ms=None,
@@ -638,18 +633,20 @@ class ContinuousBatchingEngine(LLMEngine):
         self._mk_head = False           # whole-step mode: final norm +
         self._mk_vl = 0                 # lm_head + argmax in-kernel
         if self.megakernel:
-            self._build_mk_pack()
+            with _span("setup.engine.mk_pack"):
+                self._build_mk_pack()
         if self.megakernel == "multi":
             # NATIVE stacked KV pools: "multi" consumes the whole [L,...]
             # stack every step, so store it stacked — the per-scan-step
             # jnp.stack restack PR 6 documented (XLA traffic ~ pool size
             # inside the fused block) is gone; every compiled path
             # handles both forms (list per layer / one stacked array)
-            self.k_pages = jnp.stack(self.k_pages)
-            self.v_pages = jnp.stack(self.v_pages)
-            if self._tpc is not None:
-                self.k_pages = self._tpc.place_pools(self.k_pages)
-                self.v_pages = self._tpc.place_pools(self.v_pages)
+            with _span("setup.engine.kv_pool"):
+                self.k_pages = jnp.stack(self.k_pages)
+                self.v_pages = jnp.stack(self.v_pages)
+                if self._tpc is not None:
+                    self.k_pages = self._tpc.place_pools(self.k_pages)
+                    self.v_pages = self._tpc.place_pools(self.v_pages)
         if slot_buckets is None:
             slot_buckets = []
             w = 1
@@ -726,6 +723,8 @@ class ContinuousBatchingEngine(LLMEngine):
         self._cb_step_fns = {}
         self._cb_prefill_fn = None
         self._cb_fused_fns = {}
+        self._program_built = False     # a step program was built and
+        #                                 has not been called yet
         self._pf_dummies = {}
         self._pending = None            # in-flight fused block (its
         #                                 readback not yet processed)
@@ -1128,13 +1127,14 @@ class ContinuousBatchingEngine(LLMEngine):
         block-boundary host point, so the measurement costs two
         monotonic reads and nothing on the telemetry=None fast path
         (a single branch)."""
-        if self._tel is None:
-            return self._step_impl()
-        t0 = time.monotonic()
-        moved = self._step_impl()
-        if moved:
-            self._tel.block((time.monotonic() - t0) * 1e3)
-        return moved
+        with _span("cb.step", step=self.steps):
+            if self._tel is None:
+                return self._step_impl()
+            t0 = time.monotonic()
+            moved = self._step_impl()
+            if moved:
+                self._tel.block((time.monotonic() - t0) * 1e3)
+            return moved
 
     def _step_impl(self):
         """One engine iteration. Returns False when there is nothing to
@@ -1166,10 +1166,11 @@ class ContinuousBatchingEngine(LLMEngine):
         boundary all live there."""
         if self.decode_block > 1 or self._spec:
             return self._fused_step()
-        self._expire_deadlines()
-        self._restore_sweep()
-        self._idle_demote_sweep()
-        self._admit()
+        with _span("cb.admit"):
+            self._expire_deadlines()
+            self._restore_sweep()
+            self._idle_demote_sweep()
+            self._admit()
         prefills = [r for r in self._slots if r and r.state == PREFILL]
         decodes = [r for r in self._slots if r and r.state == DECODE]
         if not prefills and not decodes:
@@ -1836,55 +1837,73 @@ class ContinuousBatchingEngine(LLMEngine):
                             donate_argnums=(2, 3))
 
     def _prefill_step(self, r):
-        chunk = self.prefill_chunk
-        start = r.filled
-        end = min(start + chunk, r.t0)
-        self._make_writable(r, start, end)
-        ids_chunk = np.zeros((1, chunk), np.int64)
-        ids_chunk[0, :end - start] = r.ids[start:end]
-        if r.adapter is not None:
-            # (not an adapter_mk_fallbacks site: chunked prefill is
-            # always the op chain — there is no megakernel to leave)
-            if self._cb_prefill_ad_fn is None:
-                self._cb_prefill_ad_fn = self._build_cb_prefill(
-                    chunk, with_adapters=True)
-            fn = self._cb_prefill_ad_fn
-            pre = (self.weights, self._apool.device,
-                   jnp.asarray(np.asarray(
-                       [self._apool.slot(r.adapter)], np.int32)))
-        else:
-            if self._cb_prefill_fn is None:
-                self._cb_prefill_fn = self._build_cb_prefill(chunk)
-            fn = self._cb_prefill_fn
-            pre = (self.weights,)
-        t_dev = time.perf_counter()
-        with _prof_span("cb.prefill_chunk"):
-            logits, self.k_pages, self.v_pages = fn(
-                *pre, jnp.asarray(ids_chunk), self.k_pages,
-                self.v_pages,
-                jnp.asarray(self._tables_np[r.slot:r.slot + 1]),
-                jnp.int32(start), jnp.int32(r.t0))
-        dt = time.perf_counter() - t_dev
-        self.dispatch_seconds += dt
-        if self._tel is not None:
-            self._tel.observe("prefill_chunk_ms", dt * 1e3)
-            self._tel.req_event(self._tel_src, r.uid, "prefill_chunk",
-                                filled=end)
-        r.filled = end
-        if end < r.t0:
-            return
-        # prompt complete: publish full prompt pages to the prefix cache
-        # (before the first decode write, so concurrent requests share),
-        # then sample the first token from the final chunk's logits
-        self._publish_prefix(r)
-        t_dev = time.perf_counter()
-        # the first generated token enters position t0 — its counter
-        tok = self._select_tokens([r], [r.t0], self._block_mode([r]),
-                                  logits=logits)[0]
-        self.dispatch_seconds += time.perf_counter() - t_dev
-        self._lens_np[r.slot] = r.t0
-        r.state = DECODE
-        self._push_token(r, tok)
+        with _span("cb.prefill.prepare"):
+            chunk = self.prefill_chunk
+            start = r.filled
+            end = min(start + chunk, r.t0)
+            self._make_writable(r, start, end)
+            ids_chunk = np.zeros((1, chunk), np.int64)
+            ids_chunk[0, :end - start] = r.ids[start:end]
+            if r.adapter is not None:
+                # (not an adapter_mk_fallbacks site: chunked prefill is
+                # always the op chain — there is no megakernel to leave)
+                if self._cb_prefill_ad_fn is None:
+                    self._cb_prefill_ad_fn = self._build_cb_prefill(
+                        chunk, with_adapters=True)
+                    self._program_built = True
+                fn = self._cb_prefill_ad_fn
+                pre = (self.weights, self._apool.device,
+                       jnp.asarray(np.asarray(
+                           [self._apool.slot(r.adapter)], np.int32)))
+            else:
+                if self._cb_prefill_fn is None:
+                    self._cb_prefill_fn = self._build_cb_prefill(chunk)
+                    self._program_built = True
+                fn = self._cb_prefill_fn
+                pre = (self.weights,)
+        with self._first_call_span():
+            t_dev = time.perf_counter()
+            with _span("cb.prefill_chunk"):
+                logits, self.k_pages, self.v_pages = fn(
+                    *pre, jnp.asarray(ids_chunk), self.k_pages,
+                    self.v_pages,
+                    jnp.asarray(self._tables_np[r.slot:r.slot + 1]),
+                    jnp.int32(start), jnp.int32(r.t0))
+            dt = time.perf_counter() - t_dev
+            self.dispatch_seconds += dt
+            if self._tel is not None:
+                self._tel.observe("prefill_chunk_ms", dt * 1e3)
+                self._tel.req_event(self._tel_src, r.uid, "prefill_chunk",
+                                    filled=end)
+            r.filled = end
+            if end < r.t0:
+                return
+            # prompt complete: publish full prompt pages to the prefix
+            # cache (before the first decode write, so concurrent
+            # requests share), then sample the first token from the
+            # final chunk's logits
+            with _span("cb.prefill.first_token"):
+                self._publish_prefix(r)
+                t_dev = time.perf_counter()
+                # the first generated token enters position t0 — its
+                # counter
+                tok = self._select_tokens([r], [r.t0],
+                                          self._block_mode([r]),
+                                          logits=logits)[0]
+                self.dispatch_seconds += time.perf_counter() - t_dev
+                self._lens_np[r.slot] = r.t0
+                r.state = DECODE
+                self._push_token(r, tok)
+
+    def _first_call_span(self):
+        """`setup.first_call` around the dispatch (and fetch) of a
+        program the caller has just built: python tracing, lowering, the
+        executable from the cache or the compiler, the first run. A
+        no-op for every later call of that program."""
+        if not self._program_built:
+            return _NULL_SPAN
+        self._program_built = False
+        return _span("setup.first_call")
 
     def _publish_prefix(self, r):
         """Make a completed prompt's FULL pages shareable (the partial
@@ -2631,63 +2650,73 @@ class ContinuousBatchingEngine(LLMEngine):
                             donate_argnums=(2, 3))
 
     def _decode_step(self, decodes):
-        p = self.page_size
-        for r in decodes:
-            # the token fed this step writes KV at position lens
-            pos = int(self._lens_np[r.slot])
-            self._make_writable(r, pos, pos + 1)
-            self._tok_np[r.slot] = r.tok
-        w = next(b for b in self._slot_buckets
-                 if b > max(r.slot for r in decodes))
-        active = np.zeros(w, bool)
-        for r in decodes:
-            if r.slot < w:
-                active[r.slot] = True
-        mode = self._block_mode(decodes)
-        aid = self._slot_aid(decodes, w)
-        fold = mode == "sampled" and self.sample_fold and aid is None
-        if aid is not None:
-            # adapter-carrying batch: the ADAPTER-AWARE program (the
-            # plain program stays untouched — and with megakernel= on,
-            # this dispatch IS the documented op-chain fallback; same
-            # for the sampling fold, which keeps the materialized arm)
-            if self.megakernel:
-                self.adapter_mk_fallbacks += 1
-            fn = self._cb_step_ad_fns.get(w)
-            if fn is None:
-                fn = self._build_cb_step(w, with_adapters=True)
-                self._cb_step_ad_fns[w] = fn
-            args = (self.weights, self._apool.device, jnp.asarray(aid))
-        else:
-            fn = self._cb_step_fns.get((w, mode))
-            if fn is None:
-                fn = self._build_cb_step(w, mode=mode)
-                self._cb_step_fns[(w, mode)] = fn
-            args = (self.weights,)
-        # the new token of the row fed at position lens occupies
-        # position lens+1 — its PRNG counter (BEFORE the increment)
-        positions = self._lens_np[:w] + 1
-        rows = [None] * w
-        for r in decodes:
-            rows[r.slot] = r
-        t_dev = time.perf_counter()
-        with _prof_span("cb.decode_step"):
-            out = fn(
-                *args, jnp.asarray(self._tok_np[:w]), self.k_pages,
-                self.v_pages, jnp.asarray(self._tables_np[:w]),
-                jnp.asarray(self._lens_np[:w]), jnp.asarray(active))
-            if fold:
-                topv, topi, self.k_pages, self.v_pages = out
-                toks = self._select_tokens(rows, positions, mode,
-                                           topv=topv, topi=topi)
+        with _span("cb.decode.prepare"):
+            for r in decodes:
+                # the token fed this step writes KV at position lens
+                pos = int(self._lens_np[r.slot])
+                self._make_writable(r, pos, pos + 1)
+                self._tok_np[r.slot] = r.tok
+            w = next(b for b in self._slot_buckets
+                     if b > max(r.slot for r in decodes))
+            active = np.zeros(w, bool)
+            for r in decodes:
+                if r.slot < w:
+                    active[r.slot] = True
+            mode = self._block_mode(decodes)
+            aid = self._slot_aid(decodes, w)
+            fold = mode == "sampled" and self.sample_fold and aid is None
+            if aid is not None:
+                # adapter-carrying batch: the ADAPTER-AWARE program (the
+                # plain program stays untouched — and with megakernel=
+                # on, this dispatch IS the documented op-chain fallback;
+                # same for the sampling fold, which keeps the
+                # materialized arm)
+                if self.megakernel:
+                    self.adapter_mk_fallbacks += 1
+                fn = self._cb_step_ad_fns.get(w)
+                if fn is None:
+                    fn = self._build_cb_step(w, with_adapters=True)
+                    self._cb_step_ad_fns[w] = fn
+                    self._program_built = True
+                args = (self.weights, self._apool.device,
+                        jnp.asarray(aid))
             else:
-                logits, self.k_pages, self.v_pages = out
-                toks = self._select_tokens(rows, positions, mode,
-                                           logits=logits)
+                fn = self._cb_step_fns.get((w, mode))
+                if fn is None:
+                    fn = self._build_cb_step(w, mode=mode)
+                    self._cb_step_fns[(w, mode)] = fn
+                    self._program_built = True
+                args = (self.weights,)
+            # the new token of the row fed at position lens occupies
+            # position lens+1 — its PRNG counter (BEFORE the increment)
+            positions = self._lens_np[:w] + 1
+            rows = [None] * w
+            for r in decodes:
+                rows[r.slot] = r
+        t_dev = time.perf_counter()
+        with self._first_call_span(), _span("cb.decode_step"):
+            # dispatch returns without waiting for the device; fetch is
+            # where the host blocks (an eager selection program, then
+            # the tokens' copy to the host)
+            with _span("cb.decode.dispatch"):
+                out = fn(
+                    *args, jnp.asarray(self._tok_np[:w]), self.k_pages,
+                    self.v_pages, jnp.asarray(self._tables_np[:w]),
+                    jnp.asarray(self._lens_np[:w]), jnp.asarray(active))
+            with _span("cb.decode.fetch"):
+                if fold:
+                    topv, topi, self.k_pages, self.v_pages = out
+                    toks = self._select_tokens(rows, positions, mode,
+                                               topv=topv, topi=topi)
+                else:
+                    logits, self.k_pages, self.v_pages = out
+                    toks = self._select_tokens(rows, positions, mode,
+                                               logits=logits)
         self.dispatch_seconds += time.perf_counter() - t_dev
-        for r in decodes:
-            self._lens_np[r.slot] += 1
-            self._push_token(r, toks[r.slot])
+        with _span("cb.decode.push"):
+            for r in decodes:
+                self._lens_np[r.slot] += 1
+                self._push_token(r, toks[r.slot])
 
     # -- fused multi-step decode (device-resident blocks) ------------------
     def _idle_or_raise(self):
@@ -3090,6 +3119,7 @@ class ContinuousBatchingEngine(LLMEngine):
             fn = self._build_cb_fused(w, with_prefill, with_decode,
                                       with_adapters, mode=mode)
             self._cb_fused_fns[key] = fn
+            self._program_built = True
         return fn
 
     def _fused_step(self):
@@ -3273,7 +3303,7 @@ class ContinuousBatchingEngine(LLMEngine):
         t_dev = time.perf_counter()
         spec_args = ((jnp.asarray(drafts_np), jnp.asarray(dlen_np))
                      if T else ())
-        with _prof_span("cb.block"):
+        with self._first_call_span(), _span("cb.block"):
             (blk.first, blk.toks, blk.emitted, blk.tok_fin, blk.lens_fin,
              blk.act_fin, blk.rem_fin, self.k_pages,
              self.v_pages) = fn(
@@ -3372,7 +3402,7 @@ class ContinuousBatchingEngine(LLMEngine):
                      jnp.asarray(np.zeros(w, np.int32)),
                      jnp.asarray(np.zeros(w, np.int32)))
             self._pf_dummies[w] = dummy
-        with _prof_span("cb.block_chain"):
+        with self._first_call_span(), _span("cb.block_chain"):
             (nxt.first, nxt.toks, nxt.emitted, nxt.tok_fin, nxt.lens_fin,
              nxt.act_fin, nxt.rem_fin, self.k_pages,
              self.v_pages) = fn(

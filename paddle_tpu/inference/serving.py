@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from ..failsafe import fault_point
+from ..profiler import RecordEvent
 from ..tensor.tensor import Tensor
 from ..autograd import tape
 from ..models.llama import LlamaForCausalLM, _rope_cache
@@ -368,16 +369,21 @@ class LLMEngine:
         self.flash_prefill_min = int(flash_prefill_min)
         self._flash = None
         self.quant_scales = quant_scales
-        self.weights = _snapshot_llama(model, quant, weight_dtype,
-                                       quant_scales)
+        # set-up phases as always-on spans (profiler.span_totals();
+        # docs/observability.md): host time, so device work dispatched
+        # inside one may finish after it
+        with RecordEvent("setup.engine.weights"):
+            self.weights = _snapshot_llama(model, quant, weight_dtype,
+                                           quant_scales)
         dtype = (jnp.bfloat16 if jax.default_backend() != "cpu"
                  else jnp.float32)
         self.kv_dtype = dtype
         L = cfg.num_hidden_layers
-        self.k_pages = [jnp.zeros((self.n_pages, page_size, self.nh_kv, self.hd),
-                                  dtype) for _ in range(L)]
-        self.v_pages = [jnp.zeros((self.n_pages, page_size, self.nh_kv, self.hd),
-                                  dtype) for _ in range(L)]
+        with RecordEvent("setup.engine.kv_pool"):
+            self.k_pages = [jnp.zeros((self.n_pages, page_size, self.nh_kv, self.hd),
+                                      dtype) for _ in range(L)]
+            self.v_pages = [jnp.zeros((self.n_pages, page_size, self.nh_kv, self.hd),
+                                      dtype) for _ in range(L)]
         self.allocator = PageAllocator(self.n_pages)
         self._step_fn = None
         self._prefill_fns = {}

@@ -68,23 +68,35 @@ def export_chrome_tracing(dir_name, worker_name=None):
     return handler
 
 
-def spans_active():
-    """True while a Profiler is RECORDING (its statistics collector is
-    live). The engine's dispatch sites gate their RecordEvent spans on
-    this — one cheap check, zero per-dispatch cost when no profiler is
-    attached."""
-    return _statistic._collector() is not None
+def span_totals():
+    """{name: (count, seconds)} over every RecordEvent span ended in this
+    process so far: cumulative, never reset, there whether or not any
+    profiler runs. Counted at the span's own boundary, so a ratio of two
+    entries is measured where the work happens. A plain dict updated
+    under the GIL: exact on one thread, good enough across a router's."""
+    return {name: (t[0], t[1]) for name, t in list(_TOTALS.items())}
 
 
-class RecordEvent:
-    """Span annotation (ref: profiler/utils.py RecordEvent); lowers to
-    jax.profiler.TraceAnnotation so spans appear in XLA traces."""
+class RecordEvent(contextlib.ContextDecorator):
+    """Span annotation, context manager or decorator as the reference's
+    is (ref: profiler/utils.py RecordEvent); lowers to
+    jax.profiler.TraceAnnotation, so the span lands on the host plane of
+    ANY active jax.profiler session, on the device lines' clock. Always
+    on: with no session a span costs about a microsecond, and its count
+    and seconds still reach span_totals(). `stats` become the
+    annotation's metadata (RecordEvent("cb.step", step=7))."""
 
-    def __init__(self, name, event_type=None):
+    def __init__(self, name, event_type=None, **stats):
         self.name = name
+        self._stats = stats
         self._ann = None
         self.begin_ts = None
         self.end_ts = None
+
+    def _recreate_cm(self):
+        # as a decorator: a span of its own per call, so calls on two
+        # threads (or a call inside a call) do not share begin_ts
+        return RecordEvent(self.name, **self._stats)
 
     def __enter__(self):
         self.begin()
@@ -97,7 +109,8 @@ class RecordEvent:
     def begin(self):
         self.begin_ts = time.perf_counter()
         try:
-            self._ann = jax.profiler.TraceAnnotation(self.name)
+            self._ann = jax.profiler.TraceAnnotation(self.name,
+                                                     **self._stats)
             self._ann.__enter__()
         except Exception:
             self._ann = None    # span timing still records host-side
@@ -109,11 +122,19 @@ class RecordEvent:
         if self.begin_ts is None:
             return              # end() without begin(): nothing to record
         self.end_ts = time.perf_counter()
+        total = _TOTALS.get(self.name)
+        if total is None:
+            total = _TOTALS[self.name] = [0, 0.0]
+        total[0] += 1
+        total[1] += self.end_ts - self.begin_ts
         _EVENTS.append((self.name, self.begin_ts, self.end_ts))
         c = _statistic._collector()
         if c is not None:
             c.record_span(self.name, self.begin_ts, self.end_ts)
 
+
+# name -> [count, seconds], read through span_totals()
+_TOTALS = {}
 
 # span timeline consumed by Profiler.export — BOUNDED (a serving loop
 # emits one span per dispatch; an unbounded list was a leak the moment

@@ -1334,6 +1334,10 @@ class ContinuousBatchingEngine(LLMEngine):
             # + greedy argmax inside the same invocation)
             "megakernel": self.megakernel if self.megakernel else "off",
             "megakernel_whole_step": self._mk_head,
+            # MXU operand type of every weight-matmul tile, decided at
+            # build from the activation and weight dtypes
+            # (quantized_matmul.mm_operand_dtype): static, a fact
+            "mm_operand_dtype": self.mm_operand_dtype,
             # tensor parallelism (inference/tp.py): shard count, tail
             # mode, and whether the per-token reduce rides int8
             "tp": self.tp,

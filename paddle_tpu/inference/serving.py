@@ -34,7 +34,9 @@ from ..models.llama import LlamaForCausalLM, _rope_cache
 from ..ops.pallas.paged_attention import (expand_kv_heads,
                                           paged_attention,
                                           paged_attention_reference)
-from ..ops.pallas.quantized_matmul import quantized_matmul, quantize_weights
+from ..ops.pallas.quantized_matmul import (mm_operand_dtype,
+                                           quantized_matmul,
+                                           quantize_weights)
 
 
 class EngineFullError(RuntimeError):
@@ -375,6 +377,13 @@ class LLMEngine:
         with RecordEvent("setup.engine.weights"):
             self.weights = _snapshot_llama(model, quant, weight_dtype,
                                            quant_scales)
+        # the type the weight matmuls' operands reach the MXU in: the
+        # kernels' own rule on this engine's activation and weight
+        # dtypes (static; health()["mm_operand_dtype"])
+        head = self.weights["head"]
+        self.mm_operand_dtype = jnp.dtype(mm_operand_dtype(
+            self.weights["emb"].dtype,
+            (head[0] if isinstance(head, tuple) else head).dtype)).name
         dtype = (jnp.bfloat16 if jax.default_backend() != "cpu"
                  else jnp.float32)
         self.kv_dtype = dtype

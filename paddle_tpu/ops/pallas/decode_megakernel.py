@@ -39,8 +39,11 @@ WHOLE tensor program):
     own padded tile grid).
 
 Numerics are kept step-for-step identical to the unfused engine path:
-matmul k-tiling shares `quantized_matmul`'s tile bodies (f32
-accumulate, per-channel scale at emission), norms share
+matmul k-tiling shares `quantized_matmul`'s tile bodies (MXU operands
+in the type `mm_operand_dtype` picks from the activation and weight
+dtypes — bf16 for a bf16 engine, whose f32 row scratches are passed as
+the bf16 they hold; f32 otherwise — f32 accumulate, per-channel scale
+at emission), norms share
 `rms_norm.rms_rows`, single-token attention runs the decode kernel's
 per-page online softmax, the tq variant the ragged kernel's (shared
 mask helper), and the HEAD running argmax reproduces `jnp.argmax`'s
@@ -81,8 +84,9 @@ _MM_SRC = {PH_Q: ("q", "x"), PH_K: ("k", "x"), PH_V: ("v", "x"),
            PH_D: ("d", "act")}
 
 # default streaming tile sizes; k matches quantized_matmul's bk=512 so
-# the f32 accumulation order (and therefore the bits) agree with the
-# unfused engine path
+# the f32 accumulation order ACROSS k-tiles (and therefore the bits)
+# agrees with the unfused engine path, whatever type the operands of one
+# tile's product are fed in. bn does not enter that order.
 DEF_BK = 512
 DEF_BN = 512
 
@@ -364,8 +368,9 @@ def _mk_kernel(*args, names, seg, stacked, counts, bks, bns, dims,
             @pl.when(a0 == 0)
             def _():
                 acc[...] = jnp.zeros_like(acc)
+            # cdtype: attn_scr is an f32 container of cdtype values
             acc[:, :bn] += dot_tile_f32(x_src[:, pl.ds(a0 * bk, bk)],
-                                        wblk("w" + wkey))
+                                        wblk("w" + wkey), cdtype)
 
             @pl.when(a0 == nk - 1)
             def _():
@@ -605,7 +610,7 @@ def _mk_kernel(*args, names, seg, stacked, counts, bks, bns, dims,
             def _():
                 acc[...] = jnp.zeros_like(acc)
             acc[:, :bnh] += dot_tile_f32(xs[:, pl.ds(a0 * bkh2, bkh2)],
-                                         refs["wh"][...])
+                                         refs["wh"][...], cdtype)
 
             @pl.when(a0 == nkh - 1)
             def _():

@@ -2,18 +2,28 @@
 
 Serving-path GEMM: weights live in HBM as int8 + per-output-channel fp
 scales (produced by the PTQ observers in paddle_tpu.quantization), halving
-weight bandwidth — the decode bottleneck. Dequantization happens in VMEM
-right before the MXU pass (ref: the reference's int8
-fused_multi_transformer variant, fused_multi_transformer_int8_op.cu).
+weight bandwidth — the decode bottleneck. The int8 tile is only CONVERTED
+in VMEM right before the MXU pass (ref: the reference's int8
+fused_multi_transformer variant, fused_multi_transformer_int8_op.cu);
+the dequantizing multiply by the scale happens once, on the finished
+accumulator:
 
   out[m, n] = (sum_k x[m, k] * w_int8[k, n]) * scale[n]
 
 The k-loop is the innermost grid dimension with an f32 VMEM accumulator;
 the per-channel scale is applied once at emission.
 
-The two tile bodies — `dot_tile_f32` (one k-tile MXU step) and
-`scale_emit` (per-channel dequant at emission) — are module-level so the
-decode megakernel (ops/pallas/decode_megakernel) runs the SAME ops in
+The MXU operand type is decided from the two tile dtypes alone
+(`mm_operand_dtype`): bf16 activations against int8 or bf16 weights
+multiply as bf16 x bf16 — every operand is exact in bf16, every product
+exact in f32, ONE pass through the MXU — and anything else (f32 or f16
+activations) multiplies as f32 x f32 at the package's "highest"
+precision, which on the chip is a six-pass product. The result and the
+accumulator are f32 either way.
+
+The two tile bodies — `dot_tile_f32` (one k-tile MXU step, f32 RESULT)
+and `scale_emit` (per-channel dequant at emission) — are module-level so
+the decode megakernel (ops/pallas/decode_megakernel) runs the SAME ops in
 the same order: its streamed per-layer matmuls are bit-identical to this
 standalone kernel because they share these definitions, not because two
 copies happen to agree.
@@ -27,13 +37,38 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 
-def dot_tile_f32(x_tile, w_tile):
-    """One k-tile partial product in f32: x [m, bk] @ w [bk, bn].
+def mm_operand_dtype(x_dtype, w_dtype):
+    """The type both operands of one k-tile product are fed to the MXU
+    in, from what the tile body can observe: bfloat16 when the
+    activations are (logically) bf16 and the weight tile is int8 or
+    bf16 — both casts are exact, a bf16 x bf16 product is exact in f32,
+    and the MXU accumulates in f32, so this is the f32 product of the
+    same operands less the five passes that multiplied zeros — and
+    float32 for everything else. The ONE rule: `dot_tile_f32` applies
+    it, the engine reports it (`health()["mm_operand_dtype"]`)."""
+    bf16 = jnp.dtype(jnp.bfloat16)
+    if jnp.dtype(x_dtype) == bf16 and jnp.dtype(w_dtype) in (
+            bf16, jnp.dtype(jnp.int8)):
+        return jnp.bfloat16
+    return jnp.float32
+
+
+def dot_tile_f32(x_tile, w_tile, x_dtype=None):
+    """One k-tile partial product, f32 result: x [m, bk] @ w [bk, bn].
     int8 (or any sub-f32) tiles dequantize by the .astype alone — the
-    per-channel scale is applied once, at emission (scale_emit)."""
+    per-channel scale is applied once, at emission (scale_emit).
+    x_dtype: the activations' LOGICAL dtype where the tile is a wider
+    container of rounded values (the megakernel's f32 row scratches);
+    default the tile's own."""
+    op = mm_operand_dtype(x_tile.dtype if x_dtype is None else x_dtype,
+                          w_tile.dtype)
+    # bf16 operands name DEFAULT: the package-wide "highest" (what None
+    # resolves to, and what the f32 product keeps) would ask Mosaic for
+    # an f32 contraction of operands that are bf16
     return jax.lax.dot_general(
-        x_tile.astype(jnp.float32), w_tile.astype(jnp.float32),
-        (((1,), (0,)), ((), ())),
+        x_tile.astype(op), w_tile.astype(op), (((1,), (0,)), ((), ())),
+        precision=(jax.lax.Precision.DEFAULT if op == jnp.bfloat16
+                   else None),
         preferred_element_type=jnp.float32)
 
 

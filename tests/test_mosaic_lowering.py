@@ -151,6 +151,16 @@ class TestOtherKernelsLowering:
 
         _lower_tpu(quantized_matmul, x, w, s)
 
+    def test_quantized_matmul_int8_f32_activations(self):
+        """float32 activations keep the f32 x f32 tile product (PR 25
+        moved only bf16 activations to bf16 MXU operands)."""
+        from paddle_tpu.ops.pallas.quantized_matmul import quantized_matmul
+        x = _sds((256, 1024), jnp.float32)
+        w = _sds((1024, 1024), jnp.int8)
+        s = _sds((1024,), jnp.float32)
+
+        _lower_tpu(quantized_matmul, x, w, s)
+
     def test_paged_attention_gqa_decode(self):
         """GQA-native cache (h_kv < h_q) must lower for TPU too."""
         from paddle_tpu.ops.pallas.paged_attention import paged_attention
@@ -181,7 +191,7 @@ class TestDecodeMegakernelLowering:
     geometry (what megakernel_supported admits on a chip)."""
     H, NH, HD, FFN, V, P, MP, W = 512, 4, 128, 1024, 1024, 128, 4, 4
 
-    def _layer(self, quant):
+    def _layer(self, quant, dtype=jnp.bfloat16):
         from paddle_tpu.ops.pallas.decode_megakernel import \
             pack_decode_layer
         from paddle_tpu.ops.pallas.quantized_matmul import quantize_weights
@@ -194,25 +204,32 @@ class TestDecodeMegakernelLowering:
                   ln2=jnp.ones((H,), jnp.float32), wq=w(H, H),
                   wk=w(H, H), wv=w(H, H), wo=w(H, H), wg=w(H, F),
                   wu=w(H, F), wd=w(F, H))
-        return pack_decode_layer(ws, cdtype=jnp.bfloat16)
+        return pack_decode_layer(ws, cdtype=dtype)
 
-    def _args(self, stacked_layers=None):
+    def _args(self, stacked_layers=None, dtype=jnp.bfloat16):
         n_pages = self.W * self.MP
         pshape = (n_pages, self.P, self.NH, self.HD)
         if stacked_layers:
             pshape = (stacked_layers,) + pshape
-        return (_sds((self.W, self.H), jnp.bfloat16),
-                _sds(pshape, jnp.bfloat16),
+        return (_sds((self.W, self.H), dtype),
+                _sds(pshape, dtype),
                 _sds((self.W, self.MP), jnp.int32),
                 _sds((self.W,), jnp.int32),
-                _sds((self.W, self.HD // 2), jnp.bfloat16))
+                _sds((self.W, self.HD // 2), dtype))
 
     @pytest.mark.parametrize("quant", [False, True])
     def test_layer(self, quant):
+        self._lower_layer(quant, jnp.bfloat16)
+
+    def test_layer_int8_f32_activations(self):
+        """The float32 engine's layer call: f32 x f32 tile products."""
+        self._lower_layer(True, jnp.float32)
+
+    def _lower_layer(self, quant, dtype):
         from paddle_tpu.ops.pallas.decode_megakernel import \
             decode_megakernel
-        pack = self._layer(quant)
-        h, pages, table, lens, rope = self._args()
+        pack = self._layer(quant, dtype)
+        h, pages, table, lens, rope = self._args(dtype=dtype)
 
         def f(h_, kp, vp, tbl, ln, cos, sin):
             return decode_megakernel(h_, pack, kp, vp, tbl, ln, None, cos,

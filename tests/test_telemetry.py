@@ -217,6 +217,8 @@ ENGINE_HEALTH_KEYS = frozenset({
     "restore_failures", "demote_errors", "tier", "index_publishes",
     "index_publish_errors", "prefix_exports", "prefix_imports",
     "adapters", "preemptions", "tenants",
+    # PR 25: the weight matmuls' MXU operand type, static
+    "mm_operand_dtype",
 })
 
 ROUTER_HEALTH_KEYS = frozenset({

@@ -52,7 +52,9 @@ from .adapters import AdapterError, UnknownAdapterError
 from .sampling import (GREEDY, NEG, SamplingParams, TokenMaskAutomaton,
                        apply_penalties, fold_keys, select_from_topk,
                        stop_hit)
-from .serving import LLMEngine, EngineFullError, _rms, _mm
+from ..profiler import record_counters
+from .description import UnsupportedByDescription
+from .serving import LLMEngine, EngineFullError, _rms, _mm, _mm_f32
 from .speculative import resolve_drafter
 
 from ..ops.pallas.paged_attention import (expand_kv_heads, paged_attention,
@@ -170,7 +172,7 @@ class Request:
                  "error", "tenant", "priority", "draft_k",
                  "spec_drafted", "spec_accepted", "demote", "seated_step",
                  "idle_steps", "adapter", "adapter_released",
-                 "sampling", "counts", "gstate")
+                 "sampling", "counts", "gstate", "more_pages")
 
     def __init__(self, uid, ids, max_new_tokens, eos_token_id,
                  deadline=None, ttl_steps=None, born_step=0,
@@ -183,6 +185,10 @@ class Request:
         self.state = QUEUED
         self.slot = None
         self.pages = []                 # page ids, one per table index
+        #                                 (page group 0, a full group)
+        self.more_pages = {}            # group index -> {table index:
+        #                                 page id} of every OTHER page
+        #                                 group (serving.PageGroup)
         self.shared_idx = set()         # table indices that are READ-ONLY
         self.cow_reserve = None         # page reserved for the one
         #                                 possible copy-on-write
@@ -554,8 +560,33 @@ class ContinuousBatchingEngine(LLMEngine):
                  tier_dir=None, tier_host_cap_mb=None, oversubscribe=None,
                  tier_idle_steps=None, telemetry=None, adapters=None,
                  **kw):
+        # before the base builds the pools: a window group's pool holds
+        # the further pages of the one chunk in flight
+        self.prefill_chunk = int(prefill_chunk or page_size)
         super().__init__(model, max_len=max_len, page_size=page_size,
                          max_batch=max_batch, **kw)
+        if not self.desc.plain:
+            # what this layer description cannot do yet is refused HERE,
+            # typed, not answered wrongly later
+            windows = any(g.window is not None for g in self.groups)
+            for on, what in (
+                    (speculate not in (None, False, 1), "speculate="),
+                    (kv_tier is not None, "kv_tier="),
+                    (adapters not in (None, False), "adapters="),
+                    (int(decode_block) > 1, "decode_block > 1"),
+                    (megakernel not in (None, False), "megakernel="),
+                    (prefix_cache and (windows or len(self.groups) > 1),
+                     "prefix_cache=True (prefix sharing across window "
+                     "layers or several page groups; pass "
+                     "prefix_cache=False)")):
+                if on:
+                    raise UnsupportedByDescription(
+                        f"{what} is written for a plain description "
+                        "(every layer the same dense block with full "
+                        "causal attention); this model's layers differ "
+                        "(page groups "
+                        f"{[g.window for g in self.groups]}, routed "
+                        f"experts: {self.desc.has_experts})")
         # telemetry=: a telemetry.Telemetry instance (or True to build
         # one) threaded through every lifecycle transition — per-request
         # spans (submit/seat/TTFT/blocks/spec passes/demote/handoff/
@@ -573,7 +604,6 @@ class ContinuousBatchingEngine(LLMEngine):
             telemetry = Telemetry()
         if telemetry is not None and telemetry is not False:
             self.attach_telemetry(telemetry)
-        self.prefill_chunk = int(prefill_chunk or page_size)
         # speculate=T (>= 2): speculative decoding — every decode scan
         # step becomes a VERIFY PASS over T feed tokens (the pending
         # token + up to T-1 drafter proposals) scored through ONE
@@ -820,6 +850,16 @@ class ContinuousBatchingEngine(LLMEngine):
         #                                 automaton (grammar id 0 in
         #                                 packed proc batches)
         self._slot_used = [False] * max_batch
+        # routing counters of a description with routed experts:
+        # accumulated ON THE DEVICE inside the decode step program (rows
+        # each held expert received, experts touched, steps), folded
+        # into these host totals only when health() reads them — no
+        # sync a step. int32 on the device: health() zeroes them as it
+        # reads, so they overflow only if nobody asks for some 2^21
+        # steps on end.
+        self._route_dev = (self._route_zeros() if self.desc.has_experts
+                           else None)
+        self._route_totals = None
         # multi-LoRA adapter serving (inference/adapters.py): adapters=
         # {"rank": R, "max_adapters": N, "pool_pages": P, "page_elems":
         # E} (True = defaults) builds a page-granular ADAPTER POOL
@@ -1204,6 +1244,7 @@ class ContinuousBatchingEngine(LLMEngine):
                     self._decode_step(live)
                 self.decode_steps += 1
                 self._prefer_decode = False
+            self._count_pages()
         except Exception:
             self._abort_in_flight()
             raise
@@ -1292,8 +1333,10 @@ class ContinuousBatchingEngine(LLMEngine):
         return {"queued": len(self._queue),
                 "running": sum(1 for s in self._slots if s is not None),
                 "slots_total": self.max_batch,
-                "pages_free": self.allocator.available,
-                "pages_total": self.allocator.n_pages,
+                # sums over the page groups (one group: its own)
+                "pages_free": sum(g.allocator.available
+                                  for g in self.groups),
+                "pages_total": sum(g.n_pages for g in self.groups),
                 # oversubscription gauges: device pages parked in the
                 # KV tier, and how many requests are parked (a router
                 # weighs these against raw pages_free)
@@ -1306,13 +1349,44 @@ class ContinuousBatchingEngine(LLMEngine):
         lifetime counters a monitor alarms on."""
         states = collections.Counter(
             r.state for r in self._requests.values())
+        groups = [{"window": g.window, "layers": len(g.layers),
+                   "kv_heads": g.n_kv_heads, "pages_total": g.n_pages,
+                   "pages_free": g.allocator.available,
+                   "pages_used": g.used,
+                   "used_page_steps": g.used_page_steps,
+                   "freed_behind_window": g.freed_behind_window}
+                  for g in self.groups]
+        experts = None
+        if self.desc.has_experts:
+            route = self._route_read()
+            experts = {"rows": route["rows"].tolist(),
+                       "touched": route["touched"].tolist(),
+                       "decode_steps": int(route["steps"])}
+        # one timestamped sample of the always-on counters, beside
+        # profiler.span_totals() (docs/observability.md): a reader that
+        # knows two moments differences the samples nearest them
+        counters = {"steps": self.steps}
+        for i, g in enumerate(groups):
+            counters[f"group{i}.used_page_steps"] = g["used_page_steps"]
+            counters[f"group{i}.pages_total"] = g["pages_total"]
+            counters[f"group{i}.window"] = g["window"] or 0
+            counters[f"group{i}.freed_behind_window"] = \
+                g["freed_behind_window"]
+        if experts is not None:
+            counters["experts.decode_steps"] = experts["decode_steps"]
+            counters["experts.rows"] = experts["rows"]
+            counters["experts.touched"] = experts["touched"]
+        record_counters("engine", counters)
         return {
             "queued": len(self._queue),
             "running": sum(1 for s in self._slots if s is not None),
             "slots_total": self.max_batch,
             "queue_limit": self.queue_limit,
-            "pages_free": self.allocator.available,
-            "pages_total": self.allocator.n_pages,
+            # sums over the page groups; per group under "page_groups"
+            "pages_free": sum(g["pages_free"] for g in groups),
+            "pages_total": sum(g["pages_total"] for g in groups),
+            "page_groups": groups,
+            "experts": experts,
             "prefix_pages": 0 if self._prefix is None else len(self._prefix),
             "prefix_hits": 0 if self._prefix is None else self._prefix.hits,
             "done": states[DONE],
@@ -1428,6 +1502,7 @@ class ContinuousBatchingEngine(LLMEngine):
         engine (raises RuntimeError otherwise) and (b) drops the prefix
         cache afterwards — cached pages may alias the clobbered slots.
         """
+        self._require_plain("probe_device_step_seconds()")
         if any(s is not None for s in self._slots) or self._queue \
                 or self._demoted:
             raise RuntimeError(
@@ -1574,6 +1649,10 @@ class ContinuousBatchingEngine(LLMEngine):
         if r.pages:
             self.allocator.free(r.pages)
             r.pages = []
+        for gi, held in r.more_pages.items():
+            if held:
+                self.groups[gi].allocator.free(list(held.values()))
+        r.more_pages = {}
         if r.cow_reserve is not None:
             self.allocator.free([r.cow_reserve])
             r.cow_reserve = None
@@ -1620,7 +1699,10 @@ class ContinuousBatchingEngine(LLMEngine):
             if self._prefix is None or r.adapter is not None else \
             self._prefix.match(r.ids)
         resume = min(covered, r.t0 - 1)
-        need = self._pages_needed(r.t0, r.max_new_tokens)
+        # group 0 claims here only if it is a full group; a window
+        # group claims as it writes (_group_prepare)
+        need = (self._pages_needed(r.t0, r.max_new_tokens)
+                if self.groups[0].window is None else 0)
         n_shared = len(shared)
         cow = 1 if n_shared and resume // self.page_size < n_shared \
             else 0
@@ -1655,6 +1737,12 @@ class ContinuousBatchingEngine(LLMEngine):
                 if fresh > self.allocator.available and self._prefix:
                     self._prefix.evict(fresh - self.allocator.available,
                                        self.allocator)
+            more_full = [g for g in self.groups[1:] if g.window is None]
+            n_log = self._pages_needed(r.t0, r.max_new_tokens)
+            if any(n_log > g.allocator.available for g in more_full):
+                # a further full group holds the same pages per sequence
+                # as group 0 and shares nothing: wait like page pressure
+                fresh = max(fresh, self.allocator.available + 1)
             if fresh > self.allocator.available:
                 # page pressure: a strictly-higher-priority candidate may
                 # preempt a lower-priority running request to free its
@@ -1672,6 +1760,7 @@ class ContinuousBatchingEngine(LLMEngine):
             # page this request already claimed and retires ONLY this
             # request — the pool stays consistent and admission moves on
             pages = []
+            more = {g.index: {} for g in self.groups[1:]}
             try:
                 fault_point("cb.admit", detail=f"uid={r.uid}")
                 for pg in shared:
@@ -1679,9 +1768,15 @@ class ContinuousBatchingEngine(LLMEngine):
                 for _ in range(need - n_shared):
                     pages.append(self.allocator.alloc())
                 r.cow_reserve = self.allocator.alloc() if cow else None
+                for g in more_full:
+                    for idx in range(n_log):
+                        more[g.index][idx] = g.allocator.alloc()
             except Exception as e:
                 if pages:
                     self.allocator.free(pages)
+                for gi, held in more.items():
+                    if held:
+                        self.groups[gi].allocator.free(list(held.values()))
                 self._fail_request(r, "admit", e)
                 continue
             if self._prefix is not None:
@@ -1690,6 +1785,7 @@ class ContinuousBatchingEngine(LLMEngine):
                 else:
                     self._prefix.misses += 1
             r.pages = pages
+            r.more_pages = more
             r.shared_idx = set(range(n_shared))
             r.pages_shared = n_shared
             r.slot = slot
@@ -1699,6 +1795,9 @@ class ContinuousBatchingEngine(LLMEngine):
             self._slots[slot] = r
             self._tables_np[slot] = 0
             self._tables_np[slot, :len(pages)] = pages
+            for gi, held in more.items():
+                for idx, pg in held.items():
+                    self._tables_np[slot, self.groups[gi].col0 + idx] = pg
             self._lens_np[slot] = 0
             self.admissions += 1
             if self._tel is not None:
@@ -1714,6 +1813,48 @@ class ContinuousBatchingEngine(LLMEngine):
         if self._prefix is None:
             return 0
         return self._prefix.evict(n, self.allocator)
+
+    # -- window groups: claim as written, free behind the window -----------
+    def _group_prepare(self, r, lo_pos, hi_pos):
+        """Before a program writes positions [lo_pos, hi_pos) of `r`:
+        every window group claims the pages those positions fall in.
+        The group's pool cannot run out (PageGroup sizes it for every
+        seat's resting pages plus one chunk in flight)."""
+        p = self.page_size
+        for g in self.groups:
+            if g.window is None:
+                continue
+            held = r.more_pages.setdefault(g.index, {})
+            for idx in range(lo_pos // p, (hi_pos - 1) // p + 1):
+                if idx not in held:
+                    held[idx] = g.allocator.alloc()
+                    self._tables_np[r.slot, g.col0 + idx] = held[idx]
+            assert len(held) <= g.bound(hi_pos - lo_pos), \
+                (len(held), g.window, lo_pos, hi_pos)
+
+    def _group_release(self, r, next_pos):
+        """After the write: the next query of `r` sits at `next_pos` and
+        sees keys >= next_pos - window + 1, so a page whose last
+        position lies below that is behind EVERY query still to come —
+        free it (its table entry is never read again: the kernels walk
+        from the window's first page)."""
+        p = self.page_size
+        for g in self.groups:
+            held = r.more_pages.get(g.index)
+            if g.window is None or not held:
+                continue
+            for idx in [i for i in held
+                        if (i + 1) * p <= next_pos - g.window + 1]:
+                # (the table keeps the dead entry: a transfer of this row
+                # may still be reading the host array)
+                g.allocator.free([held.pop(idx)])
+                g.freed_behind_window += 1
+
+    def _count_pages(self):
+        """One step ran: every group's pages in use, summed for the
+        `pages_used_share_*` means (health())."""
+        for g in self.groups:
+            g.used_page_steps += g.used
 
     # -- copy-on-write -----------------------------------------------------
     def _build_copy(self):
@@ -1769,51 +1910,90 @@ class ContinuousBatchingEngine(LLMEngine):
         carry the delta too, or its cache would diverge from a
         dedicated engine's)."""
         p = self.page_size
-        mp = self.max_pages_per_seq
+        mp = self.pages_per_seq
+        layer_group = self.desc.layer_group
 
         def prefill(W, ids, k_pages_all, v_pages_all, table, t_start,
                     t_end, AD=None, aid=None):
             ad = None if AD is None else (AD, aid)
-            h = jnp.take(W["emb"], ids, axis=0).astype(self.kv_dtype)
+            h = jnp.take(W["emb"], ids, axis=0).astype(
+                jnp.float32 if self.f32_stream else self.kv_dtype)
             pos = t_start + jnp.arange(chunk, dtype=jnp.int32)
             pos_ids = pos[None, :]
-            oob = jnp.int32(self.n_pages * p)
             new_k, new_v = [], []
             for li, wset in enumerate(W["layers"]):
+                a = self.desc.layers[li].attn
+                g = self.groups[layer_group[li]]
+                # this layer's group: its columns of the page table, its
+                # pool shape (the LOCAL kv heads under tp, which only a
+                # plain description runs)
+                tab = table[0, g.col0:g.col0 + mp]
+                nkv = a.n_kv_heads // self.tp
+                oob = jnp.int32(g.n_pages * p)
                 ad_li = None if ad is None else \
                     self._ad_sel(AD, aid, li)
-                q, k, v = self._layer_qkv(W, wset, h, pos_ids, ad=ad_li)
-                slots = table[0, pos // p] * p + pos % p
+                q, k, v = self._layer_qkv(W, wset, h, pos_ids, ad=ad_li,
+                                          li=li)
+                slots = tab[pos // p] * p + pos % p
                 # padded tail positions (>= the true prompt end) write
                 # NOTHING — scatter-drop, so cached pages stay garbage-
                 # free and shared pages are never touched
                 slots = jnp.where(pos < t_end, slots, oob)
-                kp = k_pages_all[li].reshape(-1, self.nh_kv_l, self.hd)
-                vp = v_pages_all[li].reshape(-1, self.nh_kv_l, self.hd)
-                kp = kp.at[slots].set(k[0].astype(self.kv_dtype),
-                                      mode="drop")
+                vp = v_pages_all[li].reshape(-1, nkv, a.v_dim)
                 vp = vp.at[slots].set(v[0].astype(self.kv_dtype),
                                       mode="drop")
-                kp = kp.reshape(self.n_pages, p, self.nh_kv_l, self.hd)
-                vp = vp.reshape(self.n_pages, p, self.nh_kv_l, self.hd)
+                vp = vp.reshape(g.n_pages, p, nkv, a.v_dim)
+                k_pool = k_pages_all[li].shape  # flat or by head
+                kp = k_pages_all[li].reshape((-1,) + k_pool[2:])
+                kp = kp.at[slots].set(
+                    k[0].astype(self.kv_dtype).reshape(
+                        (chunk,) + k_pool[2:]), mode="drop")
+                kp = kp.reshape(k_pool)
                 k_pages_all = _pools_put(k_pages_all, li, kp, new_k)
                 v_pages_all = _pools_put(v_pages_all, li, vp, new_v)
-                # gather this sequence's full context back out of the
-                # pool: [mp*p, h_kv, d]; keys past the causal horizon
-                # carry finite garbage and mask to exact zero weight
-                ck = kp[table[0]].reshape(mp * p, self.nh_kv_l, self.hd)
-                cv = vp[table[0]].reshape(mp * p, self.nh_kv_l, self.hd)
-                ck = expand_kv_heads(ck, self.nh_l)
-                cv = expand_kv_heads(cv, self.nh_l)
+                # gather this sequence's context back out of the pool:
+                # [pages*p, h_kv, d]; keys past the causal horizon carry
+                # finite garbage and mask to exact zero weight. A full
+                # layer gathers every logical page; a window layer only
+                # the pages the chunk's windows can touch (the ones
+                # behind them are freed and their table entries dead)
+                if a.window is None:
+                    page_ix = jnp.arange(mp, dtype=jnp.int32)
+                    live = None
+                else:
+                    n_ctx = min(mp, g.bound(chunk))
+                    first = jnp.maximum(t_start - a.window + 1, 0) // p
+                    page_ix = first + jnp.arange(n_ctx, dtype=jnp.int32)
+                    live = jnp.repeat(page_ix < mp, p)
+                    page_ix = jnp.minimum(page_ix, mp - 1)
+                n_keys = page_ix.shape[0] * p
+                ck = kp[tab[page_ix]].reshape(n_keys, nkv, a.qk_dim)
+                cv = vp[tab[page_ix]].reshape(n_keys, nkv, a.v_dim)
+                ck = expand_kv_heads(ck, q.shape[2])
+                cv = expand_kv_heads(cv, q.shape[2])
                 logits = jnp.einsum("qhd,khd->hqk", q[0], ck) \
-                    / math.sqrt(self.hd)
-                kpos = jnp.arange(mp * p)[None, None, :]
+                    / math.sqrt(a.qk_dim)
+                kpos = (page_ix[:, None] * p + jnp.arange(
+                    p, dtype=jnp.int32)[None, :]).reshape(
+                        n_keys)[None, None, :]
                 qpos = pos[None, :, None]
-                logits = jnp.where(kpos <= qpos, logits, -1e30)
-                w = jax.nn.softmax(logits.astype(jnp.float32),
-                                   -1).astype(q.dtype)
+                seen = kpos <= qpos
+                if a.window is not None:
+                    seen = seen & (kpos > qpos - a.window) \
+                        & live[None, None, :]
+                logits = jnp.where(seen, logits, -1e30)
+                logits = logits.astype(jnp.float32)
+                if a.sink:
+                    # the learned sink: one more term in the denominator
+                    sk = wset["sink"][:, None, None]
+                    m = jnp.maximum(jnp.max(logits, -1, keepdims=True), sk)
+                    e = jnp.exp(logits - m)
+                    w = (e / (jnp.sum(e, -1, keepdims=True)
+                              + jnp.exp(sk - m))).astype(q.dtype)
+                else:
+                    w = jax.nn.softmax(logits, -1).astype(q.dtype)
                 attn = jnp.einsum("hqk,khd->qhd", w, cv)[None]
-                h = self._layer_tail(W, wset, h, attn, ad=ad_li)
+                h = self._layer_tail(W, wset, h, attn, ad=ad_li, li=li)
             h = _rms(h, W["norm"], W["eps"])
             last = jnp.clip(t_end - 1 - t_start, 0, chunk - 1)
             h_last = jax.lax.dynamic_index_in_dim(h, last, axis=1)
@@ -1846,6 +2026,7 @@ class ContinuousBatchingEngine(LLMEngine):
             start = r.filled
             end = min(start + chunk, r.t0)
             self._make_writable(r, start, end)
+            self._group_prepare(r, start, end)
             ids_chunk = np.zeros((1, chunk), np.int64)
             ids_chunk[0, :end - start] = r.ids[start:end]
             if r.adapter is not None:
@@ -1871,7 +2052,10 @@ class ContinuousBatchingEngine(LLMEngine):
                 logits, self.k_pages, self.v_pages = fn(
                     *pre, jnp.asarray(ids_chunk), self.k_pages,
                     self.v_pages,
-                    jnp.asarray(self._tables_np[r.slot:r.slot + 1]),
+                    # a COPY of the row: the transfer may read the host
+                    # buffer after this returns, and the row changes as
+                    # the next chunk's pages are claimed
+                    jnp.asarray(self._tables_np[r.slot:r.slot + 1].copy()),
                     jnp.int32(start), jnp.int32(r.t0))
             dt = time.perf_counter() - t_dev
             self.dispatch_seconds += dt
@@ -1880,6 +2064,7 @@ class ContinuousBatchingEngine(LLMEngine):
                 self._tel.req_event(self._tel_src, r.uid, "prefill_chunk",
                                     filled=end)
             r.filled = end
+            self._group_release(r, end)
             if end < r.t0:
                 return
             # prompt complete: publish full prompt pages to the prefix
@@ -2118,6 +2303,9 @@ class ContinuousBatchingEngine(LLMEngine):
         runs it in interpret mode — the parity fallback the tests pin
         against the op-chain path."""
         from ..ops.pallas.decode_megakernel import megakernel_supported
+        if not self.desc.plain:
+            return False        # the op-chain programs (forcing it on
+            #                     was refused at construction)
         # under tp the kernel runs per shard on LOCAL head/ffn slices —
         # those are the dims Mosaic has to reslice cleanly
         ffn = self.cfg.intermediate_size
@@ -2408,7 +2596,8 @@ class ContinuousBatchingEngine(LLMEngine):
         return self._gather_logits(loc), tok_g, new_k, new_v
 
     def _cb_decode_math(self, W, tok, k_pages_all, v_pages_all, tables,
-                        lens, active, w, ad=None, topk=None):
+                        lens, active, w, ad=None, topk=None,
+                        expert_rows=None):
         """One decode step at slot-bucket width w, fully traceable
         (shared by the per-step jit and the fused multi-step scan, so
         both paths run byte-identical math): one token for every slot,
@@ -2442,34 +2631,49 @@ class ContinuousBatchingEngine(LLMEngine):
                                            active, w, topk=topk)
         AD, aid = ad if ad is not None else (None, None)
         p = self.page_size
+        mp = self.pages_per_seq
+        layer_group = self.desc.layer_group
         h = jnp.take(W["emb"], tok[:, None], axis=0).astype(
-            self.kv_dtype)
+            jnp.float32 if self.f32_stream else self.kv_dtype)
         pos_ids = lens[:, None]
-        oob = jnp.int32(self.n_pages * p)
         new_k, new_v = [], []
         for li, wset in enumerate(W["layers"]):
+            a = self.desc.layers[li].attn
+            g = self.groups[layer_group[li]]
+            # this layer's group: its columns of the page table, its
+            # pool shape (the LOCAL kv heads under tp, plain only)
+            tab = tables[:, g.col0:g.col0 + mp]
+            nkv = a.n_kv_heads // self.tp
+            oob = jnp.int32(g.n_pages * p)
             ad_li = None if ad is None else self._ad_sel(AD, aid, li)
-            q, k, v = self._layer_qkv(W, wset, h, pos_ids, ad=ad_li)
-            slots = (tables[jnp.arange(w), lens // p] * p + lens % p)
+            q, k, v = self._layer_qkv(W, wset, h, pos_ids, ad=ad_li,
+                                      li=li)
+            slots = (tab[jnp.arange(w), lens // p] * p + lens % p)
             slots = jnp.where(active, slots, oob)
-            kp = k_pages_all[li].reshape(-1, self.nh_kv_l, self.hd)
-            vp = v_pages_all[li].reshape(-1, self.nh_kv_l, self.hd)
-            kp = kp.at[slots].set(k[:, 0].astype(self.kv_dtype),
-                                  mode="drop")
+            vp = v_pages_all[li].reshape(-1, nkv, a.v_dim)
             vp = vp.at[slots].set(v[:, 0].astype(self.kv_dtype),
                                   mode="drop")
-            kp = kp.reshape(self.n_pages, p, self.nh_kv_l, self.hd)
-            vp = vp.reshape(self.n_pages, p, self.nh_kv_l, self.hd)
+            vp = vp.reshape(g.n_pages, p, nkv, a.v_dim)
+            k_pool = k_pages_all[li].shape      # flat or by head
+            kp = k_pages_all[li].reshape((-1,) + k_pool[2:])
+            kp = kp.at[slots].set(
+                k[:, 0].astype(self.kv_dtype).reshape((w,) + k_pool[2:]),
+                mode="drop")
+            kp = kp.reshape(k_pool)
             k_pages_all = _pools_put(k_pages_all, li, kp, new_k)
             v_pages_all = _pools_put(v_pages_all, li, vp, new_v)
             attn = paged_attention(
-                q[:, 0], kp, vp, tables,
+                q[:, 0], kp, vp, tab,
                 jnp.where(active, lens + 1, 0),
                 interpret=self.interpret,
-                active=active.astype(jnp.int32))
-            h = self._layer_tail(W, wset, h, attn[:, None], ad=ad_li)
+                active=active.astype(jnp.int32),
+                window=a.window, sinks=wset.get("sink"),
+                k_flat=g.k_flat)
+            h = self._layer_tail(W, wset, h, attn[:, None], ad=ad_li,
+                                 li=li, expert_rows=expert_rows)
         h = _rms(h, W["norm"], W["eps"])
-        loc = _mm(h, W["head"], self.interpret)[:, 0]
+        loc = (_mm_f32 if self.f32_stream else _mm)(
+            h, W["head"], self.interpret)[:, 0]
         if topk is not None:
             topv, topi = self._tp_topk(loc, topk)
             return (topv, topi,
@@ -2621,6 +2825,31 @@ class ContinuousBatchingEngine(LLMEngine):
         fold = mode == "sampled" and self.sample_fold
         sK = self.sample_k
 
+        if self.desc.has_experts:
+            # a description with routed experts: the same step, which
+            # also carries the routing counters through (donated, added
+            # to on the device, returned) — `route` None starts them
+            def step(W, tok, k_pages_all, v_pages_all, tables, lens,
+                     active, route=None):
+                rows = []
+                out = self._cb_decode_math(
+                    W, tok, k_pages_all, v_pages_all, tables, lens,
+                    active, w, topk=sK if fold else None,
+                    expert_rows=rows)
+                rows = jnp.stack(rows)          # [expert layers, held]
+                if route is None:
+                    route = self._route_zeros()
+                route = {"rows": route["rows"] + rows,
+                         "touched": route["touched"] + jnp.sum(
+                             rows > 0, axis=1, dtype=jnp.int32),
+                         "steps": route["steps"] + 1}
+                if fold:
+                    return out + (route,)
+                logits, _tok, kps, vps = out
+                return logits, kps, vps, route
+
+            return jax.jit(step, donate_argnums=(2, 3, 7))
+
         def step(W, tok, k_pages_all, v_pages_all, tables, lens, active):
             out = self._cb_decode_math(
                 W, tok, k_pages_all, v_pages_all, tables, lens, active,
@@ -2653,12 +2882,36 @@ class ContinuousBatchingEngine(LLMEngine):
                                        else (R, POOL, POOL)),
                             donate_argnums=(2, 3))
 
+    def _route_zeros(self):
+        """The routing counters at zero: rows each held expert received
+        [expert layers, held], experts touched [expert layers], decode
+        steps — all summed over the steps since health() last read."""
+        shape = [(layer.ffn.held[1] - layer.ffn.held[0])
+                 for layer in self.desc.layers
+                 if layer.ffn.kind == "experts"]
+        return {"rows": jnp.zeros((len(shape), shape[0]), jnp.int32),
+                "touched": jnp.zeros((len(shape),), jnp.int32),
+                "steps": jnp.zeros((), jnp.int32)}
+
+    def _route_read(self):
+        """Fold the device's routing counters into the host totals and
+        zero them (the one place they are fetched: health())."""
+        if self._route_totals is None:
+            zeros = self._route_zeros()
+            self._route_totals = {k: np.zeros(v.shape, np.int64)
+                                  for k, v in zeros.items()}
+        for k, v in jax.device_get(self._route_dev).items():
+            self._route_totals[k] += v
+        self._route_dev = self._route_zeros()
+        return self._route_totals
+
     def _decode_step(self, decodes):
         with _span("cb.decode.prepare"):
             for r in decodes:
                 # the token fed this step writes KV at position lens
                 pos = int(self._lens_np[r.slot])
                 self._make_writable(r, pos, pos + 1)
+                self._group_prepare(r, pos, pos + 1)
                 self._tok_np[r.slot] = r.tok
             w = next(b for b in self._slot_buckets
                      if b > max(r.slot for r in decodes))
@@ -2706,7 +2959,10 @@ class ContinuousBatchingEngine(LLMEngine):
                 out = fn(
                     *args, jnp.asarray(self._tok_np[:w]), self.k_pages,
                     self.v_pages, jnp.asarray(self._tables_np[:w]),
-                    jnp.asarray(self._lens_np[:w]), jnp.asarray(active))
+                    jnp.asarray(self._lens_np[:w]), jnp.asarray(active),
+                    *([self._route_dev] if self.desc.has_experts else []))
+                if self.desc.has_experts:   # the counters ride along
+                    *out, self._route_dev = out
             with _span("cb.decode.fetch"):
                 if fold:
                     topv, topi, self.k_pages, self.v_pages = out
@@ -2720,6 +2976,7 @@ class ContinuousBatchingEngine(LLMEngine):
         with _span("cb.decode.push"):
             for r in decodes:
                 self._lens_np[r.slot] += 1
+                self._group_release(r, int(self._lens_np[r.slot]))
                 self._push_token(r, toks[r.slot])
 
     # -- fused multi-step decode (device-resident blocks) ------------------
@@ -3719,6 +3976,7 @@ class ContinuousBatchingEngine(LLMEngine):
         memory (the fleet's store transport) — it stamps the payload
         and both telemetry legs, so a trace shows the transport that
         actually ran, not "host" for every non-device path."""
+        self._require_plain("KV page export")
         r = self._requests.get(uid)
         if r is None:
             raise UnknownRequestError(f"unknown request uid {uid}")
@@ -3829,6 +4087,7 @@ class ContinuousBatchingEngine(LLMEngine):
         the page claim. Any failure after the claim rolls the import
         back (pages freed, token NOT burned). `kv.import` is the fault
         point."""
+        self._require_plain("KV page import")
         from .handoff import KVHandoffError, verify_payload
         fault_point("kv.import", detail=f"token={payload.get('token')}")
         g = payload["geometry"]
@@ -4325,6 +4584,7 @@ class ContinuousBatchingEngine(LLMEngine):
         landed import, abort_prefix_export otherwise. device=True is
         the negotiated same-runtime ship (no host bounce — see
         _package_pages)."""
+        self._require_plain("prefix page export")
         if self._prefix is None:
             raise ValueError("export_prefix_pages: prefix cache disabled")
         ids = np.asarray(ids, np.int64).ravel()
@@ -4386,6 +4646,7 @@ class ContinuousBatchingEngine(LLMEngine):
         publish it to the fleet index. A request admitted next shares
         these pages exactly as if this engine had prefilled them.
         Returns the number of pages seated."""
+        self._require_plain("prefix page import")
         from .handoff import KVHandoffError, verify_payload
         if self._prefix is None:
             raise ValueError("import_prefix_pages: prefix cache disabled")
@@ -4585,7 +4846,8 @@ class ContinuousBatchingEngine(LLMEngine):
                     tel.req_done(self._tel_src, r.uid, FAILED,
                                  n_tokens=len(r.out), stage="engine")
                 r.pages = []          # pool is being rebuilt: page ids
-                r.cow_reserve = None  # are meaningless, nothing to free
+                r.more_pages = {}     # are meaningless, nothing to free
+                r.cow_reserve = None
                 r.shared_idx = set()
                 r.slot = None
                 self._slots[i] = None
@@ -4594,6 +4856,8 @@ class ContinuousBatchingEngine(LLMEngine):
         if prefix is not None:
             prefix.clear()                   # allocator is reset below
         super()._reset_kv()
+        if getattr(self, "_route_dev", None) is not None:
+            self._route_dev = self._route_zeros()   # donated with the call
         if getattr(self, "megakernel", None) == "multi":
             # restore the native stacked [L, ...] pool form (re-placed
             # on the mesh so the next sharded dispatch is zero-copy)

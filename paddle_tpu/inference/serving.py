@@ -30,7 +30,9 @@ from ..failsafe import fault_point
 from ..profiler import RecordEvent
 from ..tensor.tensor import Tensor
 from ..autograd import tape
-from ..models.llama import LlamaForCausalLM, _rope_cache
+from ..models.llama import _rope_cache
+from ..ops.moe import routed_experts
+from .description import UnsupportedByDescription, describe
 from ..ops.pallas.paged_attention import (expand_kv_heads,
                                           paged_attention,
                                           paged_attention_reference)
@@ -219,9 +221,69 @@ class PageAllocator:
         return len(self._free)
 
 
-def _snapshot_llama(model, quant, weight_dtype=None, quant_scales=None):
-    """Pull per-layer weights out of the Layer tree into plain arrays.
-    quant='int8' replaces the six projection weights of every layer (and
+# canonical per-layer names (inference/description.py): the projections
+# int8 may replace, and the leaves that stay float32 whatever the
+# engine's weight_dtype (the router's product is float32; a sink is one
+# float per head)
+_QUANT_KEYS = ("wq", "wk", "wv", "wo", "wg", "wu", "wd")
+_F32_KEYS = ("router", "router_bias", "sink")
+
+
+class PageGroup:
+    """The layers that share one page-pool shape, one page table and one
+    freeing policy: equal (KV heads, key width, value width, window).
+    A FULL group (window None) keeps every token of a sequence: its pages
+    are priced and claimed at admission, as the engine always did. A
+    WINDOW group claims a page when a position is first written and
+    frees it once its last token has fallen behind the window of every
+    query still to come, so a sequence never holds more than
+    `bound(tokens written at once)` of them."""
+
+    def __init__(self, index, key, layers, page_size, max_batch,
+                 pages_per_seq, chunk, plain=True):
+        self.index = index
+        self.n_kv_heads, self.qk_dim, self.v_dim, self.window = key
+        self.layers = tuple(layers)
+        self.page_size = page_size
+        self.col0 = index * pages_per_seq   # its columns of the table
+        # a key width that is no multiple of the 128 lanes is stored
+        # FLAT, a page [page, kv heads * width]: shaped [.., kv heads,
+        # 192] XLA gives the step program's pool parameter one layout
+        # and its pool result another and copies the whole pool between
+        # them every step (PERF.md, PR 26). A plain description keeps
+        # the shape every mode reads.
+        self.k_flat = not plain and self.qk_dim % 128 != 0
+        if self.window is None:
+            self.n_pages = max_batch * pages_per_seq
+        else:
+            # every seat's resting pages plus the further pages of the
+            # ONE chunk in flight
+            self.n_pages = max_batch * self.bound(1) \
+                + -(-chunk // page_size)
+        self.allocator = PageAllocator(self.n_pages)
+        self.freed_behind_window = 0    # lifetime, pages
+        self.used_page_steps = 0        # sum over steps of pages in use
+
+    def bound(self, tokens):
+        """Most pages one sequence holds while `tokens` positions are
+        written in one program: ceil((window + tokens) / page) + 1."""
+        return -(-(self.window + tokens) // self.page_size) + 1
+
+    @property
+    def used(self):
+        return self.n_pages - self.allocator.available
+
+    def pool_shapes(self):
+        h = self.n_kv_heads
+        k = ((self.n_pages, self.page_size, h * self.qk_dim) if self.k_flat
+             else (self.n_pages, self.page_size, h, self.qk_dim))
+        return k, (self.n_pages, self.page_size, h, self.v_dim)
+
+
+def _snapshot(model, quant, weight_dtype=None, quant_scales=None):
+    """Pull the model's serving_parameters() out of the Layer tree into
+    plain arrays under the engine's canonical names.
+    quant='int8' replaces the projection weights of every layer (and
     the lm_head) with (int8, scales) pairs; quant_scales (a
     quantization.ptq.CalibrationResult) swaps the absmax-from-weights
     scales for PTQ-calibrated ones, leaf by leaf — a leaf the
@@ -235,11 +297,12 @@ def _snapshot_llama(model, quant, weight_dtype=None, quant_scales=None):
     without ever holding the 27 GB eager-f32 tree that cannot fit the
     16 GB v5e."""
     from ..framework.misc import materialize_lazy
-    cfg = model.config
     wdt = weight_dtype  # validated jnp.dtype (or None) from LLMEngine
 
-    def take(param):
+    def take(param, f32=False):
         w = materialize_lazy(param)  # no-op for eagerly-built params
+        if f32:
+            return w.astype(jnp.float32)
         if wdt is not None and jnp.issubdtype(w.dtype, jnp.floating):
             w = w.astype(wdt)
         return w
@@ -258,24 +321,15 @@ def _snapshot_llama(model, quant, weight_dtype=None, quant_scales=None):
             return (wq, sc)
         return take(param)
 
-    layers = []
-    for li, layer in enumerate(model.llama.layers):
-        a = layer.self_attn
-        layers.append(dict(
-            ln1=take(layer.input_layernorm.weight),
-            ln2=take(layer.post_attention_layernorm.weight),
-            wq=maybe_q(a.q_proj.weight, li, "wq"),
-            wk=maybe_q(a.k_proj.weight, li, "wk"),
-            wv=maybe_q(a.v_proj.weight, li, "wv"),
-            wo=maybe_q(a.o_proj.weight, li, "wo"),
-            wg=maybe_q(layer.mlp.gate_proj.weight, li, "wg"),
-            wu=maybe_q(layer.mlp.up_proj.weight, li, "wu"),
-            wd=maybe_q(layer.mlp.down_proj.weight, li, "wd"),
-        ))
-    return dict(emb=take(model.llama.embed_tokens.weight),
-                norm=take(model.llama.norm.weight),
-                head=maybe_q(model.lm_head.weight, None, "head"),
-                layers=layers, eps=cfg.rms_norm_eps)
+    params = model.serving_parameters()
+    layers = [
+        {name: (maybe_q(param, li, name) if name in _QUANT_KEYS
+                else take(param, f32=name in _F32_KEYS))
+         for name, param in layer.items()}
+        for li, layer in enumerate(params["layers"])]
+    return dict(emb=take(params["emb"]), norm=take(params["norm"]),
+                head=maybe_q(params["head"], None, "head"),
+                layers=layers, eps=model.serving_description().eps)
 
 
 def _mm(x, w, interpret):
@@ -289,6 +343,15 @@ def _mm(x, w, interpret):
     return x @ w.astype(x.dtype)
 
 
+def _mm_f32(x, w, interpret):
+    """x @ w with a float32 result: the operands reach the MXU in the
+    weight's own dtype, the sums are float32 and are NOT rounded back to
+    it (what a float32 residual stream adds its updates from)."""
+    if isinstance(w, tuple):
+        return _mm(x, w, interpret).astype(jnp.float32)
+    return jnp.dot(x.astype(w.dtype), w, preferred_element_type=jnp.float32)
+
+
 def _rms(x, w, eps):
     var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
     return (x.astype(jnp.float32) * jax.lax.rsqrt(var + eps)).astype(x.dtype) \
@@ -296,10 +359,14 @@ def _rms(x, w, eps):
 
 
 class LLMEngine:
-    """Paged-KV decode engine for LlamaForCausalLM.
+    """Paged-KV decode engine for any model that describes itself
+    (inference/description.py): the engine reads the per-layer
+    description and the canonical parameter names, never a model class.
 
     max_batch sequences, each up to max_len tokens, share a pool of
-    (max_batch * max_len / page_size) pages per layer.
+    (max_batch * max_len / page_size) pages per full-attention layer;
+    layers of another shape or with a sliding window form page groups of
+    their own (PageGroup).
     """
 
     def __init__(self, model, max_len=1024, page_size=128, max_batch=8,
@@ -307,7 +374,7 @@ class LLMEngine:
                  weight_dtype=None, flash_prefill_min=256,
                  tp=1, tp_mode="exact", tp_compress=None,
                  quant_scales=None):
-        assert isinstance(model, LlamaForCausalLM), "LLaMA family only"
+        self.desc = desc = describe(model)
         if quant not in (None, "int8"):
             raise ValueError(f"unsupported quant {quant!r}")
         if quant_scales is not None and quant != "int8":
@@ -332,16 +399,45 @@ class LLMEngine:
         self.page_size = page_size
         self.max_len = max_len
         self.max_batch = max_batch
-        self.max_pages_per_seq = -(-max_len // page_size)
-        self.n_pages = max_batch * self.max_pages_per_seq
-        self.nh = cfg.num_attention_heads
-        self.hd = cfg.hidden_size // self.nh
+        # logical pages of one sequence; the page table a program takes
+        # is [rows, max_pages_per_seq] = every group's columns side by
+        # side (one group: the same thing)
+        self.pages_per_seq = -(-max_len // page_size)
+        self.max_pages_per_seq = self.pages_per_seq * len(desc.groups)
+        # the shapes every mode of a PLAIN description reads; for another
+        # description they are the first layer's, and the modes that read
+        # them are refused at construction
+        # the residual stream's dtype. A plain description keeps the
+        # cache's (bf16 on the chip), as every mode was written and
+        # measured. Any other carries it in float32 — the norms' inputs,
+        # the router's input and the logits are then not rounded to bf16
+        # at every layer — with bf16 operands and float32 sums in every
+        # product: routed experts turn a rounding into a DIFFERENT
+        # EXPERT, which a float32 reference then reads as a wrong token
+        # (PERF.md, PR 26: one check in nine failed with a bf16 stream)
+        self.f32_stream = not desc.plain
+        a0 = desc.layers[0].attn
+        self.nh = a0.n_heads
+        self.hd = a0.qk_dim
         # GQA checkpoints: the paged cache keeps the kv head count
-        self.nh_kv = getattr(cfg, "num_key_value_heads", self.nh) or self.nh
-        if self.nh % self.nh_kv:
-            raise ValueError(
-                f"num_attention_heads ({self.nh}) must be a multiple of "
-                f"num_key_value_heads ({self.nh_kv})")
+        self.nh_kv = a0.n_kv_heads
+        for layer in desc.layers:
+            if layer.attn.n_heads % layer.attn.n_kv_heads:
+                raise ValueError(
+                    f"num_attention_heads ({layer.attn.n_heads}) must be "
+                    f"a multiple of num_key_value_heads "
+                    f"({layer.attn.n_kv_heads})")
+        if not desc.plain:
+            if int(tp or 1) > 1:
+                raise UnsupportedByDescription(
+                    "tp > 1 shards a plain dense block by heads and "
+                    "columns; this model's layer description (layers of "
+                    "several kinds, routed experts, key width != value "
+                    "width) is served on one chip only")
+            if quant is not None and desc.has_experts:
+                raise UnsupportedByDescription(
+                    "quant='int8' has no grouped int8 product for routed "
+                    "experts yet; serve this description unquantized")
         # tensor parallelism: tp > 1 runs every compiled dispatch under
         # shard_map on a 1-D "mp" mesh — heads + KV pools sharded over
         # heads, matmuls column/row-parallel (inference/tp.py). The
@@ -375,8 +471,8 @@ class LLMEngine:
         # docs/observability.md): host time, so device work dispatched
         # inside one may finish after it
         with RecordEvent("setup.engine.weights"):
-            self.weights = _snapshot_llama(model, quant, weight_dtype,
-                                           quant_scales)
+            self.weights = _snapshot(model, quant, weight_dtype,
+                                     quant_scales)
         # the type the weight matmuls' operands reach the MXU in: the
         # kernels' own rule on this engine's activation and weight
         # dtypes (static; health()["mm_operand_dtype"])
@@ -387,13 +483,7 @@ class LLMEngine:
         dtype = (jnp.bfloat16 if jax.default_backend() != "cpu"
                  else jnp.float32)
         self.kv_dtype = dtype
-        L = cfg.num_hidden_layers
-        with RecordEvent("setup.engine.kv_pool"):
-            self.k_pages = [jnp.zeros((self.n_pages, page_size, self.nh_kv, self.hd),
-                                      dtype) for _ in range(L)]
-            self.v_pages = [jnp.zeros((self.n_pages, page_size, self.nh_kv, self.hd),
-                                      dtype) for _ in range(L)]
-        self.allocator = PageAllocator(self.n_pages)
+        self._new_pools()
         self._step_fn = None
         self._prefill_fns = {}
         self._loop_fns = {}
@@ -417,11 +507,24 @@ class LLMEngine:
         self._batch_buckets = (tuple(sorted(set(
             min(int(x), max_batch) for x in batch_buckets)))
             if batch_buckets is not None else None)
-        cos, sin = _rope_cache(max_len, self.hd, cfg.rope_theta, jnp.float32)
         # rope tables ride inside the weight pytree so the jitted
-        # prefill/step never closure-capture arrays (HLO-constant bloat)
-        self.weights["cos"] = cos
-        self.weights["sin"] = sin
+        # prefill/step never closure-capture arrays (HLO-constant bloat):
+        # one (cos, sin) pair per distinct (rotary width, base), which a
+        # plain description has one of, under the names it always had
+        kinds = []
+        for layer in desc.layers:
+            kind = (layer.attn.rope_dim, layer.attn.rope_theta)
+            if kind not in kinds:
+                kinds.append(kind)
+        self._layer_rope = tuple(
+            kinds.index((layer.attn.rope_dim, layer.attn.rope_theta))
+            for layer in desc.layers)
+        tables = [_rope_cache(max_len, d, theta, jnp.float32)
+                  for d, theta in kinds]
+        if len(tables) == 1:
+            self.weights["cos"], self.weights["sin"] = tables[0]
+        else:
+            self.weights["rope"] = tables
         if self._tpc is not None:
             # place weights + pools onto the mesh ONCE — every later
             # dispatch is zero-copy (jit would silently reshard per call
@@ -430,6 +533,45 @@ class LLMEngine:
             self.weights = self._tpc.place(self.weights, self._w_specs)
             self.k_pages = self._tpc.place_pools(self.k_pages)
             self.v_pages = self._tpc.place_pools(self.v_pages)
+
+    def _new_pools(self):
+        """Fresh page groups (allocators, counters) and zeroed pools: one
+        K and one V array per layer, shaped by the layer's group. Group 0
+        is what `allocator` / `n_pages` always named."""
+        desc, p = self.desc, self.page_size
+        chunk = int(getattr(self, "prefill_chunk", 0) or p)
+        layer_group = desc.layer_group
+        self.groups = [
+            PageGroup(gi, key,
+                      [li for li, g in enumerate(layer_group) if g == gi],
+                      p, self.max_batch, self.pages_per_seq, chunk,
+                      plain=desc.plain)
+            for gi, key in enumerate(desc.groups)]
+        self.allocator = self.groups[0].allocator
+        self.n_pages = self.groups[0].n_pages
+        with RecordEvent("setup.engine.kv_pool"):
+            self.k_pages, self.v_pages = [], []
+            for gi in layer_group:
+                group = self.groups[gi]
+                with RecordEvent(f"setup.engine.kv_pool.group{gi}"):
+                    k_shape, v_shape = group.pool_shapes()
+                    self.k_pages.append(jnp.zeros(k_shape, self.kv_dtype))
+                    self.v_pages.append(jnp.zeros(v_shape, self.kv_dtype))
+
+    def _rope_of(self, W, li):
+        """(cos, sin) tables of layer li: [max_len, rotary width / 2]."""
+        if "rope" in W:
+            return W["rope"][self._layer_rope[li]]
+        return W["cos"], W["sin"]
+
+    def _require_plain(self, what):
+        if not self.desc.plain:
+            raise UnsupportedByDescription(
+                f"{what} serves a plain description only (every layer "
+                "the same dense block, full rotary causal attention, "
+                "equal key and value widths); this model's layers differ "
+                "— serve it through ContinuousBatchingEngine.add_request "
+                "/ step at decode_block=1")
 
     @property
     def device_seconds(self):
@@ -467,7 +609,8 @@ class LLMEngine:
         the result is byte-identical to the replicated head. Callers on
         the greedy hot path should prefer _tp_greedy_token, which skips
         the gather entirely (argmax-of-local-max)."""
-        return self._gather_logits(_mm(h, W["head"], self.interpret))
+        mm = _mm_f32 if self.f32_stream else _mm
+        return self._gather_logits(mm(h, W["head"], self.interpret))
 
     def _gather_logits(self, local_logits):
         """Reassemble full-vocab logits from the vocab-parallel head's
@@ -561,7 +704,7 @@ class LLMEngine:
                                1.0 / math.sqrt(self.hd))
         return self._attn_dense(q, k, v)
 
-    def _layer_qkv(self, W, wset, h, pos_ids, ad=None):
+    def _layer_qkv(self, W, wset, h, pos_ids, ad=None, li=0):
         # head-count comes from the matmul's own width (nh_l/nh_kv_l):
         # under shard_map the column-sharded wq/wk/wv produce this
         # shard's heads only, at tp=1 the full set — same code path.
@@ -570,34 +713,56 @@ class LLMEngine:
         # (pre-rope, pre-reshape), where-gated so adapter-free rows
         # keep their exact bits; None (the default, and the only value
         # the static-generate paths ever pass) is zero-cost.
-        cos, sin = W["cos"], W["sin"]
+        # li: the layer, for its AttentionSpec (key and value widths,
+        # how many leading dims rotate and on which base, the value
+        # scale); the modes that serve plain descriptions only leave it
+        # at 0, every layer being the same.
+        a = self.desc.layers[li].attn
+        cos, sin = self._rope_of(W, li)
         b, t, H = h.shape
         x = _rms(h, wset["ln1"], W["eps"])
-        q = _mm(x, wset["wq"], self.interpret)
-        k = _mm(x, wset["wk"], self.interpret)
-        v = _mm(x, wset["wv"], self.interpret)
+        if self.f32_stream:         # operands in the cache's dtype
+            x = x.astype(self.kv_dtype)
+        if "wqkv" in wset:
+            # one fused projection, columns q | k | v: a layout of the
+            # weights, the mathematics is three projections
+            qkv = _mm(x, wset["wqkv"], self.interpret)
+            nq = a.n_heads * a.qk_dim
+            nk = a.n_kv_heads * a.qk_dim
+            q, k, v = qkv[..., :nq], qkv[..., nq:nq + nk], \
+                qkv[..., nq + nk:]
+        else:
+            q = _mm(x, wset["wq"], self.interpret)
+            k = _mm(x, wset["wk"], self.interpret)
+            v = _mm(x, wset["wv"], self.interpret)
         if ad is not None:
             from .adapters import lora_apply
             q = lora_apply(q, x, "wq", ad)
             k = lora_apply(k, x, "wk", ad)
             v = lora_apply(v, x, "wv", ad)
-        q = q.reshape(b, t, -1, self.hd)
-        k = k.reshape(b, t, -1, self.hd)
-        v = v.reshape(b, t, -1, self.hd)
+        q = q.reshape(b, t, -1, a.qk_dim)
+        k = k.reshape(b, t, -1, a.qk_dim)
+        v = v.reshape(b, t, -1, a.v_dim)
+        if a.value_scale != 1.0:
+            v = v * jnp.asarray(a.value_scale, v.dtype)
         # GQA: k/v STAY at nh_kv heads — the paged cache stores the
         # checkpoint's kv width (1/rep the HBM of an expanded cache) and
         # the decode kernel maps q head i -> kv head i // rep natively
         c = cos[pos_ids][..., None, :].astype(q.dtype)
         s = sin[pos_ids][..., None, :].astype(q.dtype)
-        d2 = self.hd // 2
+        d2 = a.rope_dim // 2
 
         def rope(x_):
-            x1, x2 = x_[..., :d2], x_[..., d2:]
-            return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], -1)
+            x1, x2 = x_[..., :d2], x_[..., d2:a.rope_dim]
+            out = [x1 * c - x2 * s, x2 * c + x1 * s]
+            if a.rope_dim < a.qk_dim:       # partial rotary: the rest
+                out.append(x_[..., a.rope_dim:])        # passes through
+            return jnp.concatenate(out, -1)
 
         return rope(q), rope(k), v
 
-    def _layer_tail(self, W, wset, h, attn_out, ad=None):
+    def _layer_tail(self, W, wset, h, attn_out, ad=None, li=0,
+                    expert_rows=None):
         # TP row-parallel pair (o_proj / down_proj): "exact" mode
         # gathers the sharded operand and runs the full matmul
         # replicated (byte-identical to tp=1 — the gather is pure data
@@ -609,12 +774,29 @@ class LLMEngine:
         # the exact-mode gather, replicated like wd itself); adapters
         # require tp_mode="exact" (gated at engine build) because the
         # down delta needs the FULL activation row.
+        # li: the layer, for its FFNSpec — a dense SwiGLU or the routed
+        # experts held here (ops/moe.py); expert_rows, a list, collects
+        # the rows each held expert received ([held] int32 per expert
+        # layer) for the engine's routing counters.
         b, t = attn_out.shape[:2]
+        # the two products whose results join the residual stream
+        mm_out = _mm_f32 if self.f32_stream else _mm
         attn_out = self._tp_gather_heads(attn_out)
-        o = _mm(attn_out.reshape(b, t, -1), wset["wo"], self.interpret)
+        o = mm_out(attn_out.reshape(b, t, -1), wset["wo"], self.interpret)
         o = self._tp_reduce(o)
         h = h + o
         x = _rms(h, wset["ln2"], W["eps"])
+        ffn = self.desc.layers[li].ffn
+        if ffn.kind == "experts":
+            y, rows = routed_experts(
+                x.reshape(b * t, -1), wset["router"], wset["router_bias"],
+                wset["w_gu"], wset["w_d"], ffn.held, ffn.top_k,
+                interpret=self.interpret)
+            if expert_rows is not None:
+                expert_rows.append(rows)
+            return h + y.reshape(b, t, -1)
+        if self.f32_stream:
+            x = x.astype(self.kv_dtype)
         g = _mm(x, wset["wg"], self.interpret)
         u = _mm(x, wset["wu"], self.interpret)
         if ad is not None:
@@ -623,7 +805,7 @@ class LLMEngine:
             u = lora_apply(u, x, "wu", ad)
         act = jax.nn.silu(g.astype(jnp.float32)).astype(g.dtype) * u
         act = self._tp_gather_cols(act)
-        d = _mm(act, wset["wd"], self.interpret)
+        d = mm_out(act, wset["wd"], self.interpret)
         if ad is not None:
             from .adapters import lora_apply
             d = lora_apply(d, act, "wd", ad)
@@ -783,14 +965,10 @@ class LLMEngine:
     def _reset_kv(self):
         """Fresh pools + allocator — a failed call's donated buffers are
         gone, and so is every in-flight sequence's cache."""
-        L = self.cfg.num_hidden_layers
-        shape = (self.n_pages, self.page_size, self.nh_kv, self.hd)
-        self.k_pages = [jnp.zeros(shape, self.kv_dtype) for _ in range(L)]
-        self.v_pages = [jnp.zeros(shape, self.kv_dtype) for _ in range(L)]
+        self._new_pools()
         if self._tpc is not None:
             self.k_pages = self._tpc.place_pools(self.k_pages)
             self.v_pages = self._tpc.place_pools(self.v_pages)
-        self.allocator = PageAllocator(self.n_pages)
 
     # -- weight snapshots (zero-downtime hot-swap substrate) ----------------
     # Derived/config entries are rebuilt at install, never serialized:
@@ -856,6 +1034,7 @@ class LLMEngine:
         stop the scan early), so the host loop remains the better mode
         when generations usually terminate long before the budget."""
         from ..models.generation import _sample
+        self._require_plain("generate() (the static-batch path)")
         ids = np.asarray(input_ids.numpy() if isinstance(input_ids, Tensor)
                          else input_ids)
         b_real, t0 = ids.shape

@@ -4,3 +4,4 @@ from .llama import (LlamaConfig, LlamaMLP, LlamaAttention, LlamaDecoderLayer,
 from .gpt import GPTConfig, GPTModel, GPTForCausalLM, gpt_pipeline_layers
 from .bert import (BertConfig, BertModel, BertForMaskedLM,
                    BertForSequenceClassification)
+from .mimo_v2 import MiMoV2Config, MiMoV2ForCausalLM

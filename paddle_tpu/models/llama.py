@@ -280,6 +280,40 @@ class LlamaForCausalLM(Layer):
             return self.criterion(logits, labels)
         return logits
 
+    # -- the serving engine's seam (inference/description.py) ---------------
+    def serving_description(self):
+        from ..inference.description import (AttentionSpec, FFNSpec,
+                                             LayerSpec, ModelDescription)
+        cfg = self.config
+        hd = cfg.hidden_size // cfg.num_attention_heads
+        layer = LayerSpec(
+            AttentionSpec(
+                n_heads=cfg.num_attention_heads,
+                n_kv_heads=(getattr(cfg, "num_key_value_heads", None)
+                            or cfg.num_attention_heads),
+                qk_dim=hd, v_dim=hd, rope_dim=hd,
+                rope_theta=float(cfg.rope_theta)),
+            FFNSpec("dense", cfg.intermediate_size))
+        return ModelDescription(
+            hidden_size=cfg.hidden_size, vocab_size=cfg.vocab_size,
+            eps=cfg.rms_norm_eps,
+            layers=(layer,) * cfg.num_hidden_layers)
+
+    def serving_parameters(self):
+        layers = []
+        for layer in self.llama.layers:
+            a, f = layer.self_attn, layer.mlp
+            layers.append(dict(
+                ln1=layer.input_layernorm.weight,
+                ln2=layer.post_attention_layernorm.weight,
+                wq=a.q_proj.weight, wk=a.k_proj.weight,
+                wv=a.v_proj.weight, wo=a.o_proj.weight,
+                wg=f.gate_proj.weight, wu=f.up_proj.weight,
+                wd=f.down_proj.weight))
+        return dict(emb=self.llama.embed_tokens.weight,
+                    norm=self.llama.norm.weight,
+                    head=self.lm_head.weight, layers=layers)
+
 
 class LlamaPretrainingCriterion(Layer):
     """Vocab-parallel CE averaged over tokens (ref analog:

@@ -53,9 +53,28 @@ def vmem_limit(blocks, scratch=(), temps=()):
     return int(min(max(need * 3 // 2, 16 << 20), 100 << 20))
 
 
+def window_first_page(first_key, p):
+    """Logical page that holds the oldest key a windowed query may see
+    (`first_key` = query position - window + 1, clamped at 0). ONE
+    definition for the index maps and the kernel bodies."""
+    return jnp.maximum(first_key, 0) // p
+
+
+def _sink_finish(m, l, acc, sink):
+    """Close an online softmax whose denominator also holds a learned
+    per-row sink logit: out = acc / (l + exp(sink - m)), computed at
+    m' = max(m, sink) so neither exponent can overflow. A fully masked
+    row (m = NEG_INF, l = 0, acc = 0) comes out as exact zeros."""
+    m_fin = jnp.maximum(m, sink)
+    beta = jnp.exp(m - m_fin)
+    return acc * beta, l * beta + jnp.exp(sink - m_fin)
+
+
 def _decode_kernel(page_table_ref, seq_lens_ref, active_ref, q_ref, k_ref,
-                   v_ref, o_ref, m_scr, l_scr, acc_scr, *, p, d, n_pages_max,
-                   scale, rep=1):
+                   v_ref, *rest, p, d, n_pages_max, scale, rep=1,
+                   window=None, has_sink=False, k_flat=False):
+    sink_ref = rest[0] if has_sink else None
+    o_ref, m_scr, l_scr, acc_scr = rest[-4:]
     b = pl.program_id(0)
     pi = pl.program_id(1)
 
@@ -66,14 +85,66 @@ def _decode_kernel(page_table_ref, seq_lens_ref, active_ref, q_ref, k_ref,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     seq_len = seq_lens_ref[b]
-    page_start = pi * p
+    if window is None:
+        page_start = pi * p
+    else:
+        # a windowed layer walks only the pages its window touches: grid
+        # column pi is logical page first + pi (same rule as the index
+        # map), the query sits at seq_len - 1
+        page_start = (window_first_page(seq_len - window, p) + pi) * p
     # whole page beyond the sequence — or a retired slot in a continuous-
     # batching step (active == 0)? skip its compute (its DMA still
     # happened — the table clamps to a valid page id, and an inactive
     # slot's index map pins every page fetch to block 0)
     run = jnp.logical_and(active_ref[b] > 0, page_start < seq_len)
 
-    @pl.when(run)
+    def _weigh(logits):
+        """Mask the page's [h, p] logits (positions past seq_len, and
+        behind the window: keys j with qpos - window < j <= qpos), fold
+        them into the running max and sum; returns (weights, the old
+        accumulators' rescale, the new max — stored by the caller after
+        the accumulator's update)."""
+        pos = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1) + page_start
+        ok = pos < seq_len
+        if window is not None:
+            ok = jnp.logical_and(ok, pos >= seq_len - window)
+        logits = jnp.where(ok, logits, jnp.float32(NEG_INF))
+        m_prev = m_scr[:, :1]
+        l_prev = l_scr[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
+        w = jnp.exp(logits - m_new)                            # [h, p]
+        alpha = jnp.exp(m_prev - m_new)
+        l_scr[...] = jnp.broadcast_to(
+            alpha * l_prev + jnp.sum(w, axis=-1, keepdims=True), l_scr.shape)
+        return w, alpha, m_new
+
+    def _compute_flat():
+        # keys stored FLAT, a page is [p, h_kv * d], and the query rows
+        # arrive spread over the same h_kv * d lanes (head i's d values
+        # in its kv head's stretch, zeros elsewhere): ONE product gives
+        # every head's logits, no per-head slice at a lane offset that
+        # is no multiple of 128. Operands reach the MXU in the pool's
+        # dtype (bf16 on the chip: one pass, named here because the
+        # package-wide "highest" reaches into kernels), the scale
+        # applied in float32 first, sums in float32
+        mxu = jnp.bfloat16 if k_ref.dtype == jnp.bfloat16 else jnp.float32
+        q = (q_ref[0].astype(jnp.float32)
+             * jnp.float32(scale)).astype(mxu)              # [h, h_kv*d]
+        logits = jax.lax.dot_general(
+            q, k_ref[0].astype(mxu), (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.DEFAULT,
+            preferred_element_type=jnp.float32)             # [h, p]
+        w, alpha, m_new = _weigh(logits)
+        v = v_ref[0].astype(mxu)                            # [p, h_kv, dv]
+        acc_scr[...] = alpha * acc_scr[...] + jnp.concatenate([
+            jax.lax.dot_general(
+                w[g * rep:(g + 1) * rep].astype(mxu), v[:, g, :],
+                (((1,), (0,)), ((), ())),
+                precision=jax.lax.Precision.DEFAULT,
+                preferred_element_type=jnp.float32)         # [rep, dv]
+            for g in range(v.shape[1])], axis=0)
+        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+
     def _compute():
         q = q_ref[0].astype(jnp.float32) * jnp.float32(scale)  # [h, d]
         k = k_ref[0].astype(jnp.float32)                       # [p, h, d]
@@ -97,25 +168,21 @@ def _decode_kernel(page_table_ref, seq_lens_ref, active_ref, q_ref, k_ref,
                 (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)            # [rep, p]
             for g in range(h_kv)], axis=0)                     # [h, p]
-        # mask positions past seq_len within this page
-        pos = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1) + page_start
-        logits = jnp.where(pos < seq_len, logits, jnp.float32(NEG_INF))
-
-        m_prev = m_scr[:, :1]
-        l_prev = l_scr[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
-        w = jnp.exp(logits - m_new)                            # [h, p]
-        alpha = jnp.exp(m_prev - m_new)
-        l_scr[...] = jnp.broadcast_to(
-            alpha * l_prev + jnp.sum(w, axis=-1, keepdims=True), l_scr.shape)
+        w, alpha, m_new = _weigh(logits)
         # [h, d] accumulation: sum_p w[h, p] * v[p, h_kv, d]
         acc_scr[...] = alpha * acc_scr[...] + wv_diag(w, v, d, rep=rep)
         m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
 
+    pl.when(run)(_compute_flat if k_flat else _compute)
+
     @pl.when(pi == n_pages_max - 1)
     def _emit():
-        l_fin = jnp.maximum(l_scr[:, :1], jnp.float32(1e-30))
-        o_ref[0] = (acc_scr[...] / l_fin).astype(o_ref.dtype)
+        acc, l_fin = acc_scr[...], l_scr[:, :1]
+        if has_sink:
+            acc, l_fin = _sink_finish(m_scr[:, :1], l_fin, acc,
+                                      sink_ref[:, :1])
+        l_fin = jnp.maximum(l_fin, jnp.float32(1e-30))
+        o_ref[0] = (acc / l_fin).astype(o_ref.dtype)
 
 
 def wv_diag(w, v, d, rep=1):
@@ -146,12 +213,22 @@ def expand_kv_heads(x, h_q):
     return jnp.repeat(x, h_q // h_kv, axis=-2)
 
 
+def _sink_rows(sinks, h, tq=1):
+    """[h] learned sink logits -> the [h*tq, 128] float32 block the
+    kernels read (row = head-major (head, token), every lane alike)."""
+    rows = jnp.repeat(sinks.astype(jnp.float32).reshape(h), tq)
+    return jnp.broadcast_to(rows[:, None], (h * tq, 128))
+
+
 def paged_attention(q, k_pages, v_pages, page_table, seq_lens, scale=None,
-                    interpret=False, active=None):
-    """q: [b, h, d]; pages: [n_pages, p, h_kv, d] with h % h_kv == 0
-    (GQA: q head i attends kv head i // (h // h_kv) — the cache is kept
-    at the CHECKPOINT's kv head count, ref GQA repeat_kv removed);
-    page_table: [b, max_pages] int32; seq_lens: [b] int32.
+                    interpret=False, active=None, window=None, sinks=None,
+                    k_flat=False):
+    """q: [b, h, d]; k_pages: [n_pages, p, h_kv, d], v_pages: [n_pages,
+    p, h_kv, dv] with h % h_kv == 0 (GQA: q head i attends kv head
+    i // (h // h_kv) — the cache is kept at the CHECKPOINT's kv head
+    count, ref GQA repeat_kv removed); the value width dv may differ from
+    the key width d. page_table: [b, max_pages] int32; seq_lens: [b]
+    int32.
 
     active: optional [b] mask (bool/int) for continuous batching — slots
     whose request has retired stay in the batch shape but skip every
@@ -159,13 +236,42 @@ def paged_attention(q, k_pages, v_pages, page_table, seq_lens, scale=None,
     block 0), so a mostly-drained decode batch costs roughly its live
     rows. None means all slots live. Inactive rows emit zeros.
 
-    Returns [b, h, d]."""
+    window: None = every key up to the query (causal); W = a sliding
+    window, the query at position seq_len - 1 sees keys j with
+    qpos - W < j <= qpos. The grid then walks only the
+    ceil(W / p) + 1 logical pages the window can touch, whatever
+    max_pages is; table entries behind the window are never read (the
+    engine frees those pages).
+    sinks: optional [h] learned per-head sink logits, added to the
+    softmax's denominator only (out = sum_j e^{l_j} v_j / (sum_j e^{l_j}
+    + e^{sink})).
+    k_flat: k_pages is [n_pages, p, h_kv * d], a token's keys of all kv
+    heads side by side. The engine keeps a key width that is no multiple
+    of the 128 lanes this way (PERF.md, PR 26: with [.., h_kv, 192] XLA
+    gives the step's pool parameter one layout and its pool result
+    another and copies the whole pool between them every step). The
+    query rows are spread over the same lanes here, zeros outside their
+    kv head's stretch, and one product gives all heads' logits.
+
+    Returns [b, h, dv]."""
     b, h, d = q.shape
-    n_pages, p, h_kv, dd = k_pages.shape
+    dv = v_pages.shape[-1]
+    h_kv = v_pages.shape[2]
+    if k_flat:
+        n_pages, p, flat = k_pages.shape
+        assert flat == h_kv * d, (q.shape, k_pages.shape, v_pages.shape)
+        dd = d
+    else:
+        n_pages, p, _, dd = k_pages.shape
+        assert k_pages.shape[2] == h_kv, (k_pages.shape, v_pages.shape)
     assert dd == d and h % h_kv == 0, (q.shape, k_pages.shape)
+    assert v_pages.shape[:3] == (n_pages, p, h_kv), (k_pages.shape,
+                                                     v_pages.shape)
     rep = h // h_kv
     max_pages = page_table.shape[1]
     s = scale if scale is not None else 1.0 / math.sqrt(d)
+    n_grid = max_pages if window is None else \
+        min(max_pages, -(-int(window) // p) + 1)
 
     # clamp table entries so skipped pages still index a real page
     table = jnp.clip(page_table.astype(jnp.int32), 0, n_pages - 1)
@@ -175,48 +281,71 @@ def paged_attention(q, k_pages, v_pages, page_table, seq_lens, scale=None,
     else:
         act = active.astype(jnp.int32)
 
+    def page_of(bb, pi, tbl, ln, ac):
+        if window is not None:
+            pi = jnp.minimum(window_first_page(ln[bb] - window, p) + pi,
+                             max_pages - 1)
+        return (tbl[bb, pi] * ac[bb], 0, 0, 0)
+
     kernel = functools.partial(_decode_kernel, p=p, d=d,
-                               n_pages_max=max_pages, scale=s, rep=rep)
+                               n_pages_max=n_grid, scale=s, rep=rep,
+                               window=window, has_sink=sinks is not None,
+                               k_flat=k_flat)
+    if k_flat:
+        # head i's d values into the lanes of kv head i // rep
+        onehot = (jnp.arange(h)[:, None] // rep
+                  == jnp.arange(h_kv)[None, :]).astype(q.dtype)
+        q = (q[:, :, None, :] * onehot[None, :, :, None]).reshape(
+            b, h, h_kv * d)
+        q_block, k_block = (1, h, h_kv * d), (1, p, h_kv * d)
+        page3 = lambda *a: page_of(*a)[:3]      # noqa: E731
+    else:
+        q_block, k_block, page3 = (1, h, d), (1, p, h_kv, d), page_of
+    in_specs = [
+        pl.BlockSpec(q_block, lambda bb, pi, tbl, ln, ac: (bb, 0, 0)),
+        pl.BlockSpec(k_block, page3),
+        pl.BlockSpec((1, p, h_kv, dv), page_of),
+    ]
+    args = [table, lens, act, q, k_pages, v_pages]
+    if sinks is not None:
+        in_specs.append(pl.BlockSpec(
+            (h, 128), lambda bb, pi, tbl, ln, ac: (0, 0)))
+        args.append(_sink_rows(sinks, h))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(b, max_pages),
-        in_specs=[
-            pl.BlockSpec((1, h, d),
-                         lambda bb, pi, tbl, ln, ac: (bb, 0, 0)),
-            pl.BlockSpec((1, p, h_kv, d),
-                         lambda bb, pi, tbl, ln, ac:
-                         (tbl[bb, pi] * ac[bb], 0, 0, 0)),
-            pl.BlockSpec((1, p, h_kv, d),
-                         lambda bb, pi, tbl, ln, ac:
-                         (tbl[bb, pi] * ac[bb], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, h, d),
+        grid=(b, n_grid),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, h, dv),
                                lambda bb, pi, tbl, ln, ac: (bb, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((h, 128), jnp.float32),
             pltpu.VMEM((h, 128), jnp.float32),
-            pltpu.VMEM((h, d), jnp.float32),
+            pltpu.VMEM((h, dv), jnp.float32),
         ],
     )
     f32 = jnp.float32
     limit = vmem_limit(
-        blocks=[((h, d), q.dtype)] * 2 + [((p, h_kv, d), k_pages.dtype)] * 2,
-        scratch=[((h, 128), f32)] * 2 + [((h, d), f32)],
-        temps=[((p, h_kv, d), f32)] * 2)
+        blocks=[((h, d), q.dtype), ((h, dv), q.dtype),
+                ((p, h_kv, d), k_pages.dtype),
+                ((p, h_kv, dv), v_pages.dtype)],
+        scratch=[((h, 128), f32)] * 2 + [((h, dv), f32)],
+        temps=[((p, h_kv, d), f32), ((p, h_kv, dv), f32)])
     with jax.enable_x64(False):
         out = pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
+            out_shape=jax.ShapeDtypeStruct((b, h, dv), q.dtype),
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary"),
                 vmem_limit_bytes=limit),
             interpret=interpret,
-        )(table, lens, act, q, k_pages, v_pages)
+            name="paged_attention_decode",
+        )(*args)
     return out
 
 
-def ragged_causal_mask(shape, tq, q_start, page_start, ctx_len):
+def ragged_causal_mask(shape, tq, q_start, page_start, ctx_len,
+                       window=None):
     """The ragged multi-token-q causal mask over a [rows, p] logits
     block whose rows are (head, token)-flattened with token MINOR (row r
     is chunk offset r % tq): key column c (global position page_start +
@@ -224,23 +353,30 @@ def ragged_causal_mask(shape, tq, q_start, page_start, ctx_len):
     global position q_start + r % tq AND inside the context. ONE
     definition shared by _ragged_kernel and the decode megakernel's
     tq>1 verify phase — the spec-verify byte-identity contract rests on
-    the two kernels computing this mask identically."""
+    the two kernels computing this mask identically. window=W adds the
+    sliding window's lower bound: kpos > qpos - W."""
     qpos = q_start + jax.lax.rem(
         jax.lax.broadcasted_iota(jnp.int32, shape, 0), jnp.int32(tq))
     kpos = jax.lax.broadcasted_iota(jnp.int32, shape, 1) + page_start
-    return jnp.logical_and(kpos <= qpos, kpos < ctx_len)
+    ok = jnp.logical_and(kpos <= qpos, kpos < ctx_len)
+    if window is not None:
+        ok = jnp.logical_and(ok, kpos > qpos - window)
+    return ok
 
 
 def _ragged_kernel(page_table_ref, ctx_lens_ref, q_starts_ref, active_ref,
-                   q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
-                   p, d, tq, n_pages_max, scale, rep=1):
+                   q_ref, k_ref, v_ref, *rest, p, d, tq, n_pages_max, scale,
+                   rep=1, window=None, has_sink=False):
     """Chunked (multi-token-q) variant of _decode_kernel: slot b carries
     tq query tokens at GLOBAL positions q_starts[b] + [0, tq); its keys
     are the slot's own pages, causally masked per query token. Query
     rows arrive (head, token)-flattened HEAD-MAJOR — row g*rep*tq + j*tq
     + qi is q head g*rep+j at chunk offset qi — so each kv head's rows
     are one contiguous [rep*tq, d] slice (same Mosaic-friendly unrolled
-    2-D dots as decode)."""
+    2-D dots as decode). window / has_sink: as in _decode_kernel, the
+    first query of the chunk deciding the first page walked."""
+    sink_ref = rest[0] if has_sink else None
+    o_ref, m_scr, l_scr, acc_scr = rest[-4:]
     b = pl.program_id(0)
     pi = pl.program_id(1)
 
@@ -252,7 +388,10 @@ def _ragged_kernel(page_table_ref, ctx_lens_ref, q_starts_ref, active_ref,
 
     ctx_len = ctx_lens_ref[b]
     q_start = q_starts_ref[b]
-    page_start = pi * p
+    if window is None:
+        page_start = pi * p
+    else:
+        page_start = (window_first_page(q_start - window + 1, p) + pi) * p
     # queries attend kpos <= q_start + qi < ctx_len: pages at/after the
     # context end contribute nothing — skip compute (an inactive slot's
     # index map additionally pins its page DMA to block 0)
@@ -274,7 +413,7 @@ def _ragged_kernel(page_table_ref, ctx_lens_ref, q_starts_ref, active_ref,
         # causal + length mask at GLOBAL positions (shared helper — the
         # megakernel's verify phase applies the identical mask)
         ok = ragged_causal_mask(logits.shape, tq, q_start, page_start,
-                                ctx_len)
+                                ctx_len, window=window)
         logits = jnp.where(ok, logits, jnp.float32(NEG_INF))
 
         m_prev = m_scr[:, :1]
@@ -291,13 +430,17 @@ def _ragged_kernel(page_table_ref, ctx_lens_ref, q_starts_ref, active_ref,
     def _emit():
         # fully-masked rows (padded chunk tail, inactive slots) have
         # l == 0 and acc == 0: the clamp emits exact zeros, never NaN
-        l_fin = jnp.maximum(l_scr[:, :1], jnp.float32(1e-30))
-        o_ref[0] = (acc_scr[...] / l_fin).astype(o_ref.dtype)
+        acc, l_fin = acc_scr[...], l_scr[:, :1]
+        if has_sink:
+            acc, l_fin = _sink_finish(m_scr[:, :1], l_fin, acc,
+                                      sink_ref[:, :1])
+        l_fin = jnp.maximum(l_fin, jnp.float32(1e-30))
+        o_ref[0] = (acc / l_fin).astype(o_ref.dtype)
 
 
 def ragged_paged_attention(q, k_pages, v_pages, page_table, ctx_lens,
                            q_starts, active=None, scale=None,
-                           interpret=False):
+                           interpret=False, window=None, sinks=None):
     """Ragged-chunk paged attention: ONE kernel invocation covers slots
     sitting at DIFFERENT positions — each slot b contributes tq query
     tokens at global positions q_starts[b] + [0, tq), attending its own
@@ -318,15 +461,22 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, ctx_lens,
                   DMA (index map pins their fetches to block 0) and emit
                   zeros.
 
-    Returns [b, tq, h, d]. Rows past a slot's real chunk length are
+    window / sinks: the sliding window's lower bound and the learned
+    per-head sink of the softmax's denominator, as in paged_attention;
+    v_pages may be narrower than k_pages ([..., dv]).
+
+    Returns [b, tq, h, dv]. Rows past a slot's real chunk length are
     garbage (they attend whatever the causal window holds) — callers
     index the rows they wrote, exactly like the padded dense prefill."""
     b, tq, h, d = q.shape
     n_pages, p, h_kv, dd = k_pages.shape
+    dv = v_pages.shape[-1]
     assert dd == d and h % h_kv == 0, (q.shape, k_pages.shape)
     rep = h // h_kv
     max_pages = page_table.shape[1]
     s = scale if scale is not None else 1.0 / math.sqrt(d)
+    n_grid = max_pages if window is None else \
+        min(max_pages, -(-(int(window) + tq - 1) // p) + 1)
 
     # rows head-major [(h, tq) -> h*tq, d]: each kv head's rep*tq query
     # rows form one contiguous slice (see _ragged_kernel)
@@ -339,49 +489,61 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, ctx_lens,
     else:
         act = active.astype(jnp.int32)
 
+    def page_of(bb, pi, tbl, ln, st, ac):
+        if window is not None:
+            pi = jnp.minimum(
+                window_first_page(st[bb] - window + 1, p) + pi,
+                max_pages - 1)
+        return (tbl[bb, pi] * ac[bb], 0, 0, 0)
+
     kernel = functools.partial(_ragged_kernel, p=p, d=d, tq=tq,
-                               n_pages_max=max_pages, scale=s, rep=rep)
+                               n_pages_max=n_grid, scale=s, rep=rep,
+                               window=window, has_sink=sinks is not None)
+    in_specs = [
+        pl.BlockSpec((1, h * tq, d),
+                     lambda bb, pi, tbl, ln, st, ac: (bb, 0, 0)),
+        pl.BlockSpec((1, p, h_kv, d), page_of),
+        pl.BlockSpec((1, p, h_kv, dv), page_of),
+    ]
+    args = [table, lens, starts, act, qr, k_pages, v_pages]
+    if sinks is not None:
+        in_specs.append(pl.BlockSpec(
+            (h * tq, 128), lambda bb, pi, tbl, ln, st, ac: (0, 0)))
+        args.append(_sink_rows(sinks, h, tq))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(b, max_pages),
-        in_specs=[
-            pl.BlockSpec((1, h * tq, d),
-                         lambda bb, pi, tbl, ln, st, ac: (bb, 0, 0)),
-            pl.BlockSpec((1, p, h_kv, d),
-                         lambda bb, pi, tbl, ln, st, ac:
-                         (tbl[bb, pi] * ac[bb], 0, 0, 0)),
-            pl.BlockSpec((1, p, h_kv, d),
-                         lambda bb, pi, tbl, ln, st, ac:
-                         (tbl[bb, pi] * ac[bb], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, h * tq, d),
+        grid=(b, n_grid),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, h * tq, dv),
                                lambda bb, pi, tbl, ln, st, ac: (bb, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((h * tq, 128), jnp.float32),
             pltpu.VMEM((h * tq, 128), jnp.float32),
-            pltpu.VMEM((h * tq, d), jnp.float32),
+            pltpu.VMEM((h * tq, dv), jnp.float32),
         ],
     )
     f32 = jnp.float32
     limit = vmem_limit(
-        blocks=[((h * tq, d), q.dtype)] * 2
-        + [((p, h_kv, d), k_pages.dtype)] * 2,
-        scratch=[((h * tq, 128), f32)] * 2 + [((h * tq, d), f32)],
+        blocks=[((h * tq, d), q.dtype), ((h * tq, dv), q.dtype),
+                ((p, h_kv, d), k_pages.dtype),
+                ((p, h_kv, dv), v_pages.dtype)],
+        scratch=[((h * tq, 128), f32)] * 2 + [((h * tq, dv), f32)],
         # the body's f32 copies: q, k, v, and the [rows, p] logits,
         # weights and mask
-        temps=[((h * tq, d), f32)] + [((p, h_kv, d), f32)] * 2
-        + [((h * tq, p), f32)] * 3)
+        temps=[((h * tq, d), f32), ((p, h_kv, d), f32),
+               ((p, h_kv, dv), f32)] + [((h * tq, p), f32)] * 3)
     with jax.enable_x64(False):
         out = pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((b, h * tq, d), q.dtype),
+            out_shape=jax.ShapeDtypeStruct((b, h * tq, dv), q.dtype),
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary"),
                 vmem_limit_bytes=limit),
             interpret=interpret,
-        )(table, lens, starts, act, qr, k_pages, v_pages)
-    return jnp.swapaxes(out.reshape(b, h, tq, d), 1, 2)
+            name="paged_attention_ragged",
+        )(*args)
+    return jnp.swapaxes(out.reshape(b, h, tq, dv), 1, 2)
 
 
 def spec_verify_attention(q, k_pages, v_pages, page_table, lens,
@@ -415,22 +577,34 @@ def spec_verify_attention(q, k_pages, v_pages, page_table, lens,
                                   interpret=interpret)
 
 
+def _sink_softmax(logits, sinks):
+    """softmax over the last axis of [h, ..., k] logits with the learned
+    per-head sink in the denominator only."""
+    if sinks is None:
+        return jax.nn.softmax(logits, axis=-1)
+    s = sinks.astype(jnp.float32).reshape((-1,) + (1,) * (logits.ndim - 1))
+    m = jnp.maximum(jnp.max(logits, -1, keepdims=True), s)
+    e = jnp.exp(logits - m)
+    return e / (jnp.sum(e, -1, keepdims=True) + jnp.exp(s - m))
+
+
 def ragged_paged_attention_reference(q, k_pages, v_pages, page_table,
                                      ctx_lens, q_starts, active=None,
-                                     scale=None):
+                                     scale=None, window=None, sinks=None):
     """XLA reference for tests: per-slot gather + dense causal softmax
     at the slot's global offset (GQA kv heads repeated)."""
     b, tq, h, d = q.shape
+    dv = v_pages.shape[-1]
     n_pages, p, h_kv, _ = k_pages.shape
     max_pages = page_table.shape[1]
     s = scale if scale is not None else 1.0 / math.sqrt(d)
     outs = []
     for i in range(b):
         if active is not None and not int(active[i]):
-            outs.append(jnp.zeros((tq, h, d), q.dtype))
+            outs.append(jnp.zeros((tq, h, dv), q.dtype))
             continue
         ks = k_pages[page_table[i]].reshape(max_pages * p, h_kv, d)
-        vs = v_pages[page_table[i]].reshape(max_pages * p, h_kv, d)
+        vs = v_pages[page_table[i]].reshape(max_pages * p, h_kv, dv)
         if h_kv != h:
             ks = jnp.repeat(ks, h // h_kv, axis=1)
             vs = jnp.repeat(vs, h // h_kv, axis=1)
@@ -439,8 +613,10 @@ def ragged_paged_attention_reference(q, k_pages, v_pages, page_table,
         kpos = jnp.arange(max_pages * p)[None, None, :]
         qpos = (int(q_starts[i]) + jnp.arange(tq))[None, :, None]
         ok = (kpos <= qpos) & (kpos < int(ctx_lens[i]))
+        if window is not None:
+            ok = ok & (kpos > qpos - window)
         logits = jnp.where(ok, logits, NEG_INF)
-        w = jax.nn.softmax(logits, axis=-1)
+        w = _sink_softmax(logits, sinks)
         # fully-masked rows: renormalize the uniform softmax to zero out
         any_ok = ok.any(-1)
         w = jnp.where(any_ok[..., None], w, 0.0)
@@ -450,25 +626,31 @@ def ragged_paged_attention_reference(q, k_pages, v_pages, page_table,
 
 
 def paged_attention_reference(q, k_pages, v_pages, page_table, seq_lens,
-                              scale=None):
+                              scale=None, window=None, sinks=None,
+                              k_flat=False):
     """XLA reference for tests: gather pages then plain softmax attention
     (GQA: kv heads repeated up to the q head count)."""
+    if k_flat:          # [n, p, h_kv * d] -> [n, p, h_kv, d]
+        k_pages = k_pages.reshape(k_pages.shape[:2] + (v_pages.shape[2],
+                                                       -1))
     b, h, d = q.shape
+    dv = v_pages.shape[-1]
     n_pages, p, h_kv, _ = k_pages.shape
     max_pages = page_table.shape[1]
     s = scale if scale is not None else 1.0 / math.sqrt(d)
     outs = []
     for i in range(b):
         ks = k_pages[page_table[i]].reshape(max_pages * p, h_kv, d)
-        vs = v_pages[page_table[i]].reshape(max_pages * p, h_kv, d)
+        vs = v_pages[page_table[i]].reshape(max_pages * p, h_kv, dv)
         if h_kv != h:
             ks = jnp.repeat(ks, h // h_kv, axis=1)
             vs = jnp.repeat(vs, h // h_kv, axis=1)
         L = int(seq_lens[i])
-        ks, vs = ks[:L], vs[:L]
+        lo = 0 if window is None else max(L - int(window), 0)
+        ks, vs = ks[lo:L], vs[lo:L]
         logits = jnp.einsum("hd,khd->hk", q[i].astype(jnp.float32),
                             ks.astype(jnp.float32)) * s
-        w = jax.nn.softmax(logits, axis=-1)
+        w = _sink_softmax(logits, sinks)
         outs.append(jnp.einsum("hk,khd->hd", w, vs.astype(jnp.float32)))
     return jnp.stack(outs).astype(q.dtype)
 

@@ -77,6 +77,24 @@ def span_totals():
     return {name: (t[0], t[1]) for name, t in list(_TOTALS.items())}
 
 
+def record_counters(source, values):
+    """Keep one timestamped sample of a component's cumulative counters
+    ({name: number}) beside span_totals(): always on, no profiler
+    needed, bounded (the newest 4096 samples). The serving engine's
+    health() samples its routing and page-group counters here, so a
+    reader that knows two moments (a benchmark window's ends) takes the
+    difference of the samples nearest them; see counter_history()."""
+    _COUNTERS.append((time.monotonic(), source, dict(values)))
+
+
+def counter_history(source=None):
+    """[(time.monotonic() stamp, {name: cumulative value})] of every
+    sample record_counters() kept (of `source`, if given), oldest
+    first."""
+    return [(t, v) for t, s, v in list(_COUNTERS)
+            if source is None or s == source]
+
+
 class RecordEvent(contextlib.ContextDecorator):
     """Span annotation, context manager or decorator as the reference's
     is (ref: profiler/utils.py RecordEvent); lowers to
@@ -135,6 +153,8 @@ class RecordEvent(contextlib.ContextDecorator):
 
 # name -> [count, seconds], read through span_totals()
 _TOTALS = {}
+# (stamp, source, {name: value}) samples, read through counter_history()
+_COUNTERS = collections.deque(maxlen=4096)
 
 # span timeline consumed by Profiler.export — BOUNDED (a serving loop
 # emits one span per dispatch; an unbounded list was a leak the moment

@@ -186,6 +186,56 @@ class TestOtherKernelsLowering:
                    lens)
 
 
+class TestHybridBlockKernelsLowering:
+    """The kernels the hybrid block adds or widens, at its published
+    widths (64 query heads, key width 192, value width 128, window 128
+    with a sink over 8 KV heads, full over 4; 16 held experts of width
+    2048)."""
+
+    @pytest.mark.parametrize("h_kv,window,sink", [(4, None, False),
+                                                  (8, 128, True)])
+    def test_decode_attention_window_sink_key_width(self, h_kv, window,
+                                                    sink):
+        from paddle_tpu.ops.pallas.paged_attention import paged_attention
+        b, h, dk, dv, p, n_pages, mp = 8, 64, 192, 128, 128, 64, 32
+        args = [_sds((b, h, dk), jnp.bfloat16),
+                _sds((n_pages, p, h_kv, dk), jnp.bfloat16),
+                _sds((n_pages, p, h_kv, dv), jnp.bfloat16),
+                _sds((b, mp), jnp.int32), _sds((b,), jnp.int32)]
+        if sink:
+            _lower_tpu(lambda q, k, v, t, ln, s: paged_attention(
+                q, k, v, t, ln, window=window, sinks=s), *args,
+                _sds((h,), jnp.float32))
+        else:
+            _lower_tpu(lambda q, k, v, t, ln: paged_attention(
+                q, k, v, t, ln, window=window), *args)
+        # how the engine keeps a 192-wide key: a page [p, h_kv * 192]
+        args[1] = _sds((n_pages, p, h_kv * dk), jnp.bfloat16)
+        _lower_tpu(lambda q, k, v, t, ln: paged_attention(
+            q, k, v, t, ln, window=window, k_flat=True), *args)
+
+    def test_ragged_attention_window_sink_key_width(self):
+        from paddle_tpu.ops.pallas.paged_attention import \
+            ragged_paged_attention
+        b, tq, h, h_kv, dk, dv, p, n_pages, mp = 4, 8, 64, 8, 192, 128, \
+            128, 64, 32
+        _lower_tpu(lambda q, k, v, t, c, st, s: ragged_paged_attention(
+            q, k, v, t, c, st, window=128, sinks=s),
+            _sds((b, tq, h, dk), jnp.bfloat16),
+            _sds((n_pages, p, h_kv, dk), jnp.bfloat16),
+            _sds((n_pages, p, h_kv, dv), jnp.bfloat16),
+            _sds((b, mp), jnp.int32), _sds((b,), jnp.int32),
+            _sds((b,), jnp.int32), _sds((h,), jnp.float32))
+
+    @pytest.mark.parametrize("m,k,n", [(1024, 4096, 4096),     # gate | up
+                                       (1024, 2048, 4096),     # down
+                                       (4096, 4096, 4096)])    # a chunk
+    def test_grouped_matmul(self, m, k, n):
+        from paddle_tpu.ops.pallas.grouped_matmul import grouped_matmul
+        _lower_tpu(grouped_matmul, _sds((m, k), jnp.bfloat16),
+                   _sds((16, k, n), jnp.bfloat16), _sds((16,), jnp.int32))
+
+
 class TestDecodeMegakernelLowering:
     """decode_megakernel layer/multi x dense/int8 at a lane-aligned
     geometry (what megakernel_supported admits on a chip)."""

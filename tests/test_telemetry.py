@@ -202,7 +202,8 @@ class TestWindowedPercentiles:
 # -- dashboard) --------------------------------------------------------------
 ENGINE_HEALTH_KEYS = frozenset({
     "queued", "running", "slots_total", "queue_limit", "pages_free",
-    "pages_total", "prefix_pages", "prefix_hits", "done", "failed",
+    "pages_total", "page_groups", "experts", "prefix_pages", "prefix_hits",
+    "done", "failed",
     "cancelled", "steps", "prefill_steps", "decode_steps", "admissions",
     "failures", "deadline_expiries", "cow_copies", "decode_block",
     "fused_blocks", "chained_blocks", "megakernel",

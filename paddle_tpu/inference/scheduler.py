@@ -662,6 +662,7 @@ class ContinuousBatchingEngine(LLMEngine):
         self.megakernel = self._resolve_megakernel(megakernel)
         self._mk_head = False           # whole-step mode: final norm +
         self._mk_vl = 0                 # lm_head + argmax in-kernel
+        self.mk_tile_plan = None        # set by _build_mk_pack
         if self.megakernel:
             with _span("setup.engine.mk_pack"):
                 self._build_mk_pack()
@@ -1412,6 +1413,10 @@ class ContinuousBatchingEngine(LLMEngine):
             # build from the activation and weight dtypes
             # (quantized_matmul.mm_operand_dtype): static, a fact
             "mm_operand_dtype": self.mm_operand_dtype,
+            # the megakernel's tile plan (decode_megakernel.mm_tile_plan):
+            # per projection [bk, bn] and the grid steps of one layer
+            # call at a full batch; None on the op-chain path. Static
+            "mk_tile_plan": self.mk_tile_plan,
             # tensor parallelism (inference/tp.py): shard count, tail
             # mode, and whether the per-token reduce rides int8
             "tp": self.tp,
@@ -2358,7 +2363,8 @@ class ContinuousBatchingEngine(LLMEngine):
         with tp=1 survives. megakernel="multi" additionally builds the
         WHOLE-STEP head pack (final norm + lm_head + greedy argmax in
         the same schedule)."""
-        from ..ops.pallas.decode_megakernel import (pack_decode_layer,
+        from ..ops.pallas.decode_megakernel import (layer_tile_plan,
+                                                    pack_decode_layer,
                                                     pack_lm_head,
                                                     stack_packed)
         W = self.weights
@@ -2377,6 +2383,10 @@ class ContinuousBatchingEngine(LLMEngine):
                     "grids)")
         packed = [pack_decode_layer(ws, cdtype=self.kv_dtype, tp=self.tp)
                   for ws in W["layers"]]
+        # which blocks the walk streams and how many grid steps a layer
+        # call takes at a full batch (static; health()["mk_tile_plan"])
+        self.mk_tile_plan = layer_tile_plan(
+            packed[0], self.max_batch, self.max_pages_per_seq, self.tp)
         mk = (stack_packed(packed) if self.megakernel == "multi"
               else packed)
         head_w = (W["head"][0] if isinstance(W["head"], tuple)

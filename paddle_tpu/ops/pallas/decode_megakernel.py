@@ -61,7 +61,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .paged_attention import (NEG_INF, ragged_causal_mask, vmem_limit,
                               wv_diag)
-from .quantized_matmul import dot_tile_f32, scale_emit
+from .quantized_matmul import dot_tile_f32, mm_operand_dtype, scale_emit
 from .rms_norm import rms_rows as _rms_rows
 
 # schedule phase ids (ints baked into the scalar-prefetched schedule)
@@ -83,12 +83,25 @@ _MM_SRC = {PH_Q: ("q", "x"), PH_K: ("k", "x"), PH_V: ("v", "x"),
            PH_O: ("o", "attn"), PH_G: ("g", "x"), PH_U: ("u", "x"),
            PH_D: ("d", "act")}
 
-# default streaming tile sizes; k matches quantized_matmul's bk=512 so
-# the f32 accumulation order ACROSS k-tiles (and therefore the bits)
-# agrees with the unfused engine path, whatever type the operands of one
-# tile's product are fed in. bn does not enter that order.
-DEF_BK = 512
-DEF_BN = 512
+# THE TILE PLAN of the matmul phases: how wide a weight block one grid
+# step streams. A grid step costs about a microsecond whatever it moves
+# (some twenty index maps, the DMAs issued and waited on), so a layer's
+# weights go through in as few, as wide blocks as fit.
+#   MM_BK is fixed: it matches quantized_matmul's bk=512, so the NUMBER
+#     and ORDER of k-tiles — the f32 accumulation order across them, and
+#     with it every output bit — agree with the unfused engine path,
+#     whatever type one tile's operands are fed in.
+#   bn does not enter that order, so it is chosen (`mm_tile_plan`): the
+#     widest multiple of 128 lanes that DIVIDES the packed N (the pack
+#     pads an N past MM_N_GRAIN to its multiple and no further, so a
+#     wider block never costs a zero column or a second copy of a weight)
+#     and keeps one block bk x bn x itemsize within MM_BLOCK_BYTES.
+#   MM_BLOCK_BYTES was settled by a sweep of one layer call at the 7B
+#     serving geometry on a v5e over 0.25 (the old 512 columns), 1, 2 and
+#     4 MiB: docs/probes/mk_layer_probe.py, table in PERF.md 6 (PR 27).
+MM_BK = 512
+MM_N_GRAIN = 512
+MM_BLOCK_BYTES = 2 << 20
 
 
 def _ktile(dim, want):
@@ -103,6 +116,27 @@ def _ktile(dim, want):
     return dim if dim <= want else want
 
 
+def mm_tile_plan(k, n, itemsize, budget=None):
+    """The ONE rule for a projection [k, n] of `itemsize`-byte weights:
+    -> (bk, bn, k_pad, n_pad). The pack pads to (k_pad, n_pad); the
+    call, which sees the padded shape, gets the same (bk, bn) back
+    (the rule is idempotent on its own pads). An N within MM_N_GRAIN is
+    one block whatever its width (test sizes: no lane multiple needed);
+    past it bn = 128 x the largest divisor of n_pad / 128 whose block
+    fits the budget (at least one 128-lane tile). `budget` is the
+    tests' handle on the constant, not an engine option."""
+    budget = MM_BLOCK_BYTES if budget is None else budget
+    bk = _ktile(k, MM_BK)
+    grain = _ktile(n, MM_N_GRAIN)
+    k_pad, n_pad = -(-k // bk) * bk, -(-n // grain) * grain
+    if n_pad == grain:
+        return bk, n_pad, k_pad, n_pad
+    lanes = n_pad // 128
+    fit = max(budget // (bk * 128 * itemsize), 1)
+    m = max(d for d in range(1, min(lanes, fit) + 1) if lanes % d == 0)
+    return bk, 128 * m, k_pad, n_pad
+
+
 def _pad_axis(a, mult, axis):
     pad = (-a.shape[axis]) % mult
     if not pad:
@@ -112,57 +146,54 @@ def _pad_axis(a, mult, axis):
     return jnp.pad(a, widths)
 
 
-def _pack_w(w, bk, bn, cdtype):
-    """One projection weight -> (values [k_pad, n_pad], scales [1, n_pad]).
-    int8 engine snapshots arrive as (int8 [k, n], scales [n]); dense
+def _vals_scales(w, cdtype):
+    """int8 engine snapshots arrive as (int8 [k, n], scales [n]); dense
     weights keep their dtype with unit scales (the kernel's
-    `(acc * scale)` is then an exact f32 identity). Zero-padding rows
-    add exact 0.0 to the f32 accumulator and zero-scale columns emit
-    exact zeros, so padding never perturbs real outputs."""
+    `(acc * scale)` is then an exact f32 identity)."""
     if isinstance(w, tuple):
-        vals, scales = w
-    else:
-        vals = w.astype(cdtype) if w.dtype != cdtype else w
-        scales = jnp.ones((w.shape[1],), jnp.float32)
-    k, n = vals.shape
-    vals = _pad_axis(vals, _ktile(k, bk), 0)
-    vals = _pad_axis(vals, _ktile(n, bn), 1)
-    scales = _pad_axis(scales.astype(jnp.float32).reshape(1, -1),
-                       _ktile(n, bn), 1)
+        return w
+    return (w.astype(cdtype) if w.dtype != cdtype else w,
+            jnp.ones((w.shape[1],), jnp.float32))
+
+
+def _pack_w(w, cdtype):
+    """One projection weight -> (values [k_pad, n_pad], scales [1, n_pad])
+    at mm_tile_plan's pads. Zero-padding rows add exact 0.0 to the f32
+    accumulator and zero-scale columns emit exact zeros, so padding
+    never perturbs real outputs."""
+    vals, scales = _vals_scales(w, cdtype)
+    _, _, k_pad, n_pad = mm_tile_plan(*vals.shape, vals.dtype.itemsize)
+    vals = _pad_axis(_pad_axis(vals, k_pad, 0), n_pad, 1)
+    scales = _pad_axis(scales.astype(jnp.float32).reshape(1, -1), n_pad, 1)
     return vals, scales
 
 
-def _pack_w_sharded(w, bk, bn, cdtype, tp):
+def _pack_w_sharded(w, cdtype, tp):
     """Column-parallel per-shard pack: slice the OUTPUT channels into tp
     equal shards, pack each shard to its own padded tile grid, and
     concatenate — a P(None, "mp")-sharded placement of the result hands
     shard s exactly its local packed (values, scales). The k-axis pad
-    is shard-independent (derived from (k, bk) alone), so every shard
+    is shard-independent (derived from (k, MM_BK) alone), so every shard
     walks the same k-tile count as the tp=1 pack."""
     if tp == 1:
-        return _pack_w(w, bk, bn, cdtype)
-    if isinstance(w, tuple):
-        vals, scales = w
-    else:
-        vals = w.astype(cdtype) if w.dtype != cdtype else w
-        scales = jnp.ones((w.shape[1],), jnp.float32)
+        return _pack_w(w, cdtype)
+    vals, scales = _vals_scales(w, cdtype)
     n = vals.shape[1]
     assert n % tp == 0, (n, tp)
     nl = n // tp
     vparts, sparts = [], []
     for s in range(tp):
         v, sc = _pack_w((vals[:, s * nl:(s + 1) * nl],
-                         scales[s * nl:(s + 1) * nl]), bk, bn, cdtype)
+                         scales[s * nl:(s + 1) * nl]), cdtype)
         vparts.append(v)
         sparts.append(sc)
     return jnp.concatenate(vparts, 1), jnp.concatenate(sparts, 1)
 
 
-def pack_decode_layer(wset, cdtype=jnp.float32, bk=DEF_BK, bn=DEF_BN,
-                      tp=1):
+def pack_decode_layer(wset, cdtype=jnp.float32, tp=1):
     """Repack ONE engine layer snapshot (serving._snapshot_llama entry)
     into the megakernel's streamed layout: per-projection (values,
-    scales) padded to the streaming tile grid, norm weights as [1, H]
+    scales) padded as mm_tile_plan says, norm weights as [1, H]
     rows. Views/cheap reshapes where no padding is needed — the int8
     pool is NOT duplicated for the common aligned geometries.
 
@@ -173,11 +204,11 @@ def pack_decode_layer(wset, cdtype=jnp.float32, bk=DEF_BK, bn=DEF_BN,
     out = {}
     for name, key in (("q", "wq"), ("k", "wk"), ("v", "wv"),
                       ("g", "wg"), ("u", "wu")):
-        vals, scales = _pack_w_sharded(wset[key], bk, bn, cdtype, tp)
+        vals, scales = _pack_w_sharded(wset[key], cdtype, tp)
         out["w" + name] = vals
         out["s" + name] = scales
     for name, key in (("o", "wo"), ("d", "wd")):
-        vals, scales = _pack_w(wset[key], bk, bn, cdtype)
+        vals, scales = _pack_w(wset[key], cdtype)
         out["w" + name] = vals
         out["s" + name] = scales
     hp = out["wq"].shape[0]
@@ -186,16 +217,15 @@ def pack_decode_layer(wset, cdtype=jnp.float32, bk=DEF_BK, bn=DEF_BN,
     return out
 
 
-def pack_lm_head(head, norm_w, cdtype=jnp.float32, bk=DEF_BK, bn=DEF_BN,
-                 tp=1):
+def pack_lm_head(head, norm_w, cdtype=jnp.float32, tp=1):
     """Pack the final norm + lm_head for the whole-step HEAD phase:
     {"wh": [H_pad, V_pad], "sh": [1, V_pad], "nf": [1, H_pad]}. The
-    k-axis pad matches pack_decode_layer's hidden pad (same (dim, bk)
+    k-axis pad matches pack_decode_layer's hidden pad (same (dim, MM_BK)
     rule), so the HEAD phase reuses the layer walk's x scratch rows.
     tp > 1 shards the VOCAB columns per shard (the vocab-parallel
     lm_head): each shard streams 1/tp of the head and emits its local
     (max, argmax) pair for the engine's gather-free combine."""
-    wh, sh = _pack_w_sharded(head, bk, bn, cdtype, tp)
+    wh, sh = _pack_w_sharded(head, cdtype, tp)
     return {"wh": wh, "sh": sh,
             "nf": _pad_axis(norm_w.reshape(1, -1), wh.shape[0], 1)}
 
@@ -754,18 +784,21 @@ def decode_megakernel(h, mk, k_pages=None, v_pages=None, page_table=None,
 
     counts, bks, bns = {}, {}, {}
 
-    def mm_dims(P, key):
-        kdim, ndim = shp("w" + key)
-        bks[P] = _ktile(kdim, DEF_BK)
-        bns[P] = _ktile(ndim, DEF_BN)
+    def mm_dims(P, w):
+        kdim, ndim = w.shape[-2:]
+        bks[P], bns[P], k_pad, n_pad = mm_tile_plan(kdim, ndim,
+                                                    w.dtype.itemsize)
+        assert (k_pad, n_pad) == (kdim, ndim), (
+            "weights must come from pack_decode_layer / pack_lm_head",
+            (kdim, ndim), (k_pad, n_pad))
         counts[P] = (kdim // bks[P], ndim // bns[P])
         return kdim, ndim
 
     dims = {"R": R, "H": H, "nh": nh, "nh_kv": nh_kv, "hd": hd}
     if seg in ("full", "qkv"):
-        Hp, NQp = mm_dims(PH_Q, "q")
-        _, NKp = mm_dims(PH_K, "k")
-        mm_dims(PH_V, "v")
+        Hp, NQp = mm_dims(PH_Q, mk["wq"])
+        _, NKp = mm_dims(PH_K, mk["wk"])
+        mm_dims(PH_V, mk["wv"])
         assert R == (R // T) * T
         b = R // T
         pshape = k_pages.shape[1:] if stacked else k_pages.shape
@@ -777,25 +810,25 @@ def decode_megakernel(h, mk, k_pages=None, v_pages=None, page_table=None,
         NQp = NKp = None
     if seg == "full":
         assert NQ == H, (nh, hd, H)
-        _, Hop = mm_dims(PH_O, "o")
-        _, Fg = mm_dims(PH_G, "g")
-        mm_dims(PH_U, "u")
-        Fp, _ = mm_dims(PH_D, "d")
+        _, Hop = mm_dims(PH_O, mk["wo"])
+        _, Fg = mm_dims(PH_G, mk["wg"])
+        mm_dims(PH_U, mk["wu"])
+        Fp, _ = mm_dims(PH_D, mk["wd"])
         # the pack rules derive every pad from (dim, 512) alone, so the
         # q-output, o-input and o-output pads of the SAME hidden size
         # agree
         assert NQp == Hp == Hop == shp("wd")[1], (NQp, Hp, Hop)
         assert Fg == Fp == shp("wu")[1], (Fg, Fp)
     elif seg == "tail":
-        Oin, Hop = mm_dims(PH_O, "o")
-        Hg, Fg = mm_dims(PH_G, "g")
-        mm_dims(PH_U, "u")
+        Oin, Hop = mm_dims(PH_O, mk["wo"])
+        Hg, Fg = mm_dims(PH_G, mk["wg"])
+        mm_dims(PH_U, mk["wu"])
         assert Hg == Hop == mk["ln2"].shape[-1], (Hg, Hop)
         assert Fg == shp("wu")[1], (Fg,)
         Hp, Fp = Hop, Fg
         attn_in = _pad_to(attn_in, Oin)
     elif seg == "down":
-        Fp, Hop = mm_dims(PH_D, "d")
+        Fp, Hop = mm_dims(PH_D, mk["wd"])
         Hp, Fg = Hop, Fp
         act_in = _pad_to(act_in, Fp)
     else:
@@ -808,9 +841,7 @@ def decode_megakernel(h, mk, k_pages=None, v_pages=None, page_table=None,
         hk, Vp = head["wh"].shape
         assert hk == Hp, (hk, Hp, "lm_head k-pad must match the hidden "
                           "pad (same (dim, 512) rule)")
-        bks[PH_H] = _ktile(hk, DEF_BK)
-        bns[PH_H] = _ktile(Vp, DEF_BN)
-        counts[PH_H] = (hk // bks[PH_H], Vp // bns[PH_H])
+        mm_dims(PH_H, head["wh"])
         dims["Vh"] = int(Vp if head_v is None else head_v)
         if head_k is not None and not 1 <= int(head_k) <= min(
                 128, dims["Vh"]):
@@ -1018,17 +1049,24 @@ def decode_megakernel(h, mk, k_pages=None, v_pages=None, page_table=None,
         out_specs=out_specs,
         scratch_shapes=scratch,
     )
-    # seven or eight double-buffered weight streams, two page blocks and
-    # their f32 copies: past the 16 MiB default at 7B width
+    # seven or eight double-buffered weight streams of up to
+    # MM_BLOCK_BYTES, two page blocks and their f32 copies, and the
+    # widest weight block once more in the type it reaches the MXU in
+    # (an int8 block converts to twice its bytes of bf16): far past the
+    # 16 MiB default at 7B width
     f32 = jnp.float32
+    blocks = [(sp.block_shape, op.dtype)
+              for sp, op in zip(in_specs, operands)]
+    w_ops = [(shape, jnp.dtype(mm_operand_dtype(cdtype, dt)))
+             for (shape, dt), name in zip(blocks, names)
+             if name[0] == "w"]
     limit = vmem_limit(
-        blocks=[(sp.block_shape, op.dtype)
-                for sp, op in zip(in_specs, operands)]
-        + [(sp.block_shape, sd.dtype)
-           for sp, sd in zip(out_specs, out_shapes)],
+        blocks=blocks + [(sp.block_shape, sd.dtype)
+                         for sp, sd in zip(out_specs, out_shapes)],
         scratch=[(m.shape, m.dtype) for m in scratch],
         temps=([((p, nh_kv, hd), f32)] * 4 if has_attn else [])
-        + [((R, bn_max), f32)] * 2)
+        + [((R, bn_max), f32)] * 2
+        + [max(w_ops, key=lambda t: math.prod(t[0]) * t[1].itemsize)])
     with jax.enable_x64(False):
         outs = pl.pallas_call(
             kernel,
@@ -1060,6 +1098,26 @@ def decode_megakernel(h, mk, k_pages=None, v_pages=None, page_table=None,
             ret += [res["tok"][:, 0], res["maxv"][:, 0],
                     res["logits"][:, :dims["Vh"]]]
     return tuple(ret) if len(ret) > 1 else ret[0]
+
+
+def layer_tile_plan(layer, slots, pages, tp=1):
+    """What ONE layer's schedule walk is made of, from a packed layer
+    (pack_decode_layer's dict; tp = the shards its column-parallel
+    projections are concatenated for): per projection [bk, bn] as
+    decode_megakernel() will draw them, and the grid steps of the
+    layer's matmul phases and of its attention phase (one page a step,
+    live or not). Static facts of an engine: health()["mk_tile_plan"]."""
+    blocks, steps = {}, 0
+    for key in "qkvogud":
+        w = layer["w" + key]
+        k, n = w.shape
+        if key in "qkvgu":
+            n //= tp
+        bk, bn, _, _ = mm_tile_plan(k, n, w.dtype.itemsize)
+        blocks[key] = [bk, bn]
+        steps += (k // bk) * (n // bn)
+    return {"blocks": blocks,
+            "layer_steps": {"matmul": steps, "attention": slots * pages}}
 
 
 def megakernel_weight_bytes(mk, n_layers=None, head=None):

@@ -220,6 +220,8 @@ ENGINE_HEALTH_KEYS = frozenset({
     "adapters", "preemptions", "tenants",
     # PR 25: the weight matmuls' MXU operand type, static
     "mm_operand_dtype",
+    # PR 27: the megakernel's tile plan, static (None on the op chain)
+    "mk_tile_plan",
 })
 
 ROUTER_HEALTH_KEYS = frozenset({

@@ -12,7 +12,7 @@ Measures the comm layer the training step rides (docs/distributed_perf.md):
     int8+error-feedback gradient sync — final losses must agree within
     tolerance (the claim that compression costs wire bytes, not quality).
 
-Prints one JSON line per metric (decode_bench.py-style), e.g.:
+Prints one JSON line per metric, e.g.:
   {"metric": "allreduce_gbps_exact", "size_mb": 16.0, "value": ...}
   {"metric": "allreduce_gbps_int8", "size_mb": 16.0, "value": ...}
   {"metric": "collective_convergence", "pass": true, ...}

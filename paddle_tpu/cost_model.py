@@ -486,7 +486,7 @@ def decode_weight_bytes(cfg, quant=None, weight_dtype=None):
     """Bytes ONE decode step streams from HBM: every layer's seven
     projections + norms + final norm + lm_head (the embedding is a
     b-row gather, not a table read) — the numerator of the serving
-    weight roofline (decode_bench's `_weight_bytes_per_step`)."""
+    weight roofline."""
     c = _CfgView(cfg)
     h, ffn, L, V = (c.hidden_size, c.intermediate_size,
                     c.num_hidden_layers, c.vocab_size)
@@ -672,8 +672,7 @@ def predict_serving(model_cfg, spec, calib=None, prompt_len=128,
                       gathers; psum mode halves the volume, int8
                       compress quarters it)
       host          - per-block host intervention / decode_block
-                      (megakernel "layer"/"multi" shrink it — PR 12
-                      measured whole-step host_overhead_frac 0.0)
+                      (megakernel "layer"/"multi" shrink it)
       interference  - prefill chunks stealing decode steps when the
                       fleet is NOT disaggregated; a prefill:decode
                       split removes it but shrinks the decode pool

@@ -41,7 +41,7 @@ Control law (docs/serving.md "Elastic fleet"): one `tick()` reads
 aggregates), updates the breach/slack streaks, and takes AT MOST ONE
 scaling action, then sleeps `cooldown_ticks` ticks.  Every decision —
 including the no-ops — lands in a bounded decision log with its
-wall-clock latency (the bench's scale-decision-latency metric).
+wall-clock latency.
 
 Fault points: `scale.spawn`, `scale.retire`, `scale.rebalance` — each
 fires BEFORE its action commits, so chaos runs exercise the abort

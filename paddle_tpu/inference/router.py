@@ -594,7 +594,7 @@ class EngineRouter:
         self._specs = {}                # ruid -> pending resume spec
         self._next_uid = 0
         self._rr = 0                    # routing tie-break rotation
-        # observability (tests + decode_bench's cb_failover assert these)
+        # observability (tests assert on these)
         self.steps = 0
         self.failovers = 0              # replica-declared-dead events
         self.requeued = 0               # in-flight requests moved

@@ -39,7 +39,6 @@ import collections
 import contextlib
 import math
 import time
-import warnings
 
 import numpy as np
 import jax
@@ -518,26 +517,15 @@ class ContinuousBatchingEngine(LLMEngine):
         an unbounded backlog. None (default) = unbounded.
       default_deadline_ms: deadline applied to requests submitted
         without one (None = no deadline).
-      do_sample/temperature/top_k/top_p/seed: DEPRECATED engine-level
-        sampling knobs — per-request `add_request(sampling=
-        SamplingParams(...))` is the first-class path (ISSUE 18). The
-        engine-level values now only form the DEFAULT SamplingParams a
-        request gets when it brings none; the engine seed is folded
-        with the request uid so even defaulted requests draw
-        per-request `(seed, position)` key streams (reproducible and
-        invariant to batch composition — NOT the old engine-wide
-        stream). Passing do_sample=True warns DeprecationWarning.
       sample_k: size of the top-K survivor set every sampled selection
         draws from (default 8; 1 <= sample_k <= 128). In whole-step
         megakernel mode the set is computed by the in-kernel running
         top-K merge and the [w, V] logits never materialize; top_p /
         min_p act within the survivor set (exact whenever the nucleus
         fits — docs/serving.md "Sampling & structured decoding").
-        A request's top_k must be <= sample_k.
-      sample_fold: False forces sampled selection through MATERIALIZED
-        logits + lax.top_k (the reference path; what decode_bench's
-        cb_sampling section measures against). Tokens are bit-identical
-        either way — the fold is a pure perf knob.
+        A request's top_k must be <= sample_k. Sampling itself is per
+        request: add_request(sampling=SamplingParams(...)); a request
+        without one decodes greedily.
 
     Failure posture: a request that trips a fault (injected or real) at
     a per-request boundary — admission allocation, a prefill chunk, its
@@ -551,9 +539,7 @@ class ContinuousBatchingEngine(LLMEngine):
     @_span("setup.engine")
     def __init__(self, model, max_len=1024, page_size=128, max_batch=8,
                  prefill_chunk=None, slot_buckets=None, prefix_cache=True,
-                 queue_limit=None, default_deadline_ms=None,
-                 do_sample=False, temperature=1.0, top_k=0, top_p=1.0,
-                 seed=0, sample_k=8, sample_fold=True,
+                 queue_limit=None, default_deadline_ms=None, sample_k=8,
                  decode_block=1, ragged_kernel=None,
                  megakernel=None, speculate=None, drafter="ngram",
                  spec_adaptive=True, tenants=None, kv_tier=None,
@@ -593,9 +579,9 @@ class ContinuousBatchingEngine(LLMEngine):
         # retire), latency histograms, chrome-trace + Prometheus + JSONL
         # exports. None (default) keeps a single-branch fast path at
         # every site; greedy outputs are byte-identical on vs off
-        # (pinned in tests and in-bench). All timestamps are captured
-        # at host points the engine already visits — zero extra device
-        # syncs. See docs/observability.md.
+        # (pinned in tests). All timestamps are captured at host points
+        # the engine already visits — zero extra device syncs. See
+        # docs/observability.md.
         self._tel = None
         self._tel_src = "engine"
         self.telemetry = None
@@ -686,33 +672,12 @@ class ContinuousBatchingEngine(LLMEngine):
                 w *= 2
         self._slot_buckets = tuple(sorted(
             {min(int(w), max_batch) for w in slot_buckets} | {max_batch}))
-        # DEPRECATED engine-global sampling tuple: now only the source
-        # of the per-request DEFAULT below (kept as an attribute for
-        # introspection parity with older code)
-        self._sampling = (bool(do_sample), float(temperature), int(top_k),
-                          float(top_p))
-        self._key = jax.random.key(seed)
         self.sample_k = int(sample_k)
         if not 1 <= self.sample_k <= 128:
             raise ValueError(
                 f"sample_k must be in [1, 128] (the in-kernel top-K "
                 f"fold rides the megakernel's [R, 128] select scratch), "
                 f"got {sample_k}")
-        self.sample_fold = bool(sample_fold)
-        self._engine_seed = int(seed) & 0xFFFFFFFF
-        if do_sample:
-            warnings.warn(
-                "engine-level do_sample/temperature/top_k/top_p are "
-                "deprecated: pass add_request(sampling=SamplingParams("
-                "...)) per request. The engine-level values now form a "
-                "per-request DEFAULT whose seed folds in the request "
-                "uid (a per-request key stream, not the old engine-wide "
-                "one).", DeprecationWarning, stacklevel=2)
-        if int(top_k) and int(top_k) > self.sample_k:
-            raise ValueError(
-                f"engine default top_k={top_k} exceeds sample_k="
-                f"{self.sample_k} — the sampled path selects from the "
-                "top-sample_k survivor set")
         self._prefix = PrefixCache(page_size) if prefix_cache else None
         self._drafter = (resolve_drafter(drafter, self._prefix)
                          if self._spec else None)
@@ -761,7 +726,7 @@ class ContinuousBatchingEngine(LLMEngine):
         #                                 readback not yet processed)
         self._copy_fn = None
 
-        # observability (tests + the serving bench assert on these)
+        # observability (tests assert on these)
         self.steps = 0
         self.decode_steps = 0
         self.prefill_steps = 0
@@ -900,18 +865,6 @@ class ContinuousBatchingEngine(LLMEngine):
             self._apool.place(self._tpc)
 
     # -- public ------------------------------------------------------------
-    def _default_sampling(self, uid):
-        """The SamplingParams a request gets when add_request carries
-        none: the deprecated engine-level knobs, with the engine seed
-        folded with the request uid (Knuth multiplicative hash) so even
-        defaulted sampled requests draw per-request key streams."""
-        dos, temp, tk, tp_ = self._sampling
-        if not dos:
-            return GREEDY
-        return SamplingParams(
-            do_sample=True, temperature=temp, top_k=tk, top_p=tp_,
-            seed=(self._engine_seed ^ ((uid * 2654435761) & 0xFFFFFFFF)))
-
     @staticmethod
     def _block_mode(requests):
         """Compiled-program family a dispatch needs for these
@@ -1061,12 +1014,11 @@ class ContinuousBatchingEngine(LLMEngine):
           `(seed, position)` key stream (reproducible regardless of
           batch composition, decode_block, preemption, failover or tp),
           repetition/presence/frequency penalties, stop sequences, and
-          grammar-constrained decoding (TokenMaskAutomaton). None takes
-          the engine default (greedy unless the deprecated engine-level
-          do_sample was set). Mixed greedy/sampled batches are
-          first-class. Penalties/grammar require the materialized
-          processor path and cannot compose with speculate= (typed
-          ValueError here, not a silent fallback).
+          grammar-constrained decoding (TokenMaskAutomaton). None is
+          greedy. Mixed greedy/sampled batches are first-class.
+          Penalties/grammar require the materialized processor path and
+          cannot compose with speculate= (typed ValueError here, not a
+          silent fallback).
         tenant: admission-policy tenant name (fair-share virtual time is
           tracked per tenant; unregistered tenants get share 1.0).
         priority: admission priority (higher first, strict); defaults to
@@ -1098,7 +1050,7 @@ class ContinuousBatchingEngine(LLMEngine):
         if adapter is not None:
             self._resolve_adapter(adapter)   # raises typed; may hot-load
         sp = (SamplingParams.from_spec(sampling) if sampling is not None
-              else self._default_sampling(self._next_uid))
+              else GREEDY)
         if sp.do_sample and sp.top_k > self.sample_k:
             raise ValueError(
                 f"sampling.top_k={sp.top_k} exceeds this engine's "
@@ -1437,12 +1389,11 @@ class ContinuousBatchingEngine(LLMEngine):
                 if self.spec_passes else 0.0),
             "draft_errors": self.draft_errors,
             # on-device sampling: per-request sampled admissions, the
-            # candidate-fold width, whether the in-kernel fold is on,
-            # and sampled speculation's own acceptance rate (its
-            # ceiling is set by temperature, unlike the greedy rate)
+            # candidate-fold width, and sampled speculation's own
+            # acceptance rate (its ceiling is set by temperature, unlike
+            # the greedy rate)
             "sampled_requests": self.sampled_requests,
             "sample_k": self.sample_k,
-            "sample_fold": self.sample_fold,
             "spec_sampled_accept_rate": (
                 self._spec_sampled_accepted / self._spec_sampled_offered
                 if self._spec_sampled_offered else 0.0),
@@ -1490,62 +1441,6 @@ class ContinuousBatchingEngine(LLMEngine):
                                 | {s.tenant for s in self._slots
                                    if s is not None})},
         }
-
-    def probe_device_step_seconds(self, iters=30):
-        """BLOCK-UNTIL-READY-sampled bare compiled decode-step time at
-        full slot width — the honest device-side denominator for host-
-        overhead attribution. `dispatch_seconds` accrues DISPATCH wall
-        (host call machinery included) and so overstates device
-        busyness; this probe queues `iters` compiled steps back-to-back
-        and blocks ONCE, so the per-call host cost amortizes away and
-        what remains is device compute (decode_bench's
-        host_overhead_frac is 1 - steps * this / wall — previously the
-        bench carried this math privately).
-
-        The probe dispatches REAL steps: it writes garbage KV into the
-        probe rows' page-0 slots and therefore (a) requires an IDLE
-        engine (raises RuntimeError otherwise) and (b) drops the prefix
-        cache afterwards — cached pages may alias the clobbered slots.
-        """
-        self._require_plain("probe_device_step_seconds()")
-        if any(s is not None for s in self._slots) or self._queue \
-                or self._demoted:
-            raise RuntimeError(
-                "probe_device_step_seconds needs an idle engine: the "
-                "probe dispatches real decode steps that clobber page-0 "
-                "KV slots (drain in-flight requests first)")
-        w = self.max_batch
-        fn = self._cb_step_fns.get(w)
-        if fn is None:
-            fn = self._build_cb_step(w)
-            self._cb_step_fns[w] = fn
-        kp, vp = self.k_pages, self.v_pages
-        tok = jnp.asarray(np.zeros(w, np.int64))
-        tab = jnp.asarray(self._tables_np[:w])
-        lens = jnp.asarray(np.zeros(w, np.int32))
-        act = jnp.asarray(np.ones(w, bool))
-        logits, kp, vp = fn(self.weights, tok, kp, vp, tab, lens, act)
-        jax.block_until_ready(logits)          # compile + warm
-        t0 = time.perf_counter()
-        for _ in range(max(1, int(iters))):
-            logits, kp, vp = fn(self.weights, tok, kp, vp, tab, lens,
-                                act)
-        jax.block_until_ready(logits)
-        t = (time.perf_counter() - t0) / max(1, int(iters))
-        self.k_pages, self.v_pages = kp, vp    # donated buffers moved
-        if self._prefix is not None:
-            self._prefix.clear(self.allocator)
-        return t
-
-    def device_busy_frac(self, wall_seconds, n_steps, t_step=None):
-        """Fraction of `wall_seconds` the device was genuinely busy
-        running `n_steps` decode steps, derived from the block-until-
-        ready probe (pass `t_step` to reuse a measurement). The
-        complement is decode_bench's host_overhead_frac."""
-        if t_step is None:
-            t_step = self.probe_device_step_seconds()
-        return min(1.0, max(0.0, n_steps * t_step
-                            / max(wall_seconds, 1e-9)))
 
     def generate(self, *args, **kw):
         """Inherited static-batch generate(). With native stacked pools
@@ -2052,7 +1947,7 @@ class ContinuousBatchingEngine(LLMEngine):
                 fn = self._cb_prefill_fn
                 pre = (self.weights,)
         with self._first_call_span():
-            t_dev = time.perf_counter()
+            t0 = time.perf_counter()
             with _span("cb.prefill_chunk"):
                 logits, self.k_pages, self.v_pages = fn(
                     *pre, jnp.asarray(ids_chunk), self.k_pages,
@@ -2062,10 +1957,9 @@ class ContinuousBatchingEngine(LLMEngine):
                     # the next chunk's pages are claimed
                     jnp.asarray(self._tables_np[r.slot:r.slot + 1].copy()),
                     jnp.int32(start), jnp.int32(r.t0))
-            dt = time.perf_counter() - t_dev
-            self.dispatch_seconds += dt
             if self._tel is not None:
-                self._tel.observe("prefill_chunk_ms", dt * 1e3)
+                self._tel.observe("prefill_chunk_ms",
+                                  (time.perf_counter() - t0) * 1e3)
                 self._tel.req_event(self._tel_src, r.uid, "prefill_chunk",
                                     filled=end)
             r.filled = end
@@ -2078,13 +1972,11 @@ class ContinuousBatchingEngine(LLMEngine):
             # final chunk's logits
             with _span("cb.prefill.first_token"):
                 self._publish_prefix(r)
-                t_dev = time.perf_counter()
                 # the first generated token enters position t0 — its
                 # counter
                 tok = self._select_tokens([r], [r.t0],
                                           self._block_mode([r]),
                                           logits=logits)[0]
-                self.dispatch_seconds += time.perf_counter() - t_dev
                 self._lens_np[r.slot] = r.t0
                 r.state = DECODE
                 self._push_token(r, tok)
@@ -2393,9 +2285,8 @@ class ContinuousBatchingEngine(LLMEngine):
                   else W["head"])
         vocab = head_w.shape[1]
         # whole-step head fold: "multi" mode only (per-layer mode keeps
-        # the op-chain norm/head — that spread IS the whole-step vs
-        # per-layer host_overhead_frac comparison decode_bench pins);
-        # an awkward vocab under tp falls back to the op-chain head
+        # the op-chain norm/head); an awkward vocab under tp falls back
+        # to the op-chain head
         self._mk_head = (self.megakernel == "multi"
                          and (self.tp == 1 or vocab % self.tp == 0))
         self._mk_vl = vocab // self.tp if vocab % self.tp == 0 else vocab
@@ -2826,13 +2717,13 @@ class ContinuousBatchingEngine(LLMEngine):
                 new_k, new_v)
 
     def _build_cb_step(self, w, with_adapters=False, mode="greedy"):
-        # "sampled" under sample_fold returns the folded top-sample_k
-        # candidate rows instead of logits — under the whole-step
-        # megakernel the [w, V] row never materializes even at
-        # decode_block=1. "proc" (and the materialized sampled arm)
-        # keeps the logits return; the host runs the processor chain +
-        # select eagerly (_select_tokens) — same math, same bits.
-        fold = mode == "sampled" and self.sample_fold
+        # "sampled" returns the folded top-sample_k candidate rows
+        # instead of logits — under the whole-step megakernel the
+        # [w, V] row never materializes even at decode_block=1. "proc"
+        # (and the adapter-carrying program) keeps the logits return;
+        # the host runs the processor chain + select eagerly
+        # (_select_tokens) — same math, same bits.
+        fold = mode == "sampled"
         sK = self.sample_k
 
         if self.desc.has_experts:
@@ -2931,7 +2822,7 @@ class ContinuousBatchingEngine(LLMEngine):
                     active[r.slot] = True
             mode = self._block_mode(decodes)
             aid = self._slot_aid(decodes, w)
-            fold = mode == "sampled" and self.sample_fold and aid is None
+            fold = mode == "sampled" and aid is None
             if aid is not None:
                 # adapter-carrying batch: the ADAPTER-AWARE program (the
                 # plain program stays untouched — and with megakernel=
@@ -2960,7 +2851,6 @@ class ContinuousBatchingEngine(LLMEngine):
             rows = [None] * w
             for r in decodes:
                 rows[r.slot] = r
-        t_dev = time.perf_counter()
         with self._first_call_span(), _span("cb.decode_step"):
             # dispatch returns without waiting for the device; fetch is
             # where the host blocks (an eager selection program, then
@@ -2982,7 +2872,6 @@ class ContinuousBatchingEngine(LLMEngine):
                     logits, self.k_pages, self.v_pages = out
                     toks = self._select_tokens(rows, positions, mode,
                                                logits=logits)
-        self.dispatch_seconds += time.perf_counter() - t_dev
         with _span("cb.decode.push"):
             for r in decodes:
                 self._lens_np[r.slot] += 1
@@ -3034,8 +2923,8 @@ class ContinuousBatchingEngine(LLMEngine):
         * "sampled" — six extra [w] arrays ride after eos_ids (seeds
           u32, do_sample bool, temperature/top_p/min_p f32, top_k i32).
           Tokens come from select_from_topk over the top-sample_k
-          (value, id) rows — under sample_fold the IN-KERNEL fold, so
-          the [w, V] logits are never materialized; otherwise
+          (value, id) rows — the IN-KERNEL fold, so the [w, V] logits
+          are never materialized; an adapter-carrying block takes
           lax.top_k of the materialized logits (bitwise-identical
           candidates either way). Every token's key is
           fold_in(key(seed), absolute_position) — no split chain, so
@@ -3059,7 +2948,6 @@ class ContinuousBatchingEngine(LLMEngine):
         p = self.page_size
         mp = self.max_pages_per_seq
         sK = self.sample_k
-        sfold = self.sample_fold
         NEX = {"greedy": 0, "sampled": 6, "proc": 14}[mode]
         use_kernel = (self.ragged_kernel is True) or \
             (self.ragged_kernel is None and not self.interpret)
@@ -3133,7 +3021,7 @@ class ContinuousBatchingEngine(LLMEngine):
                     tok, lens, act, rem, counts, gstate, kps, vps = carry
                 else:
                     tok, lens, act, rem, kps, vps = carry
-                if mode == "sampled" and sfold and ad is None:
+                if mode == "sampled" and ad is None:
                     # the sampling fold: top-sample_k (value, id) rows
                     # straight from the decode math — under the whole-
                     # step megakernel the IN-KERNEL running merge, so
@@ -3160,8 +3048,8 @@ class ContinuousBatchingEngine(LLMEngine):
                         topv, topi = jax.lax.top_k(lg, sK)
                         topi = topi.astype(jnp.int32)
                     elif gtok is not None:
-                        # materialized arm (sample_fold off / adapter
-                        # fallback) — bitwise the fold's candidates
+                        # materialized arm (adapter fallback) —
+                        # bitwise the fold's candidates
                         topv, topi = jax.lax.top_k(logits, sK)
                         topv = topv.astype(jnp.float32)
                         topi = topi.astype(jnp.int32)
@@ -3242,7 +3130,7 @@ class ContinuousBatchingEngine(LLMEngine):
                 drafts_s, dlen_s = xs
                 tok, lens, act, rem, kps, vps = carry
                 feed = jnp.concatenate([tok[:, None], drafts_s], axis=1)
-                if mode == "sampled" and sfold and ad is None:
+                if mode == "sampled" and ad is None:
                     topv, topi, kps, vps = self._cb_spec_verify_math(
                         W, feed, kps, vps, tables, lens, act, rem,
                         dlen_s, w, topk=sK)
@@ -3571,7 +3459,6 @@ class ContinuousBatchingEngine(LLMEngine):
         blk.eos_dev = jnp.asarray(eos)
         if T:
             blk.dlens = dlen_np
-        t_dev = time.perf_counter()
         spec_args = ((jnp.asarray(drafts_np), jnp.asarray(dlen_np))
                      if T else ())
         with self._first_call_span(), _span("cb.block"):
@@ -3586,7 +3473,6 @@ class ContinuousBatchingEngine(LLMEngine):
                 jnp.asarray(self._lens_np[:w]),
                 jnp.asarray(act), jnp.asarray(rem), blk.eos_dev,
                 *blk.extras, *spec_args)
-        self.dispatch_seconds += time.perf_counter() - t_dev
         self.fused_blocks += 1
         # steps advance by the block's DEVICE micro-steps so TTL budgets
         # stay comparable with the per-step engine (expiry itself is
@@ -3693,12 +3579,10 @@ class ContinuousBatchingEngine(LLMEngine):
         per-step path uses — host and device agree on EOS/budget by
         construction, so _push_token retires exactly where the device's
         active flag dropped."""
-        t_dev = time.perf_counter()
         first = np.asarray(blk.first) if blk.has_prefill else None
         if blk.has_decode:
             toks = np.asarray(blk.toks)
             emitted = np.asarray(blk.emitted)
-        self.dispatch_seconds += time.perf_counter() - t_dev
         for r, end in blk.pf_items:
             if r.state != PREFILL or r.slot is None:
                 continue               # cancelled while in flight

@@ -487,17 +487,6 @@ class LLMEngine:
         self._step_fn = None
         self._prefill_fns = {}
         self._loop_fns = {}
-        # wall-clock seconds spent ISSUING compiled dispatches and
-        # blocked on their readbacks — HOST time included (tracing the
-        # args, the jit-call machinery, the python around it), so this
-        # is a DISPATCH-side number, not device busyness. It used to be
-        # misleadingly named `device_seconds` (that alias survives as a
-        # deprecated read-only property); the honest device-busy signal
-        # is the block-until-ready-sampled probe
-        # (ContinuousBatchingEngine.probe_device_step_seconds /
-        # device_busy_frac), which decode_bench's host_overhead_frac is
-        # derived from. See docs/observability.md "Device attribution".
-        self.dispatch_seconds = 0.0
         # batch buckets (OPT-IN): generate() pads the request batch up to
         # the nearest bucket so varying batch sizes reuse a handful of
         # compiled prefill/step programs instead of one per size. Off by
@@ -572,14 +561,6 @@ class LLMEngine:
                 "equal key and value widths); this model's layers differ "
                 "— serve it through ContinuousBatchingEngine.add_request "
                 "/ step at decode_block=1")
-
-    @property
-    def device_seconds(self):
-        """DEPRECATED alias of `dispatch_seconds` (renamed because the
-        accrued value is dispatch wall-clock — host call machinery
-        included — not device busyness; use probe_device_step_seconds /
-        device_busy_frac for that)."""
-        return self.dispatch_seconds
 
     # -- tensor parallelism (inference/tp.py) -------------------------------
     def _jit_tp(self, fn, in_specs, out_specs, donate_argnums=()):

@@ -15,11 +15,9 @@ Design constraints (why this looks the way it does):
     boundaries, admission, retirement. Telemetry never calls
     `block_until_ready`, never fetches a device value, never changes
     what the compiled programs compute (greedy outputs are pinned
-    byte-identical telemetry-on vs -off in tests and in-bench).
+    byte-identical telemetry-on vs -off in tests).
   - `telemetry=None` stays the default and its fast path is a single
-    branch per site (`if self._tel is not None`). decode_bench's
-    `cb_telemetry_overhead` section pins the telemetry-on steady-state
-    cost under 2%.
+    branch per site (`if self._tel is not None`).
   - Everything is BOUNDED: per-request event lists, the completed-trace
     ring, the structured event log, the JSONL write buffer. A
     long-lived serving process cannot leak through its own telemetry.

@@ -1122,7 +1122,7 @@ def layer_tile_plan(layer, slots, pages, tp=1):
 
 def megakernel_weight_bytes(mk, n_layers=None, head=None):
     """Weight bytes one decode step streams through this kernel (the
-    roofline numerator decode_bench reports): every projection's values
+    roofline numerator): every projection's values
     + scales + both norms, per layer — plus the lm_head pack when the
     whole-step mode streams it too."""
     keys = ("wq", "sq", "wk", "sk", "wv", "sv", "wo", "so",

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 
 import paddle_tpu as paddle
 from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
+from paddle_tpu.inference.sampling import SamplingParams
 from paddle_tpu.inference.scheduler import ContinuousBatchingEngine
 from paddle_tpu.inference.serving import _mm, _rms
 from paddle_tpu.ops.pallas.decode_megakernel import (
@@ -263,6 +264,17 @@ class TestV2ByteIdentity:
         outs = eng.generate_many(prompts, max_new_tokens=NEW_TOKENS)
         _assert_same(ref_outputs, outs, "tp2+multi+K8")
 
+    def test_tp2_spec_wholestep_k8(self, tiny, prompts, ref_outputs):
+        # every composition at once: the verify pass on the whole-step
+        # kernel's tq>1 schedule, per-shard segments, fused blocks
+        model, _ = tiny
+        eng = ContinuousBatchingEngine(model, tp=2, megakernel="multi",
+                                       speculate=4, decode_block=8,
+                                       **ENGINE_KW)
+        outs = eng.generate_many(prompts, max_new_tokens=NEW_TOKENS)
+        _assert_same(ref_outputs, outs, "tp2+multi+spec4+K8")
+        assert eng.spec_passes > 0
+
     @pytest.mark.slow
     def test_tp2_spec_layer(self, tiny, prompts, ref_outputs):
         # slow lane: the tier-1 tp cell is test_tp2_wholestep_k8; this
@@ -370,16 +382,23 @@ class TestV2Soak:
 
     def test_sampled_mode_wholestep_identical_to_opchain(self, tiny,
                                                          prompts):
-        # sampled outputs depend only on the logits bits + key stream;
-        # the whole-step kernel's logits are bit-identical to the op
-        # chain's, so at the SAME decode_block (same key-split stream —
-        # sampled identity across K values was never a contract) the
-        # SAME seed must sample the SAME tokens
+        # sampled outputs depend only on the logits bits + the
+        # per-request (seed, position) key stream; the whole-step
+        # kernel's logits are bit-identical to the op chain's, so the
+        # SAME seeds must sample the SAME tokens
         model, _ = tiny
-        kw = dict(ENGINE_KW, do_sample=True, temperature=0.8, seed=11,
-                  decode_block=8)
-        a = ContinuousBatchingEngine(model, megakernel=False, **kw)
-        outs_a = a.generate_many(prompts, max_new_tokens=NEW_TOKENS)
-        b = ContinuousBatchingEngine(model, megakernel="multi", **kw)
-        outs_b = b.generate_many(prompts, max_new_tokens=NEW_TOKENS)
-        _assert_same(outs_a, outs_b, "sampled multi+K8")
+
+        def sampled(megakernel):
+            eng = ContinuousBatchingEngine(
+                model, megakernel=megakernel, decode_block=8,
+                **ENGINE_KW)
+            uids = [eng.add_request(
+                        p, NEW_TOKENS, sampling=SamplingParams(
+                            do_sample=True, temperature=0.8,
+                            seed=11 + i))
+                    for i, p in enumerate(prompts)]
+            eng.drain()
+            return [eng.result(u) for u in uids]
+
+        _assert_same(sampled(False), sampled("multi"),
+                     "sampled multi+K8")

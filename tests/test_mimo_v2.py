@@ -316,14 +316,13 @@ def test_each_unsupported_combination_raises_its_typed_error(model, kw,
 
 
 @pytest.mark.parametrize("call", ["generate", "export_kv_pages",
-                                  "export_prefix_pages",
-                                  "probe_device_step_seconds"])
+                                  "export_prefix_pages"])
 def test_plain_only_calls_raise_typed(model, call):
     eng = ContinuousBatchingEngine(model, max_len=64, page_size=8,
                                    max_batch=2, prefix_cache=False)
     args = {"generate": (np.zeros((1, 4), np.int64),),
-            "export_kv_pages": (0,), "export_prefix_pages": ([1, 2, 3],),
-            "probe_device_step_seconds": ()}[call]
+            "export_kv_pages": (0,),
+            "export_prefix_pages": ([1, 2, 3],)}[call]
     with pytest.raises(UnsupportedByDescription):
         getattr(eng, call)(*args)
 

@@ -5,8 +5,8 @@ Pins, bottom-up:
   - fold bit-identity: per-request sampled streams identical across
     decode_block {1, 8} x megakernel {off, multi} x tp {1, 2} on the
     int8 engine geometry, and across the in-kernel fold vs the
-    materialized arm (sample_fold=False) — lean cells tier-1, the full
-    cross on the slow lane;
+    materialized arm (the "proc" program under a neutral processor
+    chain) — lean cells tier-1, the full cross on the slow lane;
   - batch-composition invariance: a request's stream depends only on
     (seed, position), never on its batchmates — solo == batched, and
     greedy rows inside a mixed batch == the all-greedy engine;
@@ -22,11 +22,9 @@ Pins, bottom-up:
     automaton validity of every emitted token;
   - the jaxpr assert: the sampled whole-step decode program contains
     NO [*, V] intermediate outside the kernel — the [w, V] logits row
-    never reaches HBM — while the materialized arm's program (the
-    positive control) does.
+    never reaches HBM — while the "proc" program of the same engine
+    (the positive control) does.
 """
-import warnings
-
 import numpy as np
 import pytest
 import jax
@@ -135,7 +133,7 @@ class TestFoldBitIdentity:
         _assert_same(ref_sampled, outs, "multi+K8")
         h = eng.health()
         assert h["sampled_requests"] == 3
-        assert h["sample_k"] == 8 and h["sample_fold"] is True
+        assert h["sample_k"] == 8
 
     def test_tp2_multi_k8(self, tiny, prompts, ref_sampled):
         model, _ = tiny
@@ -144,13 +142,17 @@ class TestFoldBitIdentity:
         _assert_same(ref_sampled, outs, "tp2+multi+K8")
 
     def test_materialized_arm(self, tiny, prompts, ref_sampled):
-        # sample_fold=False keeps the [w, V] logits and selects on the
-        # materialized row — same survivor set, same key stream, same
-        # bits (the arm cb_sampling benchmarks the fold against)
+        # a request that needs processors runs the "proc" program,
+        # which keeps the [w, V] logits and selects on the materialized
+        # row; under a NEUTRAL chain (no penalties, the always-allow
+        # automaton) that is the same survivor set, the same key stream
+        # and the same bits as the fold
         model, _ = tiny
-        outs, _ = _run(model, prompts, [_sp(i) for i in range(3)],
-                       megakernel="multi", decode_block=8,
-                       sample_fold=False)
+        allow = TokenMaskAutomaton.trivial(V)
+        specs = [_sp(i, grammar=allow) for i in range(3)]
+        assert all(s.needs_processors for s in specs)
+        outs, _ = _run(model, prompts, specs,
+                       megakernel="multi", decode_block=8)
         _assert_same(ref_sampled, outs, "multi+K8+materialized")
 
     def test_mixed_greedy_sampled_batch(self, tiny, prompts,
@@ -510,28 +512,30 @@ class TestNoMaterializedLogits:
             f"[*, {V}] logits materialized in the folded sampled "
             f"decode program: {bad}")
 
-        # positive control — the walker is not blind: the MATERIALIZED
-        # arm's program (same signature, sample_fold=False) must show
-        # the vocab row it deliberately keeps
-        eng2 = ContinuousBatchingEngine(model, megakernel="multi",
-                                        decode_block=8,
-                                        sample_fold=False, **ENGINE_KW)
-        prog2 = eng2._build_cb_fused(seen["w"], False, True, False,
-                                     mode="sampled")
-        jaxpr2 = jax.make_jaxpr(prog2)(*seen["structs"]).jaxpr
+        # positive control — the walker is not blind: the "proc"
+        # program of the same engine (the sampled inputs plus the
+        # penalty / grammar state) must show the vocab row it keeps
+        w = seen["w"]
+        proc_extras = eng._row_params([None] * w, "proc")[6:]
+        prog2 = eng._build_cb_fused(w, False, True, False, mode="proc")
+        jaxpr2 = jax.make_jaxpr(prog2)(*seen["structs"],
+                                       *proc_extras).jaxpr
         assert _vocab_intermediates(jaxpr2), (
-            "materialized arm shows no vocab row — walker broken?")
+            '"proc" program shows no vocab row — walker broken?')
 
 
 # -- typed gates, deprecation, routing ---------------------------------------
 class TestGatesAndRouting:
-    def test_engine_do_sample_deprecated(self, tiny):
+    @pytest.mark.parametrize("gone", [
+        {"do_sample": True}, {"temperature": 0.8}, {"top_k": 4},
+        {"top_p": 0.9}, {"seed": 11}, {"sample_fold": False}],
+        ids=lambda kw: next(iter(kw)))
+    def test_engine_level_sampling_options_refused(self, tiny, gone):
+        # sampling is per request (add_request(sampling=...)); the
+        # engine-level knobs are gone and fail as any unknown option
         model, _ = tiny
-        with pytest.warns(DeprecationWarning):
-            eng = ContinuousBatchingEngine(model, do_sample=True,
-                                           temperature=0.8, seed=11,
-                                           **ENGINE_KW)
-        assert eng.sample_k == 8              # still functional
+        with pytest.raises(TypeError):
+            ContinuousBatchingEngine(model, **gone, **ENGINE_KW)
 
     def test_top_k_exceeding_sample_k_rejected(self, tiny, prompts):
         model, _ = tiny

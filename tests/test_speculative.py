@@ -142,21 +142,23 @@ class TestDrafters:
 
 
 class TestSpecByteIdentity:
-    @pytest.mark.parametrize("db", [1, 4, 8])
+    @pytest.mark.parametrize("width,db", [(4, 1), (4, 4), (4, 8),
+                                          (2, 1), (8, 1)])
     def test_greedy_identity_across_decode_blocks(self, gqa_tiny,
-                                                  ref_outs, db):
+                                                  ref_outs, width, db):
         # THE acceptance contract: spec output == non-spec output, byte
         # for byte, at decode_block 1 (one verify pass per dispatch), 4
-        # and 8 (multi-pass blocks with optimistic draft slices);
-        # parametrized so each compile stays inside the per-test budget
+        # and 8 (multi-pass blocks with optimistic draft slices), and at
+        # the narrowest and a wide verify width; parametrized so each
+        # compile stays inside the per-test budget
         model, cfg = gqa_tiny
         prompts = spec_prompts(cfg)
-        eng = mk(model, speculate=4, decode_block=db)
+        eng = mk(model, speculate=width, decode_block=db)
         outs = eng.generate_many(prompts, max_new_tokens=14)
         for i, (a, b) in enumerate(zip(ref_outs, outs)):
             np.testing.assert_array_equal(
-                a, b, err_msg=f"spec diverged at decode_block={db} "
-                f"request {i}")
+                a, b, err_msg=f"speculate={width} diverged at "
+                f"decode_block={db} request {i}")
         h = eng.health()
         assert h["spec_passes"] > 0
         assert h["spec_emitted"] >= h["spec_passes"]
@@ -498,7 +500,7 @@ class TestSpecSoak:
     def test_acceptance_rate_sweep(self, gqa_tiny):
         """Repetitive workload: acceptance should not degrade as the
         verify width grows, and tokens/pass should exceed 1.3 by K=8
-        (the decode_bench acceptance bar, pinned here deterministically)."""
+        (pinned deterministically)."""
         model, cfg = gqa_tiny
         rng = np.random.RandomState(13)
         motif = rng.randint(0, cfg.vocab_size, (4,))

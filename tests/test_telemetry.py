@@ -211,7 +211,7 @@ ENGINE_HEALTH_KEYS = frozenset({
     "drafter", "spec_passes", "spec_emitted", "spec_accept_rate",
     "spec_tokens_per_pass", "draft_errors",
     # on-device sampling v2 (PR 18: inference/sampling.py)
-    "sampled_requests", "sample_k", "sample_fold",
+    "sampled_requests", "sample_k",
     "spec_sampled_accept_rate",
     "handoffs_out", "handoffs_in",
     "kv_tier", "demoted", "pages_demoted", "demotions", "restores",
@@ -543,22 +543,23 @@ class TestProfilerAndProbe:
         assert "tel_span_two" in names
         assert "tel_span_one" not in names
 
-    def test_dispatch_seconds_and_probe(self, tiny):
+    def test_span_totals_time_dispatch_and_fetch(self, tiny):
+        """The always-on spans are the engine's only timers: with no
+        profiler session, span_totals() holds (count, seconds) of every
+        decode step's dispatch and fetch."""
         model, cfg = tiny
         eng = ContinuousBatchingEngine(model, **ENGINE_KW)
         rng = np.random.RandomState(23)
         p = rng.randint(0, cfg.vocab_size, (6,)).astype(np.int64)
+        before = profiler.span_totals()
         eng.generate_many([p], max_new_tokens=3)
-        assert eng.dispatch_seconds > 0
-        assert eng.device_seconds == eng.dispatch_seconds  # alias
-        t = eng.probe_device_step_seconds(iters=3)
-        assert t > 0
-        assert 0.0 <= eng.device_busy_frac(1.0, 10, t) <= 1.0
-        # busy engines refuse: the probe clobbers page-0 KV slots
-        eng.add_request(p, max_new_tokens=3)
-        with pytest.raises(RuntimeError, match="idle"):
-            eng.probe_device_step_seconds()
-        eng.drain()
+        after = profiler.span_totals()
+        assert eng.decode_steps > 0
+        for name in ("cb.decode.dispatch", "cb.decode.fetch"):
+            n0, s0 = before.get(name, (0, 0.0))
+            n1, s1 = after[name]
+            assert n1 - n0 == eng.decode_steps
+            assert s1 - s0 > 0
 
     def test_jsonl_streaming(self, tiny, tmp_path):
         model, cfg = tiny

@@ -1,32 +1,40 @@
 #!/usr/bin/env python3
 """Where ONE per-layer decode megakernel call spends its time at the dense
 serving cell's geometry (InternLM2-7B widths, 32 slots x 8 pages of 128,
-bf16 activations), per block budget of the tile plan
-(`decode_megakernel.MM_BLOCK_BYTES`; 0.25 MiB is the 512 x 512 int8 tile
-the kernel had before PR 27).
+bf16 activations).
 
-  chip:  chiprun -- python3 docs/probes/mk_layer_probe.py
+  chip:  chiprun -- python3 docs/probes/mk_layer_probe.py [--budgets]
   here:  JAX_PLATFORMS=cpu python3 docs/probes/mk_layer_probe.py --aot
 
 A probe, run by hand: no benchmark cell runs it, no test imports it. It
-sets the module's budget constant and swaps the shared tile body, which
-is what a probe may do and an engine may not. `--aot` compiles every
-variant for a DESCRIBED v5e and runs nothing (what the TPU compiler
-refuses here, VMEM above all, it would refuse on the chip). On the chip
-each variant is a scan of CALLS layer calls in one program, timed by the
-host clock around `block_until_ready`, best of REPS: ms a call, and us a
-grid step of the call's schedule.
+sets the module's budget constant, swaps the shared tile body and takes
+a phase out of the schedule, which is what a probe may do and an engine
+may not. It reads only names every tree since PR 27 has, so the same
+file copied into a checkout of an older commit measures that commit
+(parent against change, one chip call). `--aot` compiles every variant
+for a DESCRIBED v5e and runs nothing (what the TPU compiler refuses
+here, VMEM above all, it would refuse on the chip). On the chip each
+variant is a scan of CALLS layer calls in one program, timed by the host
+clock around `block_until_ready`, best of REPS: ms a call, and us a grid
+step of the call's schedule.
 
-Variants, per budget: the product in or taken out (`walk`: the block is
-still fetched, nothing is computed from it but one row; `convert`: the
-int8 -> bf16 conversion and a float32 sum of the tile on the VPU, no MXU:
-more vector work than the product needs, so no floor under it), the
-attention phase walking LIVE pages
-a slot (2 is the cell's mean) or skipped (every slot inactive: its 256
-steps are still walked). Dense bf16 weights at the settled budget show
-whether bytes or steps set the time. Lines go to stdout as JSON and to
+Default plan (PR 29): the attention phase. LIVE pages a slot over
+0 (every slot inactive), 2 (the cell's mean by length), 4 and 8 (the
+whole table), and once with the phase out of the schedule: what is left
+is the matmul phases, and `attention_share` of a variant is
+1 - that / its own time.
+
+`--budgets` (PR 27): the block budget of the tile plan
+(`decode_megakernel.MM_BLOCK_BYTES`; 0.25 MiB is the 512 x 512 int8 tile
+the kernel had before PR 27), the product in or taken out (`walk`: the
+block is still fetched, nothing is computed from it but one row;
+`convert`: the int8 -> bf16 conversion and a float32 sum of the tile on
+the VPU, no MXU: more vector work than the product needs, so no floor
+under it), and dense bf16 weights at the settled budget to show whether
+bytes or steps set the time. Lines go to stdout as JSON and to
 chiprun_out/mk_layer_probe.json.
 """
+import inspect
 import json
 import os
 import sys
@@ -59,12 +67,19 @@ TILES = {
     "walk": lambda x, w, dt=None: jnp.broadcast_to(
         w[:8, :].astype(f32)[:1], (x.shape[0], w.shape[1])),
 }
-# (budget, tile body, dense bf16 weights, live pages a slot)
-PLAN = [(b, t, False, live)
-        for b in (MiB // 4, MiB, 2 * MiB, 4 * MiB)
-        for t, live in (("product", LIVE), ("product", 0), ("walk", 0))]
-PLAN += [(2 * MiB, "convert", False, 0), (2 * MiB, "product", False, MP),
-         (MiB // 2, "product", True, 0), (2 * MiB, "product", True, 0)]
+# (budget, tile body, dense bf16 weights, live pages a slot; None = the
+# attention phase out of the schedule)
+ATTN_PLAN = [(2 * MiB, "product", False, live)
+             for live in (None, 0, 2, 4, MP)]
+BUDGET_PLAN = [(b, t, False, live)
+               for b in (MiB // 4, MiB, 2 * MiB, 4 * MiB)
+               for t, live in (("product", LIVE), ("product", 0),
+                               ("walk", 0))]
+BUDGET_PLAN += [(2 * MiB, "convert", False, 0),
+                (2 * MiB, "product", False, MP),
+                (MiB // 2, "product", True, 0),
+                (2 * MiB, "product", True, 0)]
+FULL = dm.SEG_PHASES["full"]
 
 
 def weight(k, n, i, dense):
@@ -85,6 +100,7 @@ def layer(dense):
 
 def main():
     aot = "--aot" in sys.argv
+    plan = BUDGET_PLAN if "--budgets" in sys.argv else ATTN_PLAN
     if aot:
         from jax.experimental import topologies
         from jax.sharding import SingleDeviceSharding
@@ -101,14 +117,23 @@ def main():
     h0 = jax.random.normal(jax.random.fold_in(key, 10), (R, H), bf16)
     cos, sin = jnp.ones((R, HD // 2), f32), jnp.zeros((R, HD // 2), f32)
     packs = {d: dm.pack_decode_layer(layer(d), cdtype=bf16)
-             for d in sorted({p[2] for p in PLAN})}
-    recs = []
-    for budget, tile, dense, live in PLAN:
+             for d in sorted({p[2] for p in plan})}
+    recs, no_attn_ms = [], None
+    # before PR 29 the plan took the table's width: a step a (slot, page)
+    by_table = "pages" in inspect.signature(dm.layer_tile_plan).parameters
+    for budget, tile, dense, live in plan:
+        jax.clear_caches()      # the call is a jit of its own since PR 29:
+        #                         what is swapped here is not in its key
         dm.MM_BLOCK_BYTES = budget
         dm.dot_tile_f32 = TILES[tile]
+        dm.SEG_PHASES["full"] = (FULL if live is not None else tuple(
+            ph for ph in FULL if ph != dm.PH_ATTN))
         mk = packs[dense]
-        steps = dm.layer_tile_plan(mk, R, MP)["layer_steps"]
-        lens = jnp.full((R,), max(live * P - 1, 0), jnp.int32)
+        steps = dict((dm.layer_tile_plan(mk, R, MP) if by_table
+                      else dm.layer_tile_plan(mk, R))["layer_steps"])
+        if live is None:
+            steps["attention"] = 0
+        lens = jnp.full((R,), max((live or 0) * P - 1, 0), jnp.int32)
         act = jnp.full((R,), 1 if live else 0, jnp.int32)
 
         @jax.jit
@@ -140,6 +165,10 @@ def main():
                 n = steps["matmul"] + steps["attention"]
                 rec.update(ms_a_call=min(ts), ms_a_call_all=ts,
                            us_a_grid_step=min(ts) * 1e3 / n)
+                if live is None:
+                    no_attn_ms = min(ts)
+                elif no_attn_ms is not None:
+                    rec["attention_share"] = 1 - no_attn_ms / min(ts)
         except Exception as e:      # a variant Mosaic refuses is a finding
             rec["error"] = repr(e)[:600]
         print(json.dumps(rec), flush=True)

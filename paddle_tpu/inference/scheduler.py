@@ -2277,8 +2277,8 @@ class ContinuousBatchingEngine(LLMEngine):
                   for ws in W["layers"]]
         # which blocks the walk streams and how many grid steps a layer
         # call takes at a full batch (static; health()["mk_tile_plan"])
-        self.mk_tile_plan = layer_tile_plan(
-            packed[0], self.max_batch, self.max_pages_per_seq, self.tp)
+        self.mk_tile_plan = layer_tile_plan(packed[0], self.max_batch,
+                                            self.tp)
         mk = (stack_packed(packed) if self.megakernel == "multi"
               else packed)
         head_w = (W["head"][0] if isinstance(W["head"], tuple)

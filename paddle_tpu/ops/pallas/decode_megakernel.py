@@ -13,8 +13,13 @@ WHOLE tensor program):
   - ONE 1-D grid walks a statically-built SCHEDULE of tiles:
       [per layer]  Q -> K -> V -> ATTN -> O -> G -> U -> D
       [step tail]  final-norm -> HEAD (lm_head n-tiles over vocab)
-    Matmul phases iterate (n-tile outer, k-tile inner); ATTN iterates
-    (slot, page); HEAD additionally maintains a RUNNING ARGMAX over the
+    Matmul phases iterate (n-tile outer, k-tile inner); ATTN takes ONE
+    step per slot and loops inside it over that slot's LIVE pages only
+    (ceil(seq_len / page) of them, none for an inactive slot), fetched
+    from the pools left in HBM by the kernel's own double-buffered
+    copies — a grid step costs about a microsecond whatever it moves,
+    so a page that holds nothing must not cost one (PERF.md 6, PR 29);
+    HEAD additionally maintains a RUNNING ARGMAX over the
     emitted logits tiles so the greedy next token leaves the kernel as
     a [b] int32 — the engine's `lax.scan` then drives the invocation
     directly and a decode_block=K block is kernel launches plus only
@@ -274,11 +279,13 @@ def _rope_flat(x, c, s, n_heads, hd, cdtype):
     return jnp.concatenate(outs, axis=1)
 
 
-def _build_schedule(L, b, mp, counts, phases, head_counts=None):
+def _build_schedule(L, b, counts, phases, head_counts=None):
     """Static tile walk -> four int32 arrays (phase, a0, a1, layer).
     Matmul phases: a0 = k-tile (inner), a1 = n-tile (outer) — k inner
     matches quantized_matmul's grid so each output tile's f32
-    accumulation order is identical. ATTN: a0 = slot, a1 = page. The
+    accumulation order is identical. ATTN: one entry per slot (a0 =
+    slot); which of the slot's pages are live is known only at run
+    time, so the pages are the kernel's loop, not the schedule's. The
     HEAD phase (when present) appends after the last layer with
     li = L-1 so every stacked layer-weight BlockSpec stays pinned on
     its final block (no spurious re-DMA)."""
@@ -287,9 +294,8 @@ def _build_schedule(L, b, mp, counts, phases, head_counts=None):
         for P in phases:
             if P == PH_ATTN:
                 for slot in range(b):
-                    for page in range(mp):
-                        ph.append(P); a0.append(slot); a1.append(page)
-                        li.append(lyr)
+                    ph.append(P); a0.append(slot); a1.append(0)
+                    li.append(lyr)
             else:
                 nk, nn = counts[P]
                 for n in range(nn):
@@ -470,43 +476,72 @@ def _mk_kernel(*args, names, seg, stacked, counts, bks, bns, dims,
         def _emit_h():
             refs["ho"][...] = hs[...]
 
-    # -- paged attention phase (a0 = slot, a1 = page) ------------------
+    # -- paged attention phase (a0 = slot; the slot's live pages loop
+    # -- inside the step) ---------------------------------------------
     if PH_ATTN in SEG_PHASES[seg]:
         attn_tgt = refs["attn_scr"]
         m_scr, l_scr, aacc = refs["m_scr"], refs["l_scr"], refs["aacc_scr"]
         tblr, lensr, actr = refs["tbl"], refs["lens"], refs["act"]
         wmr = refs["wm"]
+        kbuf, vbuf, psem = refs["kbuf"], refs["vbuf"], refs["page_sem"]
 
-        @pl.when(ph == PH_ATTN)
-        def _attn():
-            slot = a0
-            page = a1
+        def page_copies(slot, page):
+            # logical page `page` of `slot`, pool (HBM) -> buffer
+            # page % 2: the SAME descriptors start a copy and wait on it
+            pg = tblr[slot, page]
+            buf = jax.lax.rem(page, jnp.int32(2))
+            src = (lyr, pg) if stacked else (pg,)
+            return (pltpu.make_async_copy(refs["kp"].at[src], kbuf.at[buf],
+                                          psem.at[0, buf]),
+                    pltpu.make_async_copy(refs["vp"].at[src], vbuf.at[buf],
+                                          psem.at[1, buf]))
 
-            @pl.when(page == 0)
-            def _():
-                m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-                l_scr[...] = jnp.zeros_like(l_scr)
-                aacc[...] = jnp.zeros_like(aacc)
+        def start_page(slot, page):
+            for cp in page_copies(slot, page):
+                cp.start()
 
-            alive = actr[slot] > 0
+        def live_pages(slot):
             # NOTE: every jnp.where operand in this kernel must be an
             # explicitly-typed i32 — interpret mode re-discharges the
             # kernel jaxpr at OUTER-jit lowering time, outside the
             # enable_x64(False) window, and a weak python-int literal
             # re-canonicalizes to i64 there, producing an inconsistent
             # select_n (MLIR verify error).
-            seq_len = jnp.where(alive, lensr[slot] + jnp.int32(T),
-                                jnp.int32(0))
-            page_start = page * p
-            run = jnp.logical_and(alive, page_start < seq_len)
+            seq_len = jnp.where(actr[slot] > 0,
+                                lensr[slot] + jnp.int32(T), jnp.int32(0))
+            # pages that hold a position < seq_len, walked in ascending
+            # order as the unfused kernels do: 0 for an inactive slot
+            return seq_len, jnp.minimum(
+                (seq_len + jnp.int32(p - 1)) // jnp.int32(p), jnp.int32(mp))
 
-            @pl.when(run)
-            def _compute():
-                k = (refs["kp"][0, 0] if stacked
-                     else refs["kp"][0]).astype(jnp.float32)
-                v = (refs["vp"][0, 0] if stacked
-                     else refs["vp"][0]).astype(jnp.float32)
-                base = lensr[slot]
+        @pl.when(ph == PH_ATTN)
+        def _attn():
+            slot = a0
+            m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+            l_scr[...] = jnp.zeros_like(l_scr)
+            aacc[...] = jnp.zeros_like(aacc)
+
+            seq_len, n_live = live_pages(slot)
+            base = lensr[slot]
+
+            # a slot's first page is started by the step before it (at
+            # its end, below), so the copy flies over the step boundary
+            # instead of being waited for; the phase's first slot has no
+            # such step
+            @pl.when(jnp.logical_and(slot == 0, n_live > 0))
+            def _():
+                start_page(slot, jnp.int32(0))
+
+            def _page(page, carry):
+                @pl.when(page + jnp.int32(1) < n_live)
+                def _():
+                    start_page(slot, page + jnp.int32(1))
+                for cp in page_copies(slot, page):
+                    cp.wait()
+                buf = jax.lax.rem(page, jnp.int32(2))
+                k = kbuf[buf].astype(jnp.float32)
+                v = vbuf[buf].astype(jnp.float32)
+                page_start = page * jnp.int32(p)
                 rows_i = jax.lax.broadcasted_iota(jnp.int32, (p, 1, 1), 0)
                 if T == 1:
                     # v1 single-token path: substitute the current
@@ -593,30 +628,37 @@ def _mk_kernel(*args, names, seg, stacked, counts, bks, bns, dims,
                 aacc[...] = alpha * aacc[...] + wv_diag(w, v, hd,
                                                         rep=wrows)
                 m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+                return carry
 
-            @pl.when(page == mp - 1)
-            def _emit():
-                l_fin = jnp.maximum(l_scr[:, :1], jnp.float32(1e-30))
-                res = (aacc[...] / l_fin).astype(cdtype).astype(
-                    jnp.float32)
-                if T == 1:
-                    row = res.reshape(1, NQ)               # [nh, hd]
-                    if NQp != NQ:     # scratch pads must be exact zeros
+            jax.lax.fori_loop(jnp.int32(0), n_live, _page, jnp.int32(0))
+
+            # every copy of this slot has been waited for: both buffers
+            # are free, and the next slot's first page goes into buffer 0
+            @pl.when(slot + jnp.int32(1) < jnp.int32(b))
+            def _():
+                @pl.when(live_pages(slot + jnp.int32(1))[1] > 0)
+                def _():
+                    start_page(slot + jnp.int32(1), jnp.int32(0))
+
+            # a slot with no live page leaves l = 0, acc = 0: exact zeros
+            l_fin = jnp.maximum(l_scr[:, :1], jnp.float32(1e-30))
+            res = (aacc[...] / l_fin).astype(cdtype).astype(jnp.float32)
+            if T == 1:
+                row = res.reshape(1, NQ)                   # [nh, hd]
+                if NQp != NQ:         # scratch pads must be exact zeros
+                    row = jnp.pad(row, ((0, 0), (0, NQp - NQ)))
+                attn_tgt[pl.ds(slot, 1), :] = row
+            else:
+                res3 = res.reshape(nh, T, hd)
+                for qi in range(T):
+                    row = res3[:, qi, :].reshape(1, NQ)
+                    if NQp != NQ:
                         row = jnp.pad(row, ((0, 0), (0, NQp - NQ)))
-                    attn_tgt[pl.ds(slot, 1), :] = row
-                else:
-                    res3 = res.reshape(nh, T, hd)
-                    for qi in range(T):
-                        row = res3[:, qi, :].reshape(1, NQ)
-                        if NQp != NQ:
-                            row = jnp.pad(row,
-                                          ((0, 0), (0, NQp - NQ)))
-                        attn_tgt[pl.ds(slot * T + qi, 1), :] = row
-                if seg == "qkv":        # segment ends here: emit it
-                    @pl.when(slot == b - 1)
-                    def _():
-                        refs["attn_out"][...] = attn_tgt[...].astype(
-                            cdtype)
+                    attn_tgt[pl.ds(slot * T + qi, 1), :] = row
+            if seg == "qkv":            # segment ends here: emit it
+                @pl.when(slot == b - 1)
+                def _():
+                    refs["attn_out"][...] = attn_tgt[...].astype(cdtype)
 
     # -- whole-step tail: final norm + lm_head tiles + running argmax --
     if head:
@@ -766,7 +808,29 @@ def decode_megakernel(h, mk, k_pages=None, v_pages=None, page_table=None,
                   act [R, mlp_v] local gate*up)
       seg="down": h + act_in (gathered, full ffn) -> h_out, plus the
                   head outputs when head= rides (vocab-local slice).
+
+    The call is a `jax.jit` of its own, keyed by the shapes and the
+    static arguments: the 32 per-layer calls of a decode program are one
+    trace of the kernel and one lowering to a Mosaic module, called 32
+    times (XLA inlines the calls), not 32 of each — python tracing was
+    most of a serving engine's set-up (PERF.md 6, PR 29).
     """
+    return _decode_megakernel(
+        h, mk, k_pages, v_pages, page_table, lens, active, cos_sel,
+        sin_sel, head, wmask, attn_in, act_in, nh=nh, nh_kv=nh_kv, hd=hd,
+        eps=eps, scale=scale, interpret=interpret, seg=seg, head_v=head_v,
+        head_k=head_k, mlp_v=mlp_v, tq=tq, block_bytes=MM_BLOCK_BYTES)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "nh", "nh_kv", "hd", "eps", "scale", "interpret", "seg", "head_v",
+    "head_k", "mlp_v", "tq", "block_bytes"))
+def _decode_megakernel(h, mk, k_pages, v_pages, page_table, lens, active,
+                       cos_sel, sin_sel, head, wmask, attn_in, act_in, *,
+                       nh, nh_kv, hd, eps, scale, interpret, seg, head_v,
+                       head_k, mlp_v, tq, block_bytes):
+    # block_bytes: the module's MM_BLOCK_BYTES as the caller saw it (the
+    # tests' handle on the constant), static so it keys the trace
     R, H = h.shape
     if seg not in SEG_PHASES:
         raise ValueError(f"unknown megakernel segment {seg!r}")
@@ -786,8 +850,8 @@ def decode_megakernel(h, mk, k_pages=None, v_pages=None, page_table=None,
 
     def mm_dims(P, w):
         kdim, ndim = w.shape[-2:]
-        bks[P], bns[P], k_pad, n_pad = mm_tile_plan(kdim, ndim,
-                                                    w.dtype.itemsize)
+        bks[P], bns[P], k_pad, n_pad = mm_tile_plan(
+            kdim, ndim, w.dtype.itemsize, block_bytes)
         assert (k_pad, n_pad) == (kdim, ndim), (
             "weights must come from pack_decode_layer / pack_lm_head",
             (kdim, ndim), (k_pad, n_pad))
@@ -852,7 +916,7 @@ def decode_megakernel(h, mk, k_pages=None, v_pages=None, page_table=None,
     dims.update(Hp=Hp, NQp=NQp, b=b)
 
     ph_arr, a0_arr, a1_arr, li_arr = _build_schedule(
-        L, b, mp, counts, SEG_PHASES[seg], counts.get(PH_H))
+        L, b, counts, SEG_PHASES[seg], counts.get(PH_H))
     n_steps = ph_arr.size
     bn_max = max(bns.values())
 
@@ -900,22 +964,6 @@ def decode_megakernel(h, mk, k_pages=None, v_pages=None, page_table=None,
             return (li[st], 0, 0) if stacked else (0, 0)
 
         return pl.BlockSpec(((1, 1, Hp) if stacked else (1, Hp)), idx)
-
-    def page_spec():
-        def idx(st, ph, a0, a1, li, *rest):
-            tbl, ac = rest[0], rest[2]
-            mine = ph[st] == PH_ATTN
-            before = ph[st] < PH_ATTN
-            slot = jnp.where(mine, a0[st],
-                             jnp.where(before, i32(0), i32(b - 1)))
-            page = jnp.where(mine, a1[st],
-                             jnp.where(before, i32(0), i32(mp - 1)))
-            pg = tbl[slot, page] * ac[slot]
-            return ((li[st], pg, 0, 0, 0) if stacked
-                    else (pg, 0, 0, 0))
-
-        return pl.BlockSpec(((1, 1, p, nh_kv, hd) if stacked
-                             else (1, p, nh_kv, hd)), idx)
 
     def out_kv_spec():
         if stacked:
@@ -977,8 +1025,10 @@ def decode_megakernel(h, mk, k_pages=None, v_pages=None, page_table=None,
         add("wd", mk["wd"], w_spec(PH_D, "d", stacked))
         add("sd", mk["sd"], s_spec(PH_D, stacked))
     if has_attn:
-        add("kp", k_pages, page_spec())
-        add("vp", v_pages, page_spec())
+        # the pools stay in HBM: the ATTN step copies a slot's live
+        # pages itself (page_copies in _mk_kernel)
+        add("kp", k_pages, pl.BlockSpec(memory_space=pl.ANY))
+        add("vp", v_pages, pl.BlockSpec(memory_space=pl.ANY))
     if head is not None:
         add("nf", head["nf"], full_spec((1, Hp)))
         add("wh", head["wh"], w_spec(PH_H, "h", False))
@@ -1024,8 +1074,10 @@ def decode_megakernel(h, mk, k_pages=None, v_pages=None, page_table=None,
                     pltpu.VMEM((nh * T, 128), jnp.float32),
                     pltpu.VMEM((nh * T, hd), jnp.float32)]
     if has_attn:
-        scr_names += ["attn_scr"]
-        scratch += [pltpu.VMEM((R, NQp), jnp.float32)]
+        scr_names += ["attn_scr", "kbuf", "vbuf"]
+        scratch += [pltpu.VMEM((R, NQp), jnp.float32),
+                    pltpu.VMEM((2, p, nh_kv, hd), k_pages.dtype),
+                    pltpu.VMEM((2, p, nh_kv, hd), v_pages.dtype)]
     if seg in ("full", "tail"):
         scr_names += ["g_scr", "u_scr", "act_scr"]
         scratch += [pltpu.VMEM((R, Fg), cdtype)] * 3
@@ -1034,9 +1086,12 @@ def decode_megakernel(h, mk, k_pages=None, v_pages=None, page_table=None,
         scratch += [pltpu.VMEM((R, 128), jnp.float32),
                     pltpu.VMEM((R, 128), jnp.int32)]
 
+    # DMA semaphores [k | v, buffer] of the page copies: last, so the
+    # VMEM accounting below sees arrays only
+    sems = [pltpu.SemaphoreType.DMA((2, 2))] if has_attn else []
     kernel = functools.partial(
         _mk_kernel, names=tuple(pre_names + names + out_names
-                                + scr_names),
+                                + scr_names + ["page_sem"] * len(sems)),
         seg=seg, stacked=stacked, counts=counts, bks=bks, bns=bns,
         dims=dims, eps=float(eps), p=p, mp=mp, scale=float(s),
         head=head is not None, T=T,
@@ -1047,18 +1102,22 @@ def decode_megakernel(h, mk, k_pages=None, v_pages=None, page_table=None,
         grid=(n_steps,),
         in_specs=in_specs,
         out_specs=out_specs,
-        scratch_shapes=scratch,
+        scratch_shapes=scratch + sems,
     )
     # seven or eight double-buffered weight streams of up to
-    # MM_BLOCK_BYTES, two page blocks and their f32 copies, and the
-    # widest weight block once more in the type it reaches the MXU in
-    # (an int8 block converts to twice its bytes of bf16): far past the
-    # 16 MiB default at 7B width
+    # MM_BLOCK_BYTES, and the widest weight block once more in the type
+    # it reaches the MXU in (an int8 block converts to twice its bytes
+    # of bf16): far past the 16 MiB default at 7B width. The page
+    # blocks are no pipelined operands (the pools stay in HBM, block
+    # shape None): their two buffers per pool are scratch, counted once,
+    # and the f32 copies of one k and one v page, before and after the
+    # feed tokens are substituted, are the four temporaries
     f32 = jnp.float32
     blocks = [(sp.block_shape, op.dtype)
-              for sp, op in zip(in_specs, operands)]
-    w_ops = [(shape, jnp.dtype(mm_operand_dtype(cdtype, dt)))
-             for (shape, dt), name in zip(blocks, names)
+              for sp, op in zip(in_specs, operands)
+              if sp.block_shape is not None]
+    w_ops = [(sp.block_shape, jnp.dtype(mm_operand_dtype(cdtype, op.dtype)))
+             for sp, op, name in zip(in_specs, operands, names)
              if name[0] == "w"]
     limit = vmem_limit(
         blocks=blocks + [(sp.block_shape, sd.dtype)
@@ -1100,13 +1159,15 @@ def decode_megakernel(h, mk, k_pages=None, v_pages=None, page_table=None,
     return tuple(ret) if len(ret) > 1 else ret[0]
 
 
-def layer_tile_plan(layer, slots, pages, tp=1):
+def layer_tile_plan(layer, slots, tp=1):
     """What ONE layer's schedule walk is made of, from a packed layer
     (pack_decode_layer's dict; tp = the shards its column-parallel
     projections are concatenated for): per projection [bk, bn] as
     decode_megakernel() will draw them, and the grid steps of the
-    layer's matmul phases and of its attention phase (one page a step,
-    live or not). Static facts of an engine: health()["mk_tile_plan"]."""
+    layer's matmul phases and of its attention phase: one step a slot,
+    which loops over the slot's live pages (`attention_pages`: the walk
+    no longer depends on the page table's width). Static facts of an
+    engine: health()["mk_tile_plan"]."""
     blocks, steps = {}, 0
     for key in "qkvogud":
         w = layer["w" + key]
@@ -1116,8 +1177,8 @@ def layer_tile_plan(layer, slots, pages, tp=1):
         bk, bn, _, _ = mm_tile_plan(k, n, w.dtype.itemsize)
         blocks[key] = [bk, bn]
         steps += (k // bk) * (n // bn)
-    return {"blocks": blocks,
-            "layer_steps": {"matmul": steps, "attention": slots * pages}}
+    return {"blocks": blocks, "attention_pages": "live",
+            "layer_steps": {"matmul": steps, "attention": slots}}
 
 
 def megakernel_weight_bytes(mk, n_layers=None, head=None):

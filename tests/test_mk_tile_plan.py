@@ -12,7 +12,8 @@ Pins:
     128-lane tile each (a small budget through the module constant — a
     test's handle, not an engine option) is byte-identical to the op
     chain, tq = 1 and tq > 1, and its segments compose to the full walk;
-  - `health()["mk_tile_plan"]` reports the plan and the grid steps.
+  - `health()["mk_tile_plan"]` reports the plan and the grid steps (since
+    ISSUE 29 the attention phase's are the slots: its pages are a loop).
 """
 import numpy as np
 import pytest
@@ -138,14 +139,18 @@ class TestPack:
             assert mk["w" + key].shape == kn
             assert mk["s" + key].shape == (1, kn[1])
         assert mk["wq"].dtype == (jnp.int8 if quant else jnp.bfloat16)
-        plan = dm.layer_tile_plan(mk, slots=32, pages=8, tp=tp)
+        plan = dm.layer_tile_plan(mk, slots=32, tp=tp)
         want = dict(WANT[1 if quant else 2])
         if tp == 2 and quant:
             want.update(q=2048, k=512, v=512)     # g / u stay 3584
         elif tp == 2:
             want.update(k=512, v=512, g=1792, u=1792)
         assert plan["blocks"] == {k: [512, v] for k, v in want.items()}
-        assert plan["layer_steps"]["attention"] == 256
+        # one attention step a slot, whatever the table's width (PR 29)
+        assert plan["layer_steps"]["attention"] == 32
+        assert plan["attention_pages"] == "live"
+        if quant and tp == 1:     # the dense serving cell's engine
+            assert plan["layer_steps"] == {"matmul": 124, "attention": 32}
 
     def test_aligned_weights_are_not_copied(self, monkeypatch):
         rng = np.random.RandomState(0)
@@ -266,13 +271,14 @@ class TestWideMultiTileEmission:
     def test_the_small_budget_gives_several_wide_tiles(self, wide,
                                                        monkeypatch):
         monkeypatch.setattr(dm, "MM_BLOCK_BYTES", WIDE_BUDGET)
-        plan = dm.layer_tile_plan(wide["mk"], wide["b"], wide["mp"])
+        plan = dm.layer_tile_plan(wide["mk"], wide["b"])
         assert plan["blocks"] == {
             "q": [512, 256], "k": [512, 256], "v": [512, 256],
             "o": [512, 256], "g": [512, 384], "u": [512, 384],
             "d": [512, 256]}
         # q 2x4, k and v 2x1, o 2x4, gate and up 2x4, down 3x4
-        assert plan["layer_steps"] == {"matmul": 48, "attention": 6}
+        assert plan["layer_steps"] == {"matmul": 48,
+                                       "attention": wide["b"]}
 
     @pytest.mark.parametrize("T", [1, 3], ids=["tq1", "tq3"])
     def test_byte_identical_to_the_op_chain(self, wide, monkeypatch, T):
@@ -341,7 +347,7 @@ class TestWideMultiTileEmission:
             monkeypatch.setattr(dm, "MM_BLOCK_BYTES", budget)
             outs.append(jax.jit(lambda hT: dm.decode_megakernel(
                 hT, st["mk"], *args, **self._kw(st)))(h))
-        assert dm.layer_tile_plan(st["mk"], 2, 3)["blocks"]["g"] == [
+        assert dm.layer_tile_plan(st["mk"], 2)["blocks"]["g"] == [
             512, 384]
         for got in outs[1:]:
             for a, c in zip(outs[0], got):
@@ -368,7 +374,9 @@ class TestEngineReportsThePlan:
         assert plan["blocks"] == {
             "q": [32, 32], "k": [32, 16], "v": [32, 16], "o": [32, 32],
             "g": [32, 48], "u": [32, 48], "d": [48, 32]}
-        assert plan["layer_steps"] == {"matmul": 7, "attention": 2 * 6}
+        # 2 slots: a step each, not one per column of the 6-wide table
+        assert plan["layer_steps"] == {"matmul": 7, "attention": 2}
+        assert plan["attention_pages"] == "live"
 
     def test_op_chain_reports_none(self, tiny):
         eng = ContinuousBatchingEngine(

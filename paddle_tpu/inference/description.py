@@ -13,7 +13,18 @@ A model that wants to be served implements two methods and nothing else:
       the layer has one; a dense FFN as `wg wu wd`; routed experts as
       `router` ([hidden, experts]), `router_bias` ([experts]), `w_gu`
       ([held, hidden, 2 x width], gate columns first) and `w_d` ([held,
-      width, hidden]). Matrices are [in, out].
+      width, hidden]) and, beside them, one shared expert as `ws_g ws_u
+      ws_d`. A LATENT attention layer (`AttentionSpec.latent`) has no
+      `wq wk wv`: it gives `wq_a` ([hidden, query rank]), `q_norm`,
+      `wq_b` ([query rank, heads x (no-position + rotary width)], a
+      head's no-position columns first), `wkv_a` ([hidden, latent rank +
+      rotary width]), `kv_norm`, the up-projection split by use as
+      `w_uk` ([latent rank, heads x no-position width]) and `w_uv`
+      ([latent rank, heads x value width]); `w_gate` ([hidden, heads])
+      where the layer gates its heads; and an indexer's `ix_wq` ([query
+      rank, index heads x index width]), `ix_wk` ([hidden, index
+      width]), `ix_kn_w ix_kn_b` (the index key's LayerNorm) and `ix_ww`
+      ([hidden, index heads]). Matrices are [in, out].
 
 The engine (serving.py, scheduler.py) reads the description and the
 canonical names and never asks what class the model is. What a
@@ -31,6 +42,33 @@ class UnsupportedByDescription(ValueError):
 
 
 @dataclasses.dataclass(frozen=True)
+class LatentSpec:
+    """Latent (low-rank) attention: ONE cached row a token, [c_kv ; k_r]
+    = the normed, rescaled latent and the rotated position key, shared
+    by every head. `AttentionSpec.qk_dim` is then no-position + rotary
+    width and `rope_dim` the TRAILING dims of a query head that rotate."""
+    q_rank: int
+    kv_rank: int
+    q_scale: float = 1.0            # c_q is multiplied after its norm
+    kv_scale: float = 1.0           # c_kv likewise
+
+    def row_width(self, rope_dim):
+        return self.kv_rank + rope_dim
+
+
+@dataclasses.dataclass(frozen=True)
+class IndexerSpec:
+    """Learned sparse attention: a query attends to the `top_k` visible
+    positions with the largest index score (all of them while fewer are
+    visible). One index key a token is cached beside the layer's rows."""
+    n_heads: int
+    dim: int
+    rope_dim: int                   # leading dims of q^I and k^I that rotate
+    top_k: int
+    eps: float = 1e-6               # of the index key's LayerNorm
+
+
+@dataclasses.dataclass(frozen=True)
 class AttentionSpec:
     n_heads: int
     n_kv_heads: int
@@ -41,11 +79,20 @@ class AttentionSpec:
     window: Optional[int] = None    # None = full causal attention
     sink: bool = False              # learned per-head sink logit
     value_scale: float = 1.0        # values are scaled before caching
+    latent: Optional[LatentSpec] = None
+    gate: bool = False              # head-wise sigmoid gate before wo
+    indexer: Optional[IndexerSpec] = None
 
     @property
     def group(self):
         """Layers with equal keys share a page pool shape, a page table
-        and a freeing policy."""
+        and a freeing policy. Per-head K and V: (KV heads, key width,
+        value width, window). Latent rows: (1, row width, index key
+        width or 0, window, "latent")."""
+        if self.latent is not None:
+            return (1, self.latent.row_width(self.rope_dim),
+                    self.indexer.dim if self.indexer else 0, self.window,
+                    "latent")
         return (self.n_kv_heads, self.qk_dim, self.v_dim, self.window)
 
 
@@ -56,6 +103,7 @@ class FFNSpec:
     n_experts: int = 0              # router outputs (all chips' experts)
     top_k: int = 0
     held: Tuple[int, int] = (0, 0)  # [lo, hi): the experts held HERE
+    shared_width: int = 0           # one shared SwiGLU expert beside them
 
 
 @dataclasses.dataclass(frozen=True)
@@ -100,11 +148,17 @@ class ModelDescription:
                 and a.qk_dim == a.v_dim == a.rope_dim
                 and a.n_heads * a.qk_dim == self.hidden_size
                 and a.window is None and not a.sink
-                and a.value_scale == 1.0)
+                and a.value_scale == 1.0
+                and a.latent is None and not a.gate
+                and a.indexer is None)
 
     @property
     def has_experts(self):
         return any(layer.ffn.kind == "experts" for layer in self.layers)
+
+    @property
+    def has_indexer(self):
+        return any(layer.attn.indexer is not None for layer in self.layers)
 
 
 def describe(model):

@@ -1,0 +1,204 @@
+"""A latent attention layer inside the continuous-batching engine's step
+programs: the row pool and the index-key pool written and read through a
+page table (ops/latent_attention.py has the mathematics, serving.py the
+pool shapes, docs/serving.md "Latent page groups" the design).
+
+Both run the ABSORBED form: the query carried into the latent space
+scores the cached rows directly, W_uv and the gate come after the sum.
+
+  decode_layer   one token a slot. A FULL layer scores every visible
+                 index key of every slot, selects exactly the top-k
+                 positions, gathers THOSE rows by page table and attends
+                 to them; a WINDOW layer gathers the pages its window
+                 touches.
+  prefill_layer  one chunk of one sequence over key blocks of the
+                 sequence's LIVE pages (online softmax): no tensor
+                 against all `pages_per_seq` pages exists. A full
+                 layer first fills a [chunk, max_len] float32 buffer of
+                 index scores block by block and marks each query's top-k
+                 in it (a radix select for the k-th value, no sort).
+
+The four device phases carry `jax.named_scope`s (the name stack reaches
+the profiler's event metadata; docs/observability.md): sparse_index_
+scores, sparse_select, sparse_attend, window_latent_attend.
+"""
+import jax
+import jax.numpy as jnp
+
+from ..ops import latent_attention as la
+from .serving import _rms
+
+KEY_BLOCK_PAGES = 4     # pages a prefill key block gathers
+# what a full layer's decode step adds to the engine's device counters:
+# index keys visible to its queries, cache rows they attended to, index
+# keys the scan scored (dead pages included), and the queries themselves
+SPARSE_COUNTS = ("visible", "attended", "scored", "queries")
+
+
+def _write(pool, slots, values):
+    """values [n, width] into the flat token slots of pool [pages, p,
+    width]; a slot at or past the pool's end is dropped."""
+    flat = pool.reshape(-1, pool.shape[-1])
+    return flat.at[slots].set(values.astype(pool.dtype),
+                              mode="drop").reshape(pool.shape)
+
+
+def _front(eng, W, wset, h, pos_ids, li):
+    """A latent layer up to its attention, for tokens h [b, t, hidden] at
+    pos_ids [b, t]: (q_n [b, t, H, no-position] and q_r [b, t, H, rotary]
+    float32; row [b, t, padded row] the tokens' cache rows, zero in the
+    padding; gate [b, t, H] float32 or None; the indexer's (q^I, k^I, w)
+    float32 or None). Products take bf16 operands on the chip, their sums
+    and the norms between them are float32."""
+    a = eng.desc.layers[li].attn
+    g = eng.groups[eng.desc.layer_group[li]]
+    cos, sin = eng._rope_of(W, li)
+    cos, sin = cos[pos_ids], sin[pos_ids]
+    x = _rms(h, wset["ln1"], W["eps"])
+    q_n, q_r, row, c_q = la.latent_qkv(x, wset, a, W["eps"], cos, sin)
+    row = jnp.pad(row, [(0, 0)] * 2 + [(0, g.row_pad - g.row_width)])
+    gate = la.head_gate(x, wset["w_gate"]) if a.gate else None
+    ix = la.index_qkw(x, c_q, wset, a.indexer, cos, sin) \
+        if a.indexer is not None else None
+    return q_n, q_r, row.astype(eng.kv_dtype), gate, ix
+
+
+def _gated(o, gate, dtype):
+    """The heads' outputs [..., H, value width] under the head-wise gate,
+    in the dtype the output projection takes."""
+    return (o if gate is None else o * gate[..., None]).astype(dtype)
+
+
+def _absorbed_query(eng, wset, q_n, q_r, a, g):
+    """The query carried into the latent space, zero over the row's
+    padding, in the cache's dtype."""
+    q_abs = la.absorb_query(q_n, q_r, wset["w_uk"], a)
+    pad = [(0, 0)] * (q_abs.ndim - 1) + [(0, g.row_pad - g.row_width)]
+    return jnp.pad(q_abs, pad).astype(eng.kv_dtype)
+
+
+def decode_layer(eng, W, wset, h, rows_pool, ix_pool, tab, lens, active, li):
+    """h [w, 1, hidden] -> (heads' outputs [w, 1, H, value width], the two
+    pools, `SPARSE_COUNTS` int32 of this layer's decode queries, zeros
+    for a window layer). tab [w, pages_per_seq] is the layer's group's
+    part of the page table."""
+    a = eng.desc.layers[li].attn
+    g = eng.groups[eng.desc.layer_group[li]]
+    p, mp, w = eng.page_size, eng.pages_per_seq, lens.shape[0]
+    r, scale = a.latent.kv_rank, la.softmax_scale(a)
+    q_n, q_r, row, gate, ix = _front(eng, W, wset, h, lens[:, None], li)
+    q_abs = _absorbed_query(eng, wset, q_n, q_r, a, g)
+    slots = jnp.where(active, tab[jnp.arange(w), lens // p] * p + lens % p,
+                      g.n_pages * p)
+    rows_pool = _write(rows_pool, slots, row[:, 0])
+    flat = rows_pool.reshape(-1, g.row_pad)
+    n_vis = jnp.where(active, lens + 1, 0)
+    if a.indexer is not None:
+        q_i, k_i, w_i = ix
+        ix_pool = _write(ix_pool, slots, k_i[:, 0])
+        with jax.named_scope("sparse_index_scores"):
+            keys = ix_pool[tab].reshape(w, mp * p, a.indexer.dim)
+            scores = la.index_scores(q_i, keys, w_i)[:, 0]
+        with jax.named_scope("sparse_select"):
+            visible = jnp.arange(mp * p)[None, :] < n_vis[:, None]
+            idx, valid = la.select_top(scores, visible, a.indexer.top_k)
+        with jax.named_scope("sparse_attend"):
+            sel = jnp.take_along_axis(tab, idx // p, axis=1) * p + idx % p
+            o_lat = la.attend_rows(q_abs[:, 0], flat[sel], valid, r, scale)
+        # the scan reads every table page of every slot of the bucket,
+        # live or not: what it scores is w x mp x p, not what is visible
+        counts = (jnp.sum(n_vis, dtype=jnp.int32),
+                  jnp.sum(valid, dtype=jnp.int32),
+                  jnp.int32(w * mp * p),
+                  jnp.sum(active, dtype=jnp.int32))
+    else:
+        with jax.named_scope("window_latent_attend"):
+            # the pages the window of the query at `lens` touches
+            n_ctx = min(mp, g.bound(1))
+            first = jnp.maximum(lens - a.window + 1, 0) // p
+            page_ix = first[:, None] + jnp.arange(n_ctx)[None, :]
+            pages = jnp.take_along_axis(
+                tab, jnp.minimum(page_ix, mp - 1), axis=1)
+            kpos = (page_ix[:, :, None] * p
+                    + jnp.arange(p)[None, None, :]).reshape(w, n_ctx * p)
+            qpos = lens[:, None]
+            valid = (kpos <= qpos) & (kpos > qpos - a.window) \
+                & active[:, None]
+            o_lat = la.attend_rows(
+                q_abs[:, 0], rows_pool[pages].reshape(w, n_ctx * p, -1),
+                valid, r, scale)
+        counts = (jnp.int32(0),) * len(SPARSE_COUNTS)
+    o = la.expand_values(o_lat, wset["w_uv"], a)    # W_uv after the sum
+    return (_gated(o[:, None], gate, eng.kv_dtype), rows_pool, ix_pool,
+            counts)
+
+
+def prefill_layer(eng, W, wset, h, rows_pool, ix_pool, tab, pos, t_end, li):
+    """One chunk of one sequence: h [1, chunk, hidden] at positions pos
+    [chunk] (those >= t_end are padding and write nothing) -> (heads'
+    outputs [1, chunk, H, value width], the two pools). tab [pages_per_
+    seq]."""
+    a = eng.desc.layers[li].attn
+    g = eng.groups[eng.desc.layer_group[li]]
+    p, mp, chunk = eng.page_size, eng.pages_per_seq, pos.shape[0]
+    q_n, q_r, row, gate, ix = _front(eng, W, wset, h, pos[None, :], li)
+    q_abs = _absorbed_query(eng, wset, q_n, q_r, a, g)
+    slots = jnp.where(pos < t_end, tab[pos // p] * p + pos % p,
+                      g.n_pages * p)
+    rows_pool = _write(rows_pool, slots, row[0])
+    nb = KEY_BLOCK_PAGES
+    kb = nb * p
+    qpos = pos[:, None]
+    last = jnp.minimum(pos[0] + chunk, t_end) - 1   # last real position
+
+    def block_pages(j):
+        """(pool pages [nb], key positions [kb]) of key block j; a page
+        past the table reads its last entry and is masked by position."""
+        page_ix = j * nb + jnp.arange(nb)
+        kpos = (page_ix[:, None] * p + jnp.arange(p)[None, :]).reshape(kb)
+        return tab[jnp.minimum(page_ix, mp - 1)], \
+            jnp.where(jnp.repeat(page_ix < mp, p), kpos, mp * p)
+
+    if a.indexer is not None:
+        q_i, k_i, w_i = ix
+        ix_pool = _write(ix_pool, slots, k_i[0])
+        hi_blk = last // kb + 1
+        with jax.named_scope("sparse_index_scores"):
+            def score_block(j, buf):
+                pages, kpos = block_pages(j)
+                keys = ix_pool[pages].reshape(kb, a.indexer.dim)
+                sc = la.index_scores(q_i[0], keys, w_i[0])
+                sc = jnp.where(kpos[None, :] <= qpos, sc, -jnp.inf)
+                return jax.lax.dynamic_update_slice(
+                    buf, sc, (jnp.zeros((), j.dtype), j * kb))
+
+            # columns past the live blocks stay -inf: not visible
+            width = -(-mp // nb) * kb
+            buf = jax.lax.fori_loop(
+                0, hi_blk, score_block,
+                jnp.full((chunk, width), -jnp.inf, jnp.float32))
+        with jax.named_scope("sparse_select"):
+            chosen = la.top_mask(buf, a.indexer.top_k)
+
+        def block(j):
+            pages, kpos = block_pages(j)
+            sel = jax.lax.dynamic_slice(
+                chosen, (jnp.zeros((), j.dtype), j * kb), (chunk, kb))
+            return rows_pool[pages].reshape(kb, -1), \
+                sel & (kpos[None, :] <= qpos)
+
+        lo_blk, scope = 0, "sparse_attend"
+    else:
+        def block(j):
+            pages, kpos = block_pages(j)
+            return rows_pool[pages].reshape(kb, -1), \
+                (kpos[None, :] <= qpos) & (kpos[None, :] > qpos - a.window)
+
+        # from the page the first query's window starts in
+        lo_blk = jnp.maximum(pos[0] - a.window + 1, 0) // kb
+        hi_blk, scope = last // kb + 1, "window_latent_attend"
+    with jax.named_scope(scope):
+        o_lat = la.attend_key_blocks(q_abs[0], block, lo_blk, hi_blk,
+                                     a.latent.kv_rank, la.softmax_scale(a))
+    o = la.expand_values(o_lat[None], wset["w_uv"], a)
+    return _gated(o, gate, eng.kv_dtype), rows_pool, ix_pool

@@ -52,6 +52,7 @@ from .sampling import (GREEDY, NEG, SamplingParams, TokenMaskAutomaton,
                        apply_penalties, fold_keys, select_from_topk,
                        stop_hit)
 from ..profiler import record_counters
+from . import latent
 from .description import UnsupportedByDescription
 from .serving import LLMEngine, EngineFullError, _rms, _mm, _mm_f32
 from .speculative import resolve_drafter
@@ -823,8 +824,11 @@ class ContinuousBatchingEngine(LLMEngine):
         # sync a step. int32 on the device: health() zeroes them as it
         # reads, so they overflow only if nobody asks for some 2^21
         # steps on end.
-        self._route_dev = (self._route_zeros() if self.desc.has_experts
-                           else None)
+        # the same for a description with an indexer: keys visible to and
+        # keys attended by its layers' decode queries, as (high, low 24
+        # bits) int32 pairs: a step adds up to slots x max_len a layer
+        self._counted = self.desc.has_experts or self.desc.has_indexer
+        self._route_dev = self._route_zeros() if self._counted else None
         self._route_totals = None
         # multi-LoRA adapter serving (inference/adapters.py): adapters=
         # {"rank": R, "max_adapters": N, "pool_pages": P, "page_elems":
@@ -1303,18 +1307,31 @@ class ContinuousBatchingEngine(LLMEngine):
         states = collections.Counter(
             r.state for r in self._requests.values())
         groups = [{"window": g.window, "layers": len(g.layers),
+                   "kind": "latent" if g.latent else "heads",
+                   "row_width": g.row_width,
+                   "index_width": g.index_width,
                    "kv_heads": g.n_kv_heads, "pages_total": g.n_pages,
                    "pages_free": g.allocator.available,
                    "pages_used": g.used,
                    "used_page_steps": g.used_page_steps,
                    "freed_behind_window": g.freed_behind_window}
                   for g in self.groups]
-        experts = None
+        experts = sparse = None
+        route = self._route_read() if self._counted else None
         if self.desc.has_experts:
-            route = self._route_read()
             experts = {"rows": route["rows"].tolist(),
                        "touched": route["touched"].tolist(),
                        "decode_steps": int(route["steps"])}
+        if self.desc.has_indexer:
+            # over the decode queries of the layers with an indexer:
+            # the index scan scores whole table pages, so `scored` holds
+            # dead keys too; queries / indexer layers = rows decoded
+            visible, attended, scored, queries = (
+                int(route[k][0]) * (1 << 24) + int(route[k][1])
+                for k in latent.SPARSE_COUNTS)
+            sparse = {"keys_visible": visible, "keys_attended": attended,
+                      "index_keys_scored": scored,
+                      "decode_queries": queries}
         # one timestamped sample of the always-on counters, beside
         # profiler.span_totals() (docs/observability.md): a reader that
         # knows two moments differences the samples nearest them
@@ -1329,6 +1346,9 @@ class ContinuousBatchingEngine(LLMEngine):
             counters["experts.decode_steps"] = experts["decode_steps"]
             counters["experts.rows"] = experts["rows"]
             counters["experts.touched"] = experts["touched"]
+        if sparse is not None:
+            for k, v in sparse.items():
+                counters[f"sparse.{k}"] = v
         record_counters("engine", counters)
         return {
             "queued": len(self._queue),
@@ -1340,6 +1360,7 @@ class ContinuousBatchingEngine(LLMEngine):
             "pages_total": sum(g["pages_total"] for g in groups),
             "page_groups": groups,
             "experts": experts,
+            "sparse": sparse,
             "prefix_pages": 0 if self._prefix is None else len(self._prefix),
             "prefix_hits": 0 if self._prefix is None else self._prefix.hits,
             "done": states[DONE],
@@ -1832,6 +1853,16 @@ class ContinuousBatchingEngine(LLMEngine):
                 oob = jnp.int32(g.n_pages * p)
                 ad_li = None if ad is None else \
                     self._ad_sel(AD, aid, li)
+                if a.latent is not None:
+                    # rows (and index keys) written, then the absorbed
+                    # form over the LIVE key blocks (inference/latent.py)
+                    attn, kp, vp = latent.prefill_layer(
+                        self, W, wset, h, k_pages_all[li],
+                        v_pages_all[li], tab, pos, t_end, li)
+                    k_pages_all = _pools_put(k_pages_all, li, kp, new_k)
+                    v_pages_all = _pools_put(v_pages_all, li, vp, new_v)
+                    h = self._layer_tail(W, wset, h, attn, li=li)
+                    continue
                 q, k, v = self._layer_qkv(W, wset, h, pos_ids, ad=ad_li,
                                           li=li)
                 slots = tab[pos // p] * p + pos % p
@@ -2498,7 +2529,7 @@ class ContinuousBatchingEngine(LLMEngine):
 
     def _cb_decode_math(self, W, tok, k_pages_all, v_pages_all, tables,
                         lens, active, w, ad=None, topk=None,
-                        expert_rows=None):
+                        expert_rows=None, sparse_counts=None):
         """One decode step at slot-bucket width w, fully traceable
         (shared by the per-step jit and the fused multi-step scan, so
         both paths run byte-identical math): one token for every slot,
@@ -2547,6 +2578,18 @@ class ContinuousBatchingEngine(LLMEngine):
             nkv = a.n_kv_heads // self.tp
             oob = jnp.int32(g.n_pages * p)
             ad_li = None if ad is None else self._ad_sel(AD, aid, li)
+            if a.latent is not None:
+                # one row a token, no value pool (inference/latent.py)
+                attn, kp, vp, counts = latent.decode_layer(
+                    self, W, wset, h, k_pages_all[li], v_pages_all[li],
+                    tab, lens, active, li)
+                if sparse_counts is not None:
+                    sparse_counts.append(counts)
+                k_pages_all = _pools_put(k_pages_all, li, kp, new_k)
+                v_pages_all = _pools_put(v_pages_all, li, vp, new_v)
+                h = self._layer_tail(W, wset, h, attn, li=li,
+                                     expert_rows=expert_rows)
+                continue
             q, k, v = self._layer_qkv(W, wset, h, pos_ids, ad=ad_li,
                                       li=li)
             slots = (tab[jnp.arange(w), lens // p] * p + lens % p)
@@ -2726,24 +2769,33 @@ class ContinuousBatchingEngine(LLMEngine):
         fold = mode == "sampled"
         sK = self.sample_k
 
-        if self.desc.has_experts:
-            # a description with routed experts: the same step, which
-            # also carries the routing counters through (donated, added
-            # to on the device, returned) — `route` None starts them
+        if self._counted:
+            # a description with routed experts or an indexer: the same
+            # step, which also carries the routing and selection
+            # counters through (donated, added to on the device,
+            # returned) — `route` None starts them
             def step(W, tok, k_pages_all, v_pages_all, tables, lens,
                      active, route=None):
-                rows = []
+                rows, sparse = [], []
                 out = self._cb_decode_math(
                     W, tok, k_pages_all, v_pages_all, tables, lens,
                     active, w, topk=sK if fold else None,
-                    expert_rows=rows)
-                rows = jnp.stack(rows)          # [expert layers, held]
+                    expert_rows=rows, sparse_counts=sparse)
                 if route is None:
                     route = self._route_zeros()
-                route = {"rows": route["rows"] + rows,
-                         "touched": route["touched"] + jnp.sum(
-                             rows > 0, axis=1, dtype=jnp.int32),
-                         "steps": route["steps"] + 1}
+                route = dict(route)
+                if rows:
+                    rows = jnp.stack(rows)      # [expert layers, held]
+                    route.update(
+                        rows=route["rows"] + rows,
+                        touched=route["touched"] + jnp.sum(
+                            rows > 0, axis=1, dtype=jnp.int32),
+                        steps=route["steps"] + 1)
+                for name, add in zip(latent.SPARSE_COUNTS, zip(*sparse)):
+                    low = route[name][1] + sum(add)
+                    route[name] = jnp.stack(
+                        [route[name][0] + (low >> 24),
+                         low & ((1 << 24) - 1)]).astype(jnp.int32)
                 if fold:
                     return out + (route,)
                 logits, _tok, kps, vps = out
@@ -2790,9 +2842,16 @@ class ContinuousBatchingEngine(LLMEngine):
         shape = [(layer.ffn.held[1] - layer.ffn.held[0])
                  for layer in self.desc.layers
                  if layer.ffn.kind == "experts"]
-        return {"rows": jnp.zeros((len(shape), shape[0]), jnp.int32),
-                "touched": jnp.zeros((len(shape),), jnp.int32),
-                "steps": jnp.zeros((), jnp.int32)}
+        zeros = {}
+        if shape:
+            zeros.update(
+                rows=jnp.zeros((len(shape), shape[0]), jnp.int32),
+                touched=jnp.zeros((len(shape),), jnp.int32),
+                steps=jnp.zeros((), jnp.int32))
+        if self.desc.has_indexer:
+            zeros.update({k: jnp.zeros((2,), jnp.int32)
+                          for k in latent.SPARSE_COUNTS})
+        return zeros
 
     def _route_read(self):
         """Fold the device's routing counters into the host totals and
@@ -2860,8 +2919,8 @@ class ContinuousBatchingEngine(LLMEngine):
                     *args, jnp.asarray(self._tok_np[:w]), self.k_pages,
                     self.v_pages, jnp.asarray(self._tables_np[:w]),
                     jnp.asarray(self._lens_np[:w]), jnp.asarray(active),
-                    *([self._route_dev] if self.desc.has_experts else []))
-                if self.desc.has_experts:   # the counters ride along
+                    *([self._route_dev] if self._counted else []))
+                if self._counted:           # the counters ride along
                     *out, self._route_dev = out
             with _span("cb.decode.fetch"):
                 if fold:

@@ -31,6 +31,7 @@ from ..profiler import RecordEvent
 from ..tensor.tensor import Tensor
 from ..autograd import tape
 from ..models.llama import _rope_cache
+from ..ops import latent_attention as la
 from ..ops.moe import routed_experts
 from .description import UnsupportedByDescription, describe
 from ..ops.pallas.paged_attention import (expand_kv_heads,
@@ -226,12 +227,18 @@ class PageAllocator:
 # engine's weight_dtype (the router's product is float32; a sink is one
 # float per head)
 _QUANT_KEYS = ("wq", "wk", "wv", "wo", "wg", "wu", "wd")
-_F32_KEYS = ("router", "router_bias", "sink")
+_F32_KEYS = ("router", "router_bias", "sink", "ix_wq", "ix_wk", "ix_kn_w",
+             "ix_kn_b", "ix_ww")
 
 
 class PageGroup:
     """The layers that share one page-pool shape, one page table and one
-    freeing policy: equal (KV heads, key width, value width, window).
+    freeing policy: equal (KV heads, key width, value width, window), or
+    for LATENT layers equal (row width, index key width, window): such a
+    group keeps ONE row a token for all heads ([c_kv ; k_r], padded to
+    the 128 lanes: a gather of 576-wide rows ran 3.3 times slower on the
+    v5e than of 640-wide ones, PERF.md PR 30), no value pool, and beside
+    it the indexer's key where its layers have an indexer.
     A FULL group (window None) keeps every token of a sequence: its pages
     are priced and claimed at admission, as the engine always did. A
     WINDOW group claims a page when a position is first written and
@@ -242,7 +249,11 @@ class PageGroup:
     def __init__(self, index, key, layers, page_size, max_batch,
                  pages_per_seq, chunk, plain=True):
         self.index = index
-        self.n_kv_heads, self.qk_dim, self.v_dim, self.window = key
+        self.n_kv_heads, self.qk_dim, self.v_dim, self.window = key[:4]
+        self.latent = len(key) > 4
+        self.row_width = self.qk_dim if self.latent else 0
+        self.index_width = self.v_dim if self.latent else 0
+        self.row_pad = -(-self.row_width // 128) * 128
         self.layers = tuple(layers)
         self.page_size = page_size
         self.col0 = index * pages_per_seq   # its columns of the table
@@ -252,7 +263,8 @@ class PageGroup:
         # and its pool result another and copies the whole pool between
         # them every step (PERF.md, PR 26). A plain description keeps
         # the shape every mode reads.
-        self.k_flat = not plain and self.qk_dim % 128 != 0
+        self.k_flat = not plain and not self.latent \
+            and self.qk_dim % 128 != 0
         if self.window is None:
             self.n_pages = max_batch * pages_per_seq
         else:
@@ -274,6 +286,12 @@ class PageGroup:
         return self.n_pages - self.allocator.available
 
     def pool_shapes(self):
+        """(keys, values) of one layer; a latent group's are (rows, index
+        keys), the second empty where its layers have no indexer."""
+        if self.latent:
+            return ((self.n_pages, self.page_size, self.row_pad),
+                    (self.n_pages, self.page_size, self.index_width)
+                    if self.index_width else (0,))
         h = self.n_kv_heads
         k = ((self.n_pages, self.page_size, h * self.qk_dim) if self.k_flat
              else (self.n_pages, self.page_size, h, self.qk_dim))
@@ -438,6 +456,11 @@ class LLMEngine:
                 raise UnsupportedByDescription(
                     "quant='int8' has no grouped int8 product for routed "
                     "experts yet; serve this description unquantized")
+            if quant is not None and any(layer.attn.latent is not None
+                                         for layer in desc.layers):
+                raise UnsupportedByDescription(
+                    "quant='int8' does not cover a latent layer's "
+                    "projections; serve this description unquantized")
         # tensor parallelism: tp > 1 runs every compiled dispatch under
         # shard_map on a 1-D "mp" mesh — heads + KV pools sharded over
         # heads, matmuls column/row-parallel (inference/tp.py). The
@@ -775,6 +798,9 @@ class LLMEngine:
                 interpret=self.interpret)
             if expert_rows is not None:
                 expert_rows.append(rows)
+            if ffn.shared_width:    # the shared expert, on every token
+                y = y + la.swiglu(x.reshape(b * t, -1), wset["ws_g"],
+                                  wset["ws_u"], wset["ws_d"])
             return h + y.reshape(b, t, -1)
         if self.f32_stream:
             x = x.astype(self.kv_dtype)

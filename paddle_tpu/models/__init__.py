@@ -5,3 +5,4 @@ from .gpt import GPTConfig, GPTModel, GPTForCausalLM, gpt_pipeline_layers
 from .bert import (BertConfig, BertModel, BertForMaskedLM,
                    BertForSequenceClassification)
 from .mimo_v2 import MiMoV2Config, MiMoV2ForCausalLM
+from .dots3_note import Dots3NoteConfig, Dots3NoteForCausalLM

@@ -202,7 +202,8 @@ class TestWindowedPercentiles:
 # -- dashboard) --------------------------------------------------------------
 ENGINE_HEALTH_KEYS = frozenset({
     "queued", "running", "slots_total", "queue_limit", "pages_free",
-    "pages_total", "page_groups", "experts", "prefix_pages", "prefix_hits",
+    "pages_total", "page_groups", "experts", "sparse", "prefix_pages",
+    "prefix_hits",
     "done", "failed",
     "cancelled", "steps", "prefill_steps", "decode_steps", "admissions",
     "failures", "deadline_expiries", "cow_copies", "decode_block",
@@ -575,3 +576,25 @@ class TestProfilerAndProbe:
             lines = [json.loads(ln) for ln in f]
         assert any(e["ev"] == "submit" for e in lines)
         assert any(e["ev"] == "retire" for e in lines)
+
+
+# -- the latent sparse path's names (PR 30): scopes, counters, health keys --
+LATENT_SPARSE_NAMES = (
+    "sparse_index_scores", "sparse_select", "sparse_attend",
+    "window_latent_attend", "sparse.keys_visible", "sparse.keys_attended",
+    "sparse.index_keys_scored", "sparse.decode_queries", "row_width",
+    "index_width")
+
+
+@pytest.mark.parametrize("name", LATENT_SPARSE_NAMES)
+def test_latent_sparse_names_are_in_the_docs_and_in_the_program(name):
+    """Readers under perf/ find device time by these scope names and
+    counters by these keys: a rename must fail here, with the docs."""
+    import os
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    docs = open(os.path.join(root, "docs", "observability.md")).read()
+    assert name in docs, f"{name} missing from docs/observability.md"
+    code = "".join(open(os.path.join(root, "paddle_tpu", "inference",
+                                     f)).read()
+                   for f in ("latent.py", "scheduler.py"))
+    assert name.split(".")[-1] in code

@@ -200,8 +200,19 @@ def _make_probe_engine(base, candidates):
     fills as {name: {uid: (position, margin / |top logit|)}}."""
     import numpy as np
 
+    from paddle_tpu.inference.sampling import (SamplingParams,
+                                               TokenMaskAutomaton)
+
     class ProbeEngine(base):
         first_split = {name: {} for name in candidates}
+
+        def add_request(self, ids, max_new_tokens=32, **kw):
+            # a greedy step program leaves its logits on the device; a
+            # neutral processor chain keeps this engine on the arm that
+            # materializes them, token for token the same
+            kw.setdefault("sampling", SamplingParams(
+                grammar=TokenMaskAutomaton.trivial(self.cfg.vocab_size)))
+            return super().add_request(ids, max_new_tokens, **kw)
 
         def _select_tokens(self, rows, positions, mode, logits=None, **kw):
             toks = super()._select_tokens(rows, positions, mode,

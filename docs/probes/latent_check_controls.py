@@ -64,7 +64,14 @@ def engine_logits(eng, cfg, seed):
         return select(rows, positions, mode, logits=logits, **kw)
 
     eng._select_tokens = spy
-    uids = [eng.add_request(p, max_new_tokens=int(spec["new_tokens"]))
+    # (a greedy step program leaves its logits on the device: a neutral
+    # processor chain serves these on the arm that materializes them)
+    from paddle_tpu.inference.sampling import (SamplingParams,
+                                               TokenMaskAutomaton)
+    anything = SamplingParams(
+        grammar=TokenMaskAutomaton.trivial(cfg["vocab_size"]))
+    uids = [eng.add_request(p, max_new_tokens=int(spec["new_tokens"]),
+                            sampling=anything)
             for p in prompts]
     eng.drain()
     eng._select_tokens = select

@@ -410,6 +410,12 @@ class PrefixCache:
             self.on_evict(key)
 
 
+@jax.jit
+def _tok_merge(toks, vals, mask):
+    """The device token vector with the host's `vals` where `mask`."""
+    return jnp.where(mask, vals, toks)
+
+
 class _FusedBlock:
     """One in-flight fused dispatch (decode_block > 1): which requests
     rode it, plus the device futures the host has not yet fetched. The
@@ -444,6 +450,32 @@ class _FusedBlock:
         self.aid = None             # device [w] adapter pool-slot ids
         #                             (None = adapter-free block: the
         #                             plain compiled program ran)
+
+
+class _Dispatched:
+    """One step program of the decode_block == 1 path that has been
+    dispatched and whose tokens the host has not fetched and booked yet
+    (`_resolve`). A row is (request, the slot it held at dispatch): by
+    the time the program is resolved a request may have left its slot."""
+
+    __slots__ = ("decode", "rows", "uids", "toks", "mode", "w", "logits",
+                 "top", "positions", "last")
+
+    def __init__(self, decode, rows, toks=None, mode="greedy", w=1,
+                 logits=None, top=None, positions=None, last=False):
+        self.decode = decode        # a decode step, else a prefill chunk
+        self.rows = rows            # [(Request, slot)]
+        self.uids = frozenset(r.uid for r, _ in rows)
+        self.toks = toks            # the engine's device token vector as
+        #                             this program left it (a greedy
+        #                             program); else the host selects,
+        #                             under `mode` at width `w`, from:
+        self.mode, self.w = mode, w
+        self.logits = logits        # device [w, V] ("proc", adapters)
+        self.top = top              # device (topv, topi) ("sampled")
+        self.positions = positions  # the new tokens' PRNG counters
+        self.last = last            # a prompt's last chunk: it holds
+        #                             the request's first token
 
 
 class ContinuousBatchingEngine(LLMEngine):
@@ -723,8 +755,19 @@ class ContinuousBatchingEngine(LLMEngine):
         self._program_built = False     # a step program was built and
         #                                 has not been called yet
         self._pf_dummies = {}
-        self._pending = None            # in-flight fused block (its
-        #                                 readback not yet processed)
+        self._pending = None            # the ONE dispatched program whose
+        #                                 tokens are not booked yet: a
+        #                                 _FusedBlock (decode_block > 1)
+        #                                 or a _Dispatched (the per-step
+        #                                 path); _sync_pending resolves it
+        # the selected token of every slot, ON THE DEVICE: each greedy
+        # step program and each prompt's last chunk writes the rows it
+        # ran, the next decode step reads its input tokens from it. The
+        # host writes into it only where the host is the source of a
+        # slot's pending token (`_tok_on_dev` False: an imported or
+        # restored request, a token the host selected)
+        self._tok_dev = jnp.zeros((max_batch,), jnp.int32)
+        self._tok_on_dev = np.zeros(max_batch, bool)
         self._copy_fn = None
 
         # observability (tests assert on these)
@@ -740,6 +783,13 @@ class ContinuousBatchingEngine(LLMEngine):
         self.fused_blocks = 0
         self.chained_blocks = 0         # blocks dispatched BEFORE the
         #                                 previous block's readback
+        # the per-step path's run-ahead (docs/serving.md "Dispatch
+        # ahead"): programs dispatched while another was unresolved,
+        # programs resolved BEFORE the next dispatch by reason, rows
+        # that ran one step past an EOS and were discarded
+        self.ahead_dispatched = 0
+        self.ahead_resolved_first = collections.Counter()
+        self.ahead_overrun_rows = 0
         self.preemptions = 0            # decode-slot preemptions (work
         #                                 re-queued, not failed)
         self.handoffs_out = 0           # KV-page exports committed away
@@ -1107,6 +1157,7 @@ class ContinuousBatchingEngine(LLMEngine):
         r = self._requests.get(uid)
         if r is None:
             raise UnknownRequestError(f"unknown request uid {uid}")
+        self._sync_pending()    # it may just have finished on the device
         if r.state in (DONE, FAILED, CANCELLED, MIGRATED):
             return False
         if r.state == QUEUED:
@@ -1138,9 +1189,22 @@ class ContinuousBatchingEngine(LLMEngine):
         do.
 
         decode_block == 1 (default): shed expired deadlines, admit what
-        fits, then run ONE compiled program — a prefill chunk or a
+        fits, then dispatch ONE compiled program — a prefill chunk or a
         decode step (alternating when both have work, so long prompts
-        don't stall live decodes).
+        don't stall live decodes) — and THEN fetch and book (`_resolve`)
+        the program the previous step() dispatched: the device runs
+        this step's program while the host reads the last one's tokens,
+        admits, prepares and dispatches again. Tokens of a program
+        become visible at the next step(), at drain() or at any call
+        that resolves (`_sync_pending`); step() returns True while a
+        program is unresolved. The program to dispatch is chosen from
+        what the host knows without the tokens in flight (`_runnable`).
+        When its participants need the host's knowledge of those tokens
+        (`_resolve_first`: processors, sampling, a stop sequence, an
+        adapter, a deadline, an armed fault point, a KV tier) the step
+        resolves first and dispatches after, and resolves its own
+        program before it returns: the order this path always had, bit
+        for bit. docs/serving.md "Dispatch ahead".
 
         decode_block == K > 1: one BLOCK — a single compiled dispatch
         covering a ragged prefill phase (every prefilling slot advances
@@ -1163,22 +1227,34 @@ class ContinuousBatchingEngine(LLMEngine):
         boundary all live there."""
         if self.decode_block > 1 or self._spec:
             return self._fused_step()
-        with _span("cb.admit"):
-            self._expire_deadlines()
-            self._restore_sweep()
-            self._idle_demote_sweep()
-            self._admit()
-        prefills = [r for r in self._slots if r and r.state == PREFILL]
-        decodes = [r for r in self._slots if r and r.state == DECODE]
-        if not prefills and not decodes:
-            return self._idle_or_raise()
+        while True:
+            with _span("cb.admit"):
+                self._expire_deadlines()
+                self._restore_sweep()
+                self._idle_demote_sweep()
+                self._admit()
+            prefills, decodes = self._runnable()
+            if not prefills and not decodes:
+                if self._pending is None:
+                    return self._idle_or_raise()
+                self._sync_pending()    # nothing to put behind it
+                return True
+            prefill = bool(prefills) and (
+                not decodes or not self._prefer_decode)
+            why = self._resolve_first([prefills[0]] if prefill else decodes)
+            # a program that waits for the host's knowledge of the tokens
+            # in flight: book them, then choose again (a row may just
+            # have ended, a seat may just have come free)
+            if why is None or not self._resolved_for(why):
+                break
         self.steps += 1
         try:
-            if prefills and (not decodes or not self._prefer_decode):
+            cur = None
+            if prefill:
                 r = prefills[0]
                 try:
                     fault_point("cb.prefill", detail=f"uid={r.uid}")
-                    self._prefill_step(r)
+                    cur = self._prefill_step(r)
                 except InjectedFault as e:
                     self._fail_request(r, "prefill", e)
                 self.prefill_steps += 1
@@ -1198,14 +1274,70 @@ class ContinuousBatchingEngine(LLMEngine):
                     except InjectedFault as e:
                         self._fail_request(r, "decode", e)
                 if live:
-                    self._decode_step(live)
+                    cur = self._decode_step(live)
                 self.decode_steps += 1
                 self._prefer_decode = False
             self._count_pages()
+            # the device has `cur` queued behind the program it runs:
+            # NOW fetch and book that one
+            prev, self._pending = self._pending, cur
+            if prev is not None:
+                self.ahead_dispatched += cur is not None
+                self._resolve(prev)
+            if why is not None:
+                self._resolved_for(why)
         except Exception:
             self._abort_in_flight()
             raise
         return True
+
+    def _runnable(self):
+        """(prefills, decodes) in slot order, as the NEXT program may
+        take them: the host's state as of the last resolve plus what it
+        knows of the program in flight without its tokens. A prompt
+        whose last chunk is dispatched decodes next, whatever its first
+        token is; a row whose token in flight is its budget's last is
+        left out."""
+        ahead = self._pending.uids if self._pending is not None else ()
+        prefills, decodes = [], []
+        for r in self._slots:
+            if r is None:
+                continue
+            if r.state == PREFILL and r.filled < r.t0:
+                prefills.append(r)
+            elif r.state in (PREFILL, DECODE) and \
+                    len(r.out) + (r.uid in ahead) < r.max_new_tokens:
+                decodes.append(r)
+        return prefills, decodes
+
+    def _resolve_first(self, rows):
+        """Why the program over `rows` may not be in flight while the
+        next one is chosen and dispatched (its tokens, or when they are
+        booked, decide something on the host), or None: then the engine
+        dispatches the next program before it fetches this one's
+        tokens. Read off the participants, never set."""
+        if _faults_armed():
+            return "faults"     # fault points fire at host sync points,
+            #                     once per request per resolved step
+        if self._tier is not None:
+            return "tier"       # demotion victims and the idle clocks
+            #                     are read off resolved state
+        for r in rows:
+            sp = r.sampling
+            if sp.needs_processors:
+                return "proc"   # penalties and grammar state advance on
+                #                 the host, token by token
+            if sp.do_sample:
+                return "sampled"    # the host draws from the folded
+                #                     candidates (the named next step)
+            if sp.stop:
+                return "stop"   # a stop sequence retires on the host
+            if r.adapter is not None:
+                return "adapter"    # the adapter programs return logits
+            if r.deadline is not None or r.ttl_steps is not None:
+                return "deadline"   # expiry is promised between two
+                #                     resolved steps
+        return None
 
     def drain(self):
         """Run until every queued/in-flight request retires. Returns
@@ -1233,6 +1365,8 @@ class ContinuousBatchingEngine(LLMEngine):
         r = self._requests.get(uid)
         if r is None:
             raise UnknownRequestError(f"unknown request uid {uid}")
+        if r.state in (PREFILL, DECODE):
+            self._sync_pending()    # its last token may be in flight
         if r.state == CANCELLED:
             raise RequestCancelledError(r.error)
         if r.state == FAILED:
@@ -1335,7 +1469,13 @@ class ContinuousBatchingEngine(LLMEngine):
         # one timestamped sample of the always-on counters, beside
         # profiler.span_totals() (docs/observability.md): a reader that
         # knows two moments differences the samples nearest them
-        counters = {"steps": self.steps}
+        counters = {"steps": self.steps,
+                    "ahead.dispatched": self.ahead_dispatched,
+                    "ahead.overrun_rows": self.ahead_overrun_rows,
+                    "ahead.resolved_first": sum(
+                        self.ahead_resolved_first.values())}
+        for why, n in self.ahead_resolved_first.items():
+            counters[f"ahead.resolved_first.{why}"] = n
         for i, g in enumerate(groups):
             counters[f"group{i}.used_page_steps"] = g["used_page_steps"]
             counters[f"group{i}.pages_total"] = g["pages_total"]
@@ -1376,6 +1516,14 @@ class ContinuousBatchingEngine(LLMEngine):
             "decode_block": self.decode_block,
             "fused_blocks": self.fused_blocks,
             "chained_blocks": self.chained_blocks,
+            # the per-step path's run-ahead (docs/serving.md "Dispatch
+            # ahead"): programs dispatched while another was unresolved,
+            # programs resolved before the next dispatch by reason, rows
+            # that ran one step past an EOS and were discarded. As
+            # everything here: the state as of the last resolve
+            "ahead": {"dispatched": self.ahead_dispatched,
+                      "resolved_first": dict(self.ahead_resolved_first),
+                      "overrun_rows": self.ahead_overrun_rows},
             # active decode-kernel mode: "off" = per-op XLA chain,
             # "layer"/"multi" = the Pallas decode megakernel;
             # whole_step = the "multi" head fold (final norm + lm_head
@@ -1470,6 +1618,7 @@ class ContinuousBatchingEngine(LLMEngine):
         call (once per generate(), not per step) and restored after —
         unless a mid-flight failure already rebuilt the pools (the CB
         _reset_kv restacks them itself)."""
+        self._sync_pending()
         if self.megakernel != "multi":
             return super().generate(*args, **kw)
         L = self.cfg.num_hidden_layers
@@ -1637,6 +1786,9 @@ class ContinuousBatchingEngine(LLMEngine):
             if slot is None:
                 victim = self._preemption_victim(r)
                 if victim is not None:
+                    if self._resolved_for("preempt"):
+                        continue       # the victim is chosen, and its
+                        #                tokens folded, from resolved state
                     self._preempt(victim)
                     continue           # re-evaluate with the freed slot
                 if self._demote_for(r):
@@ -1670,6 +1822,8 @@ class ContinuousBatchingEngine(LLMEngine):
                 # pages — one victim per attempt, then re-evaluate
                 victim = self._preemption_victim(r)
                 if victim is not None:
+                    if self._resolved_for("preempt"):
+                        continue
                     self._preempt(victim)
                     continue
                 if self._demote_for(r):
@@ -1714,6 +1868,7 @@ class ContinuousBatchingEngine(LLMEngine):
             r.state = PREFILL
             r.seated_step = self.steps
             self._slots[slot] = r
+            self._tok_on_dev[slot] = False
             self._tables_np[slot] = 0
             self._tables_np[slot, :len(pages)] = pages
             for gi, held in more.items():
@@ -1825,7 +1980,12 @@ class ContinuousBatchingEngine(LLMEngine):
         sequence's pages, then attend over the sequence's whole gathered
         context (shared prefix pages included) with causal masking.
         Static shape: [1, chunk]; t_start/t_end ride as traced scalars
-        so every chunk of every prompt reuses ONE compiled program.
+        so every chunk of every prompt reuses ONE compiled program. It
+        returns the chunk's last logits row AND the engine's token
+        vector `toks` with the greedy token of that row written at
+        `slot` when the chunk is the prompt's last (`_tp_greedy_token`:
+        bitwise the host's argmax of the row), so a greedy prompt's
+        first token never leaves the device before it is fed.
         with_adapters=True builds the ADAPTER-AWARE variant (aid [1] —
         the request's pool slot; an adapter request's prompt KV must
         carry the delta too, or its cache would diverge from a
@@ -1835,7 +1995,7 @@ class ContinuousBatchingEngine(LLMEngine):
         layer_group = self.desc.layer_group
 
         def prefill(W, ids, k_pages_all, v_pages_all, table, t_start,
-                    t_end, AD=None, aid=None):
+                    t_end, toks=None, slot=0, AD=None, aid=None):
             ad = None if AD is None else (AD, aid)
             h = jnp.take(W["emb"], ids, axis=0).astype(
                 jnp.float32 if self.f32_stream else self.kv_dtype)
@@ -1928,16 +2088,25 @@ class ContinuousBatchingEngine(LLMEngine):
             h = _rms(h, W["norm"], W["eps"])
             last = jnp.clip(t_end - 1 - t_start, 0, chunk - 1)
             h_last = jax.lax.dynamic_index_in_dim(h, last, axis=1)
-            logits = self._lm_head(W, h_last)
-            return (logits[:, 0], _pools_result(k_pages_all, new_k),
+            loc = (_mm_f32 if self.f32_stream else _mm)(
+                h_last, W["head"], self.interpret)[:, 0]
+            if toks is None:        # a caller that only compiles it
+                toks = jnp.zeros((self.max_batch,), jnp.int32)
+            first = self._tp_greedy_token(loc)[0].astype(toks.dtype)
+            toks = jnp.where(t_start + chunk >= t_end,
+                             toks.at[slot].set(first), toks)
+            return (self._gather_logits(loc), toks,
+                    _pools_result(k_pages_all, new_k),
                     _pools_result(v_pages_all, new_v))
 
         W, R, POOL = self._tp_specs()
         if with_adapters:
             def prefill_ad(W, AD, aid, ids, k_pages_all, v_pages_all,
                            table, t_start, t_end):
-                return prefill(W, ids, k_pages_all, v_pages_all, table,
-                               t_start, t_end, AD=AD, aid=aid)
+                logits, _toks, kps, vps = prefill(
+                    W, ids, k_pages_all, v_pages_all, table, t_start,
+                    t_end, AD=AD, aid=aid)
+                return logits, kps, vps
 
             ADsp = (self._apool.specs() if self._tpc is not None
                     else None)
@@ -1947,8 +2116,8 @@ class ContinuousBatchingEngine(LLMEngine):
                                 out_specs=(R, POOL, POOL),
                                 donate_argnums=(4, 5))
         return self._jit_tp(prefill,
-                            in_specs=(W, R, POOL, POOL, R, R, R),
-                            out_specs=(R, POOL, POOL),
+                            in_specs=(W, R, POOL, POOL, R, R, R, R, R),
+                            out_specs=(R, R, POOL, POOL),
                             donate_argnums=(2, 3))
 
     def _prefill_step(self, r):
@@ -1977,40 +2146,49 @@ class ContinuousBatchingEngine(LLMEngine):
                     self._program_built = True
                 fn = self._cb_prefill_fn
                 pre = (self.weights,)
+            mode = self._block_mode([r])
+            greedy = mode == "greedy" and r.adapter is None
+            slot = r.slot
         with self._first_call_span():
             t0 = time.perf_counter()
             with _span("cb.prefill_chunk"):
-                logits, self.k_pages, self.v_pages = fn(
-                    *pre, jnp.asarray(ids_chunk), self.k_pages,
-                    self.v_pages,
-                    # a COPY of the row: the transfer may read the host
-                    # buffer after this returns, and the row changes as
-                    # the next chunk's pages are claimed
-                    jnp.asarray(self._tables_np[r.slot:r.slot + 1].copy()),
+                # a COPY of the row: the transfer may read the host
+                # buffer after the call returns, and the row changes as
+                # the next chunk's pages are claimed
+                chunk_args = (
+                    jnp.asarray(ids_chunk), self.k_pages, self.v_pages,
+                    jnp.asarray(self._tables_np[slot:slot + 1].copy()),
                     jnp.int32(start), jnp.int32(r.t0))
+                if r.adapter is not None:
+                    logits, self.k_pages, self.v_pages = fn(
+                        *pre, *chunk_args)
+                    toks = None
+                else:
+                    logits, toks, self.k_pages, self.v_pages = fn(
+                        *pre, *chunk_args, self._tok_dev, jnp.int32(slot))
             if self._tel is not None:
                 self._tel.observe("prefill_chunk_ms",
                                   (time.perf_counter() - t0) * 1e3)
                 self._tel.req_event(self._tel_src, r.uid, "prefill_chunk",
                                     filled=end)
-            r.filled = end
-            self._group_release(r, end)
-            if end < r.t0:
-                return
+        # what the host knows without the chunk's result is booked HERE,
+        # at dispatch; the first token at _resolve
+        r.filled = end
+        self._group_release(r, end)
+        last = end >= r.t0
+        if last:
             # prompt complete: publish full prompt pages to the prefix
             # cache (before the first decode write, so concurrent
-            # requests share), then sample the first token from the
-            # final chunk's logits
-            with _span("cb.prefill.first_token"):
-                self._publish_prefix(r)
-                # the first generated token enters position t0 — its
-                # counter
-                tok = self._select_tokens([r], [r.t0],
-                                          self._block_mode([r]),
-                                          logits=logits)[0]
-                self._lens_np[r.slot] = r.t0
-                r.state = DECODE
-                self._push_token(r, tok)
+            # requests share); the first generated token enters
+            # position t0
+            self._publish_prefix(r)
+            self._lens_np[slot] = r.t0
+            if greedy:
+                self._tok_dev = toks
+                self._tok_on_dev[slot] = True
+        return _Dispatched(False, [(r, slot)], last=last, mode=mode,
+                           toks=toks if greedy else None,
+                           logits=None if greedy else logits)
 
     def _first_call_span(self):
         """`setup.first_call` around the dispatch (and fetch) of a
@@ -2766,8 +2944,17 @@ class ContinuousBatchingEngine(LLMEngine):
         # (and the adapter-carrying program) keeps the logits return;
         # the host runs the processor chain + select eagerly
         # (_select_tokens) — same math, same bits.
+        # "greedy" returns NO logits: the engine's token vector over all
+        # slots (`tok`, read at [:w]) comes back with the greedy token
+        # of every active row written, and the next step reads its
+        # inputs from it (the other modes take the host's [w] tokens).
         fold = mode == "sampled"
+        greedy = mode == "greedy"
         sK = self.sample_k
+
+        def put(tok, tok_g, active):
+            return tok.at[:w].set(
+                jnp.where(active, tok_g.astype(tok.dtype), tok[:w]))
 
         if self._counted:
             # a description with routed experts or an indexer: the same
@@ -2778,7 +2965,7 @@ class ContinuousBatchingEngine(LLMEngine):
                      active, route=None):
                 rows, sparse = [], []
                 out = self._cb_decode_math(
-                    W, tok, k_pages_all, v_pages_all, tables, lens,
+                    W, tok[:w], k_pages_all, v_pages_all, tables, lens,
                     active, w, topk=sK if fold else None,
                     expert_rows=rows, sparse_counts=sparse)
                 if route is None:
@@ -2798,19 +2985,23 @@ class ContinuousBatchingEngine(LLMEngine):
                          low & ((1 << 24) - 1)]).astype(jnp.int32)
                 if fold:
                     return out + (route,)
-                logits, _tok, kps, vps = out
+                logits, tok_g, kps, vps = out
+                if greedy:
+                    return put(tok, tok_g, active), kps, vps, route
                 return logits, kps, vps, route
 
             return jax.jit(step, donate_argnums=(2, 3, 7))
 
         def step(W, tok, k_pages_all, v_pages_all, tables, lens, active):
             out = self._cb_decode_math(
-                W, tok, k_pages_all, v_pages_all, tables, lens, active,
-                w, topk=sK if fold else None)
+                W, tok[:w], k_pages_all, v_pages_all, tables, lens,
+                active, w, topk=sK if fold else None)
             if fold:
                 topv, topi, kps, vps = out
                 return topv, topi, kps, vps
-            logits, _tok, kps, vps = out
+            logits, tok_g, kps, vps = out
+            if greedy:
+                return put(tok, tok_g, active), kps, vps
             return logits, kps, vps
 
         def step_ad(W, AD, aid, tok, k_pages_all, v_pages_all, tables,
@@ -2872,7 +3063,6 @@ class ContinuousBatchingEngine(LLMEngine):
                 pos = int(self._lens_np[r.slot])
                 self._make_writable(r, pos, pos + 1)
                 self._group_prepare(r, pos, pos + 1)
-                self._tok_np[r.slot] = r.tok
             w = next(b for b in self._slot_buckets
                      if b > max(r.slot for r in decodes))
             active = np.zeros(w, bool)
@@ -2882,6 +3072,7 @@ class ContinuousBatchingEngine(LLMEngine):
             mode = self._block_mode(decodes)
             aid = self._slot_aid(decodes, w)
             fold = mode == "sampled" and aid is None
+            greedy = mode == "greedy" and aid is None
             if aid is not None:
                 # adapter-carrying batch: the ADAPTER-AWARE program (the
                 # plain program stays untouched — and with megakernel=
@@ -2904,38 +3095,98 @@ class ContinuousBatchingEngine(LLMEngine):
                     self._cb_step_fns[(w, mode)] = fn
                     self._program_built = True
                 args = (self.weights,)
+            if greedy:
+                # the input tokens are on the device already, but for
+                # the rows whose pending token the host chose or carried
+                # in (imported, restored, selected on the host)
+                late = [r for r in decodes
+                        if not self._tok_on_dev[r.slot]]
+                if late:
+                    vals = np.zeros(self.max_batch, np.int32)
+                    mask = np.zeros(self.max_batch, bool)
+                    for r in late:
+                        vals[r.slot], mask[r.slot] = r.tok, True
+                    self._tok_dev = _tok_merge(
+                        self._tok_dev, jnp.asarray(vals), jnp.asarray(mask))
+                    self._tok_on_dev[mask] = True
+                tok = self._tok_dev
+            else:
+                for r in decodes:
+                    self._tok_np[r.slot] = r.tok
+                tok = jnp.asarray(self._tok_np[:w].copy())
             # the new token of the row fed at position lens occupies
             # position lens+1 — its PRNG counter (BEFORE the increment)
             positions = self._lens_np[:w] + 1
-            rows = [None] * w
-            for r in decodes:
-                rows[r.slot] = r
-        with self._first_call_span(), _span("cb.decode_step"):
-            # dispatch returns without waiting for the device; fetch is
-            # where the host blocks (an eager selection program, then
-            # the tokens' copy to the host)
-            with _span("cb.decode.dispatch"):
-                out = fn(
-                    *args, jnp.asarray(self._tok_np[:w]), self.k_pages,
-                    self.v_pages, jnp.asarray(self._tables_np[:w]),
-                    jnp.asarray(self._lens_np[:w]), jnp.asarray(active),
-                    *([self._route_dev] if self._counted else []))
-                if self._counted:           # the counters ride along
-                    *out, self._route_dev = out
-            with _span("cb.decode.fetch"):
-                if fold:
-                    topv, topi, self.k_pages, self.v_pages = out
-                    toks = self._select_tokens(rows, positions, mode,
-                                               topv=topv, topi=topi)
+        with self._first_call_span(), _span("cb.decode_step"), \
+                _span("cb.decode.dispatch"):
+            # returns without waiting for the device. Every host array
+            # is handed over as a COPY: the transfer may read the buffer
+            # after the call returns, and nothing waits for the program
+            # before these rows change again
+            out = fn(
+                *args, tok, self.k_pages, self.v_pages,
+                jnp.asarray(self._tables_np[:w].copy()),
+                jnp.asarray(self._lens_np[:w].copy()), jnp.asarray(active),
+                *([self._route_dev] if self._counted else []))
+            if self._counted:           # the counters ride along
+                *out, self._route_dev = out
+            *head, self.k_pages, self.v_pages = out
+        # what the host knows without the tokens is booked HERE, at
+        # dispatch: every active row advanced by one position
+        for r in decodes:
+            self._lens_np[r.slot] += 1
+            self._group_release(r, int(self._lens_np[r.slot]))
+        if greedy:
+            self._tok_dev = head[0]
+        return _Dispatched(True, [(r, r.slot) for r in decodes], mode=mode,
+                           w=w, positions=positions,
+                           toks=head[0] if greedy else None,
+                           top=tuple(head) if fold else None,
+                           logits=None if greedy or fold else head[0])
+
+    def _resolve(self, rec):
+        """Fetch a dispatched program's tokens (the host blocks here
+        until THAT program is done; the device meanwhile runs whatever
+        was dispatched behind it) and book them through the same
+        functions as ever: `_push_token`, retirement. A row whose
+        request left while the program was in flight is skipped: it was
+        cancelled, or it met its EOS in the program before this one and
+        ran one step too many, whose token is discarded and whose KV
+        write landed in its own page inside its budget (programs run in
+        dispatch order, so a page freed here is not written by an older
+        program after a newer owner's)."""
+        if not rec.decode:
+            (r, slot), = rec.rows
+            if not rec.last or r.state != PREFILL or r.slot is None:
+                return          # no token yet / cancelled in flight
+            with _span("cb.prefill.first_token"):
+                if rec.toks is not None:
+                    tok = np.asarray(rec.toks)[slot]
                 else:
-                    logits, self.k_pages, self.v_pages = out
-                    toks = self._select_tokens(rows, positions, mode,
-                                               logits=logits)
+                    tok = self._select_tokens([r], [r.t0], rec.mode,
+                                              logits=rec.logits)[0]
+                r.state = DECODE
+                self._push_token(r, tok)
+            return
+        with _span("cb.decode.fetch"):
+            if rec.toks is not None:
+                toks = np.asarray(rec.toks)
+            else:
+                rows = [None] * rec.w
+                for r, slot in rec.rows:
+                    rows[slot] = r
+                topv, topi = rec.top or (None, None)
+                toks = self._select_tokens(
+                    rows, rec.positions, rec.mode, logits=rec.logits,
+                    topv=topv, topi=topi)
         with _span("cb.decode.push"):
-            for r in decodes:
-                self._lens_np[r.slot] += 1
-                self._group_release(r, int(self._lens_np[r.slot]))
-                self._push_token(r, toks[r.slot])
+            for r, slot in rec.rows:
+                if r.state != DECODE or r.slot is None:
+                    self.ahead_overrun_rows += r.state == DONE
+                    continue
+                if rec.toks is None:        # the host is its source now
+                    self._tok_on_dev[slot] = False
+                self._push_token(r, toks[slot])
 
     # -- fused multi-step decode (device-resident blocks) ------------------
     def _idle_or_raise(self):
@@ -3778,6 +4029,7 @@ class ContinuousBatchingEngine(LLMEngine):
         r = self._requests.get(uid)
         if r is None:
             raise UnknownRequestError(f"unknown request uid {uid}")
+        self._sync_pending()    # the fold must hold every token emitted
         prompt = (np.concatenate([r.ids, np.asarray(r.out, np.int64)])
                   if r.out else r.ids.copy())
         ttl = r.ttl_steps
@@ -3816,6 +4068,7 @@ class ContinuousBatchingEngine(LLMEngine):
         recomputes them elsewhere, their tier entry dies with the
         replica) — the payload a router salvages when this replica is
         declared dead."""
+        self._sync_pending()
         return [self.export_request(u)
                 for u, r in self._requests.items()
                 if r.state in (QUEUED, PREFILL, DECODE, DEMOTED)]
@@ -3899,12 +4152,32 @@ class ContinuousBatchingEngine(LLMEngine):
         return checksum_payload(payload)
 
     def _sync_pending(self):
-        """Apply a chained block still in flight so host state (lens,
-        generated tokens) is current before a handoff reads it."""
+        """The ONE resolve point: fetch and book the program still in
+        flight (a chained fused block, or the per-step path's program
+        dispatched ahead), so host state (lens, generated tokens,
+        retirements) is current before anything reads or hands it
+        over."""
         while self._pending is not None:
             blk = self._pending
             self._pending = None
-            self._process_block(blk)
+            if isinstance(blk, _Dispatched):
+                try:
+                    self._resolve(blk)
+                except Exception:
+                    self._abort_in_flight()
+                    raise
+            else:
+                self._process_block(blk)
+
+    def _resolved_for(self, why):
+        """Resolve the program in flight, if there is one, because
+        `why` reads the host's state; True if there was one (counted in
+        `ahead.resolved_first`)."""
+        if self._pending is None:
+            return False
+        self.ahead_resolved_first[why] += 1
+        self._sync_pending()
+        return True
 
     def export_kv_pages(self, uid, device=False, transport=None):
         """Package a post-prefill request for migration to ANOTHER
@@ -3997,6 +4270,7 @@ class ContinuousBatchingEngine(LLMEngine):
         r = self._requests.get(uid)
         if r is None:
             raise UnknownRequestError(f"unknown request uid {uid}")
+        self._sync_pending()    # it may have retired since the export
         token = self._handoffs_out.pop(uid, None)
         if token is None:
             raise ValueError(
@@ -4042,6 +4316,7 @@ class ContinuousBatchingEngine(LLMEngine):
         point."""
         self._require_plain("KV page import")
         from .handoff import KVHandoffError, verify_payload
+        self._sync_pending()    # seats are read off resolved state
         fault_point("kv.import", detail=f"token={payload.get('token')}")
         g = payload["geometry"]
         mine = self._kv_geometry()
@@ -4175,6 +4450,7 @@ class ContinuousBatchingEngine(LLMEngine):
             self._next_uid += 1
             self._requests[r.uid] = r
             self._slots[slot] = r
+            self._tok_on_dev[slot] = False      # r.tok rides the payload
             self._tables_np[slot] = 0
             self._tables_np[slot, :len(pages)] = pages
             self._lens_np[slot] = lens
@@ -4314,6 +4590,7 @@ class ContinuousBatchingEngine(LLMEngine):
             raise ValueError(
                 f"restore_request: request {uid} is {r.state!r}, not "
                 "demoted")
+        self._sync_pending()    # seats are read off resolved state
         d = r.demote
         slot = next((i for i, s in enumerate(self._slots) if s is None),
                     None)
@@ -4382,6 +4659,7 @@ class ContinuousBatchingEngine(LLMEngine):
             r.idle_steps = 0            # a fresh seat restarts the
             #                             demote-on-idle clock
             self._slots[slot] = r
+            self._tok_on_dev[slot] = False      # r.tok waited in the tier
             self._tables_np[slot] = 0
             self._tables_np[slot, :len(table)] = table
             self._lens_np[slot] = d["lens"]
@@ -4541,6 +4819,7 @@ class ContinuousBatchingEngine(LLMEngine):
         if self._prefix is None:
             raise ValueError("export_prefix_pages: prefix cache disabled")
         ids = np.asarray(ids, np.int64).ravel()
+        self._sync_pending()
         p = self.page_size
         key = ()
         pages = []
@@ -4603,6 +4882,7 @@ class ContinuousBatchingEngine(LLMEngine):
         from .handoff import KVHandoffError, verify_payload
         if self._prefix is None:
             raise ValueError("import_prefix_pages: prefix cache disabled")
+        self._sync_pending()
         fault_point("kv.import", detail="prefix")
         g = payload["geometry"]
         mine = self._kv_geometry()
@@ -4673,6 +4953,7 @@ class ContinuousBatchingEngine(LLMEngine):
         admitted) requests HOLD through the flip and run under the new
         weights. The prefix cache is dropped with the old weights (its
         pages are old-weight KV); the megakernel repack is rebuilt."""
+        self._sync_pending()    # a slot may just have emptied
         busy = [r.uid for r in self._slots if r is not None]
         busy += list(self._demoted)
         if busy:
@@ -4805,6 +5086,10 @@ class ContinuousBatchingEngine(LLMEngine):
                 r.slot = None
                 self._slots[i] = None
         self._pending = None
+        if getattr(self, "_tok_on_dev", None) is not None:
+            self._tok_on_dev[:] = False
+            # (it may be the result of the call that failed)
+            self._tok_dev = jnp.zeros((self.max_batch,), jnp.int32)
         prefix = getattr(self, "_prefix", None)
         if prefix is not None:
             prefix.clear()                   # allocator is reset below

@@ -17,6 +17,8 @@ import jax.numpy as jnp
 
 import paddle_tpu as paddle
 from paddle_tpu.inference import ContinuousBatchingEngine
+from paddle_tpu.inference.sampling import (SamplingParams,
+                                           TokenMaskAutomaton)
 from paddle_tpu.inference.description import (UnsupportedByDescription,
                                               describe)
 from paddle_tpu.models import Dots3NoteConfig, Dots3NoteForCausalLM
@@ -121,7 +123,19 @@ def served(model):
     prompts = [rng.integers(0, 96, n) for n in (7, 19, 42, 61)]
     uids = [eng.add_request(p, max_new_tokens=12) for p in prompts]
     eng.drain()
-    return eng, {u: eng.result(u) for u in uids}, seen
+    results = {u: eng.result(u) for u in uids}
+    # a greedy step program keeps its logits on the device (PR 31): the
+    # rows come from serving the prompts again under a neutral processor
+    # chain, the arm that materializes them, token for token the same
+    assert not seen
+    anything = SamplingParams(grammar=TokenMaskAutomaton.trivial(96))
+    again = [eng.add_request(p, max_new_tokens=12, sampling=anything)
+             for p in prompts]
+    eng.drain()
+    for u, v in zip(uids, again):
+        np.testing.assert_array_equal(results[u], eng.result(v))
+    seen[:] = [(uids[again.index(v)], pos, row) for v, pos, row in seen]
+    return eng, results, seen
 
 
 # float32 engine against float32 reference: the same products in another
@@ -164,6 +178,8 @@ def test_engine_counts_the_selection_and_leaks_no_page(served):
             visible += 2 * (t + 1)
             attended += 2 * min(t + 1, 12)
             queries += 2
+    # (the fixture serves every prompt twice)
+    visible, attended, queries = 2 * visible, 2 * attended, 2 * queries
     scored = h["sparse"].pop("index_keys_scored")
     assert h["sparse"] == {"keys_visible": visible,
                            "keys_attended": attended,

@@ -99,17 +99,25 @@ def traced(tiny, tmp_path_factory):
             "traced": np.asarray(eng.result(uid))}
 
 
-# the table of docs/observability.md, per kind of step
+# the table of docs/observability.md, per kind of step. Since PR 31 a
+# step dispatches its own program and THEN fetches and books the one the
+# step before it dispatched, so the fetch of a step's tokens sits in the
+# following `cb.step`
 PHASES = {
     "prefill_mid_prompt": (0, [
         "cb.admit", "cb.prefill.prepare", "cb.prefill_chunk"]),
     "prefill_last_chunk": (1, [
-        "cb.admit", "cb.prefill.prepare", "cb.prefill_chunk",
-        "cb.prefill.first_token"]),
-    "decode": (2, [
+        "cb.admit", "cb.prefill.prepare", "cb.prefill_chunk"]),
+    "decode_after_last_chunk": (2, [
         "cb.admit", "cb.decode.prepare",
-        ("cb.decode_step", ["cb.decode.dispatch", "cb.decode.fetch"]),
-        "cb.decode.push"]),
+        ("cb.decode_step", ["cb.decode.dispatch"]),
+        "cb.prefill.first_token"]),
+    "decode": (3, [
+        "cb.admit", "cb.decode.prepare",
+        ("cb.decode_step", ["cb.decode.dispatch"]),
+        "cb.decode.fetch", "cb.decode.push"]),
+    "nothing_to_dispatch": (-2, [
+        "cb.admit", "cb.decode.fetch", "cb.decode.push"]),
     "nothing_to_do": (-1, ["cb.admit"]),
 }
 
@@ -121,15 +129,18 @@ def test_plain_jax_profiler_session_sees_the_phases_nested_in_order(
     name, stats, kids = traced["steps"][index]
     assert name == "cb.step"
     assert _names(kids) == want
-    # the engine's step counter rides on cb.step as annotation metadata
+    # the engine's step counter (programs dispatched so far) rides on
+    # cb.step as annotation metadata
     n = len(traced["steps"])
-    assert stats["step"] == traced["first_step"] + (index % n)
+    programs = 2 + (NEW_TOKENS - 1)
+    assert stats["step"] == traced["first_step"] + min(index % n, programs)
 
 
 def test_a_session_sees_every_step_and_changes_no_token(traced):
     # 2 prefill chunks, NEW_TOKENS - 1 decode steps (the first token comes
-    # out of the last chunk), and the step that finds nothing to do
-    assert len(traced["steps"]) == 2 + (NEW_TOKENS - 1) + 1
+    # out of the last chunk), the step that has nothing to dispatch and
+    # books the last token, and the step that finds nothing to do
+    assert len(traced["steps"]) == 2 + (NEW_TOKENS - 1) + 1 + 1
     assert traced["plain"].size == PROMPT_LEN + NEW_TOKENS
     np.testing.assert_array_equal(traced["plain"], traced["traced"])
 

@@ -14,6 +14,8 @@ import jax.numpy as jnp
 
 import paddle_tpu as paddle
 from paddle_tpu.inference import ContinuousBatchingEngine
+from paddle_tpu.inference.sampling import (SamplingParams,
+                                           TokenMaskAutomaton)
 from paddle_tpu.inference.description import (UnsupportedByDescription,
                                               describe)
 from paddle_tpu.models import (LlamaConfig, LlamaForCausalLM, MiMoV2Config,
@@ -116,7 +118,19 @@ def served(model):
     prompts = [rng.integers(0, 96, n) for n in (7, 19, 42, 30)]
     uids = [eng.add_request(p, max_new_tokens=12) for p in prompts]
     eng.drain()
-    return eng, {u: eng.result(u) for u in uids}, seen
+    results = {u: eng.result(u) for u in uids}
+    # a greedy step program keeps its logits on the device (PR 31): the
+    # rows come from serving the prompts again under a neutral processor
+    # chain, the arm that materializes them, token for token the same
+    assert not seen
+    anything = SamplingParams(grammar=TokenMaskAutomaton.trivial(96))
+    again = [eng.add_request(p, max_new_tokens=12, sampling=anything)
+             for p in prompts]
+    eng.drain()
+    for u, v in zip(uids, again):
+        np.testing.assert_array_equal(results[u], eng.result(v))
+    seen[:] = [(uids[again.index(v)], pos, row) for v, pos, row in seen]
+    return eng, results, seen
 
 
 # float32 engine against float32 reference: the same products in another

@@ -207,7 +207,11 @@ ENGINE_HEALTH_KEYS = frozenset({
     "done", "failed",
     "cancelled", "steps", "prefill_steps", "decode_steps", "admissions",
     "failures", "deadline_expiries", "cow_copies", "decode_block",
-    "fused_blocks", "chained_blocks", "megakernel",
+    "fused_blocks", "chained_blocks",
+    # the per-step path's run-ahead (PR 31): {"dispatched",
+    # "resolved_first": {reason: n}, "overrun_rows"}
+    "ahead",
+    "megakernel",
     "megakernel_whole_step", "tp", "tp_mode", "tp_compress", "speculate",
     "drafter", "spec_passes", "spec_emitted", "spec_accept_rate",
     "spec_tokens_per_pass", "draft_errors",

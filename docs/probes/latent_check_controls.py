@@ -1,6 +1,7 @@
 #!/usr/bin/env python3
-"""Can `correct` fail in `serve-mla-sparse-longdoc`? The harness's own
-comparison (`perf/systems/serve_engine.check_against_reference`: the
+"""Can `correct` fail in `serve-mla-sparse-longdoc` (or, with `--workload
+serve-sparse-gqa-longctx`, in that cell: `CONTROLS` has each family's
+list)? The harness's own comparison (`perf/systems/serve_engine.check_against_reference`: the
 cell's engine, the check's prompts, the runner's `TIE_TOL`) against the
 reference as the benchmark builds it, against each of the reference's four
 deliberate faults (no selection, no gate, no rescale, a window one short)
@@ -15,7 +16,7 @@ and against the reference computed in bf16 and on float8 weights.
 A probe, run by hand: no benchmark cell runs it, no test imports it. A
 control that reads `ok: True` says the check cannot see that fault at these
 prompt lengths. One JSON line a control to stdout and to
-chiprun_out/latent_check_controls.json.
+chiprun_out/check_controls.<workload>.json.
 
 `--logits` compares LOGITS instead of tokens, which the harness cannot (it
 drives the engine through its public API): every logits row the engine
@@ -35,13 +36,22 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path[:0] = [ROOT, os.path.join(ROOT, "perf")]
 CELL = "serve-mla-sparse-longdoc"
-CONTROLS = [("reference", {}),
-            ("no_selection", {"variant": "no_selection"}),
-            ("no_gate", {"variant": "no_gate"}),
-            ("no_rescale", {"variant": "no_rescale"}),
-            ("window_off_by_one", {"variant": "window_off_by_one"}),
-            ("bfloat16", {"precision": "bfloat16"}),
-            ("float8", {"precision": "float8"})]
+# by the configuration's `reference`: what its Reference(cfg, **kw) takes
+CONTROLS = {
+    "dots3_note": [("reference", {}),
+                   ("no_selection", {"variant": "no_selection"}),
+                   ("no_gate", {"variant": "no_gate"}),
+                   ("no_rescale", {"variant": "no_rescale"}),
+                   ("window_off_by_one", {"variant": "window_off_by_one"}),
+                   ("bfloat16", {"precision": "bfloat16"}),
+                   ("float8", {"precision": "float8"})],
+    "keye_vl2": [("reference", {}),
+                 ("no_selection", {"variant": "no_selection"}),
+                 ("bf16_indexer", {"variant": "bf16_indexer"}),
+                 ("no_qk_norm", {"variant": "no_qk_norm"}),
+                 ("sigmoid_router", {"variant": "sigmoid_router"}),
+                 ("float8_kv", {"variant": "float8_kv"}),
+                 ("bfloat16", {"precision": "bfloat16"})]}
 
 
 def engine_logits(eng, cfg, seed):
@@ -126,12 +136,13 @@ def main(argv=None):
         perf_run.Tracer(False, os.devnull))
     runner = manifest.load_plugin("systems", "serve_engine")
     eng, family = runner.build(ctx)
-    out = os.path.join(ROOT, "chiprun_out", "latent_check_controls.json")
+    out = os.path.join(ROOT, "chiprun_out",
+                       f"check_controls.{args.workload}.json")
     os.makedirs(os.path.dirname(out), exist_ok=True)
     true_reference = family.Reference
     rows = engine_logits(eng, cell.config, args.seed) if args.logits else None
     with open(out, "a") as f:
-        for name, kw in CONTROLS:
+        for name, kw in CONTROLS[cell.config["reference"]]:
             if args.only and name not in args.only:
                 continue
             if rows is not None:

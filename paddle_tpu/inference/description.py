@@ -24,7 +24,13 @@ A model that wants to be served implements two methods and nothing else:
       where the layer gates its heads; and an indexer's `ix_wq` ([query
       rank, index heads x index width]), `ix_wk` ([hidden, index
       width]), `ix_kn_w ix_kn_b` (the index key's LayerNorm) and `ix_ww`
-      ([hidden, index heads]). Matrices are [in, out].
+      ([hidden, index heads]). A PER-HEAD layer may have an indexer too
+      (`wq wk wv` and the same `ix_*`, with `ix_wq` [hidden, index heads
+      x index width]: its index query reads the normed hidden state),
+      and `q_hn k_hn` ([head width] each) where `AttentionSpec.qk_norm`
+      norms every query and key head before the rotation. A router with
+      `FFNSpec.score` "softmax" has no `router_bias`. Matrices are
+      [in, out].
 
 The engine (serving.py, scheduler.py) reads the description and the
 canonical names and never asks what class the model is. What a
@@ -33,7 +39,7 @@ UnsupportedByDescription when the engine is BUILT — never a wrong answer
 later.
 """
 import dataclasses
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 
 class UnsupportedByDescription(ValueError):
@@ -60,12 +66,29 @@ class LatentSpec:
 class IndexerSpec:
     """Learned sparse attention: a query attends to the `top_k` visible
     positions with the largest index score (all of them while fewer are
-    visible). One index key a token is cached beside the layer's rows."""
+    visible). One index key a token is cached beside the layer's rows.
+    The index query is a product of the query latent in a latent layer
+    and of the normed hidden state in a per-head one; both rotate on the
+    layer's base."""
     n_heads: int
     dim: int
     rope_dim: int                   # leading dims of q^I and k^I that rotate
     top_k: int
     eps: float = 1e-6               # of the index key's LayerNorm
+
+
+class GroupKey(NamedTuple):
+    """What a page group's layers have in common. `kind` "heads": per-head
+    K and V, `n_kv_heads` of widths `qk_dim` / `v_dim` a token; "latent":
+    ONE row a token for all heads, `qk_dim` wide, no values. Either kind
+    keeps an `index_width`-wide index key a token beside them where its
+    layers have an indexer (0: none)."""
+    kind: str
+    n_kv_heads: int
+    qk_dim: int
+    v_dim: int
+    window: Optional[int]
+    index_width: int = 0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -82,18 +105,19 @@ class AttentionSpec:
     latent: Optional[LatentSpec] = None
     gate: bool = False              # head-wise sigmoid gate before wo
     indexer: Optional[IndexerSpec] = None
+    qk_norm: bool = False           # RMSNorm on every q and k head
 
     @property
     def group(self):
         """Layers with equal keys share a page pool shape, a page table
-        and a freeing policy. Per-head K and V: (KV heads, key width,
-        value width, window). Latent rows: (1, row width, index key
-        width or 0, window, "latent")."""
+        and a freeing policy."""
+        index_width = self.indexer.dim if self.indexer else 0
         if self.latent is not None:
-            return (1, self.latent.row_width(self.rope_dim),
-                    self.indexer.dim if self.indexer else 0, self.window,
-                    "latent")
-        return (self.n_kv_heads, self.qk_dim, self.v_dim, self.window)
+            return GroupKey("latent", 1,
+                            self.latent.row_width(self.rope_dim), 0,
+                            self.window, index_width)
+        return GroupKey("heads", self.n_kv_heads, self.qk_dim, self.v_dim,
+                        self.window, index_width)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -104,6 +128,8 @@ class FFNSpec:
     top_k: int = 0
     held: Tuple[int, int] = (0, 0)  # [lo, hi): the experts held HERE
     shared_width: int = 0           # one shared SwiGLU expert beside them
+    score: str = "sigmoid"          # the router (ops/moe.route): "sigmoid"
+    #                                 (+ a stored bias) | "softmax"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -150,7 +176,7 @@ class ModelDescription:
                 and a.window is None and not a.sink
                 and a.value_scale == 1.0
                 and a.latent is None and not a.gate
-                and a.indexer is None)
+                and a.indexer is None and not a.qk_norm)
 
     @property
     def has_experts(self):

@@ -21,18 +21,20 @@ scores the cached rows directly, W_uv and the gate come after the sum.
 The four device phases carry `jax.named_scope`s (the name stack reaches
 the profiler's event metadata; docs/observability.md): sparse_index_
 scores, sparse_select, sparse_attend, window_latent_attend.
+
+`decode_selection`, `prefill_selection` and `block_pages` (the index scan
+and the exact selection through a page table) know nothing latent: a
+per-head layer with an indexer (inference/sparse_heads.py) calls them too.
 """
 import jax
 import jax.numpy as jnp
 
 from ..ops import latent_attention as la
+from ..ops import sparse_attention as sa
+from ..ops.sparse_attention import SPARSE_COUNTS
 from .serving import _rms
 
 KEY_BLOCK_PAGES = 4     # pages a prefill key block gathers
-# what a full layer's decode step adds to the engine's device counters:
-# index keys visible to its queries, cache rows they attended to, index
-# keys the scan scored (dead pages included), and the queries themselves
-SPARSE_COUNTS = ("visible", "attended", "scored", "queries")
 
 
 def _write(pool, slots, values):
@@ -41,6 +43,63 @@ def _write(pool, slots, values):
     flat = pool.reshape(-1, pool.shape[-1])
     return flat.at[slots].set(values.astype(pool.dtype),
                               mode="drop").reshape(pool.shape)
+
+
+def block_pages(tab, j, p):
+    """(pool pages [nb], key positions [kb]) of key block j of a sequence
+    whose table is tab [pages_per_seq]; a page past the table reads its
+    last entry and is masked by position."""
+    mp, nb = tab.shape[0], KEY_BLOCK_PAGES
+    page_ix = j * nb + jnp.arange(nb)
+    kpos = (page_ix[:, None] * p + jnp.arange(p)[None, :]).reshape(nb * p)
+    return tab[jnp.minimum(page_ix, mp - 1)], \
+        jnp.where(jnp.repeat(page_ix < mp, p), kpos, mp * p)
+
+
+def decode_selection(ix_pool, tab, q_i, w_i, n_vis, active, ix, p):
+    """One decode query a slot (q_i [w, 1, Hi, di], w_i [w, 1, Hi]) over
+    its slot's index keys: (idx [w, top_k] the selected POSITIONS, valid
+    [w, top_k], `SPARSE_COUNTS` int32). n_vis [w]: positions visible (0
+    for an inactive slot)."""
+    w, mp = tab.shape
+    with jax.named_scope("sparse_index_scores"):
+        keys = ix_pool[tab].reshape(w, mp * p, ix.dim)
+        scores = sa.index_scores(q_i, keys, w_i)[:, 0]
+    with jax.named_scope("sparse_select"):
+        visible = jnp.arange(mp * p)[None, :] < n_vis[:, None]
+        idx, valid = sa.select_top(scores, visible, ix.top_k)
+    # the scan reads every table page of every slot of the bucket, live
+    # or not: what it scores is w x mp x p, not what is visible
+    counts = (jnp.sum(n_vis, dtype=jnp.int32),
+              jnp.sum(valid, dtype=jnp.int32),
+              jnp.int32(w * mp * p),
+              jnp.sum(active, dtype=jnp.int32))
+    return idx, valid, counts
+
+
+def prefill_selection(ix_pool, tab, q_i, w_i, qpos, hi_blk, ix, p):
+    """A chunk's queries (q_i [chunk, Hi, di], w_i [chunk, Hi], at qpos
+    [chunk, 1]) over the sequence's LIVE index-key blocks 0..hi_blk-1:
+    chosen [chunk, width] bool, each query's top-k among the positions
+    it sees. The [chunk, width] float32 score buffer is filled block by
+    block; a radix select marks the k-th value, no sort."""
+    chunk, kb = q_i.shape[0], KEY_BLOCK_PAGES * p
+    with jax.named_scope("sparse_index_scores"):
+        def score_block(j, buf):
+            pages, kpos = block_pages(tab, j, p)
+            keys = ix_pool[pages].reshape(kb, ix.dim)
+            sc = sa.index_scores(q_i, keys, w_i)
+            sc = jnp.where(kpos[None, :] <= qpos, sc, -jnp.inf)
+            return jax.lax.dynamic_update_slice(
+                buf, sc, (jnp.zeros((), j.dtype), j * kb))
+
+        # columns past the live blocks stay -inf: not visible
+        width = -(-tab.shape[0] // KEY_BLOCK_PAGES) * kb
+        buf = jax.lax.fori_loop(
+            0, hi_blk, score_block,
+            jnp.full((chunk, width), -jnp.inf, jnp.float32))
+    with jax.named_scope("sparse_select"):
+        return sa.top_mask(buf, ix.top_k)
 
 
 def _front(eng, W, wset, h, pos_ids, li):
@@ -58,7 +117,7 @@ def _front(eng, W, wset, h, pos_ids, li):
     q_n, q_r, row, c_q = la.latent_qkv(x, wset, a, W["eps"], cos, sin)
     row = jnp.pad(row, [(0, 0)] * 2 + [(0, g.row_pad - g.row_width)])
     gate = la.head_gate(x, wset["w_gate"]) if a.gate else None
-    ix = la.index_qkw(x, c_q, wset, a.indexer, cos, sin) \
+    ix = sa.index_qkw(x, c_q, wset, a.indexer, cos, sin) \
         if a.indexer is not None else None
     return q_n, q_r, row.astype(eng.kv_dtype), gate, ix
 
@@ -96,21 +155,11 @@ def decode_layer(eng, W, wset, h, rows_pool, ix_pool, tab, lens, active, li):
     if a.indexer is not None:
         q_i, k_i, w_i = ix
         ix_pool = _write(ix_pool, slots, k_i[:, 0])
-        with jax.named_scope("sparse_index_scores"):
-            keys = ix_pool[tab].reshape(w, mp * p, a.indexer.dim)
-            scores = la.index_scores(q_i, keys, w_i)[:, 0]
-        with jax.named_scope("sparse_select"):
-            visible = jnp.arange(mp * p)[None, :] < n_vis[:, None]
-            idx, valid = la.select_top(scores, visible, a.indexer.top_k)
+        idx, valid, counts = decode_selection(
+            ix_pool, tab, q_i, w_i, n_vis, active, a.indexer, p)
         with jax.named_scope("sparse_attend"):
             sel = jnp.take_along_axis(tab, idx // p, axis=1) * p + idx % p
             o_lat = la.attend_rows(q_abs[:, 0], flat[sel], valid, r, scale)
-        # the scan reads every table page of every slot of the bucket,
-        # live or not: what it scores is w x mp x p, not what is visible
-        counts = (jnp.sum(n_vis, dtype=jnp.int32),
-                  jnp.sum(valid, dtype=jnp.int32),
-                  jnp.int32(w * mp * p),
-                  jnp.sum(active, dtype=jnp.int32))
     else:
         with jax.named_scope("window_latent_attend"):
             # the pages the window of the query at `lens` touches
@@ -140,48 +189,24 @@ def prefill_layer(eng, W, wset, h, rows_pool, ix_pool, tab, pos, t_end, li):
     seq]."""
     a = eng.desc.layers[li].attn
     g = eng.groups[eng.desc.layer_group[li]]
-    p, mp, chunk = eng.page_size, eng.pages_per_seq, pos.shape[0]
+    p, chunk = eng.page_size, pos.shape[0]
     q_n, q_r, row, gate, ix = _front(eng, W, wset, h, pos[None, :], li)
     q_abs = _absorbed_query(eng, wset, q_n, q_r, a, g)
     slots = jnp.where(pos < t_end, tab[pos // p] * p + pos % p,
                       g.n_pages * p)
     rows_pool = _write(rows_pool, slots, row[0])
-    nb = KEY_BLOCK_PAGES
-    kb = nb * p
+    kb = KEY_BLOCK_PAGES * p
     qpos = pos[:, None]
     last = jnp.minimum(pos[0] + chunk, t_end) - 1   # last real position
-
-    def block_pages(j):
-        """(pool pages [nb], key positions [kb]) of key block j; a page
-        past the table reads its last entry and is masked by position."""
-        page_ix = j * nb + jnp.arange(nb)
-        kpos = (page_ix[:, None] * p + jnp.arange(p)[None, :]).reshape(kb)
-        return tab[jnp.minimum(page_ix, mp - 1)], \
-            jnp.where(jnp.repeat(page_ix < mp, p), kpos, mp * p)
-
     if a.indexer is not None:
         q_i, k_i, w_i = ix
         ix_pool = _write(ix_pool, slots, k_i[0])
         hi_blk = last // kb + 1
-        with jax.named_scope("sparse_index_scores"):
-            def score_block(j, buf):
-                pages, kpos = block_pages(j)
-                keys = ix_pool[pages].reshape(kb, a.indexer.dim)
-                sc = la.index_scores(q_i[0], keys, w_i[0])
-                sc = jnp.where(kpos[None, :] <= qpos, sc, -jnp.inf)
-                return jax.lax.dynamic_update_slice(
-                    buf, sc, (jnp.zeros((), j.dtype), j * kb))
-
-            # columns past the live blocks stay -inf: not visible
-            width = -(-mp // nb) * kb
-            buf = jax.lax.fori_loop(
-                0, hi_blk, score_block,
-                jnp.full((chunk, width), -jnp.inf, jnp.float32))
-        with jax.named_scope("sparse_select"):
-            chosen = la.top_mask(buf, a.indexer.top_k)
+        chosen = prefill_selection(ix_pool, tab, q_i[0], w_i[0], qpos,
+                                   hi_blk, a.indexer, p)
 
         def block(j):
-            pages, kpos = block_pages(j)
+            pages, kpos = block_pages(tab, j, p)
             sel = jax.lax.dynamic_slice(
                 chosen, (jnp.zeros((), j.dtype), j * kb), (chunk, kb))
             return rows_pool[pages].reshape(kb, -1), \
@@ -190,7 +215,7 @@ def prefill_layer(eng, W, wset, h, rows_pool, ix_pool, tab, pos, t_end, li):
         lo_blk, scope = 0, "sparse_attend"
     else:
         def block(j):
-            pages, kpos = block_pages(j)
+            pages, kpos = block_pages(tab, j, p)
             return rows_pool[pages].reshape(kb, -1), \
                 (kpos[None, :] <= qpos) & (kpos[None, :] > qpos - a.window)
 

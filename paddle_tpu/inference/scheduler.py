@@ -52,7 +52,8 @@ from .sampling import (GREEDY, NEG, SamplingParams, TokenMaskAutomaton,
                        apply_penalties, fold_keys, select_from_topk,
                        stop_hit)
 from ..profiler import record_counters
-from . import latent
+from . import latent, sparse_heads
+from ..ops.sparse_attention import SPARSE_COUNTS
 from .description import UnsupportedByDescription
 from .serving import LLMEngine, EngineFullError, _rms, _mm, _mm_f32
 from .speculative import resolve_drafter
@@ -90,6 +91,15 @@ def _pools_put(pools, li, arr, acc):
         acc.append(arr)
         return pools
     return pools.at[li].set(arr)
+
+
+def _rows_layer(a):
+    """The module that serves a layer whose page group keeps ONE row a
+    token (latent rows, or per-head [K ; V] beside index keys), None for
+    a layer of separate K and V pools."""
+    if a.latent is not None:
+        return latent
+    return sparse_heads if a.indexer is not None else None
 
 
 def _pools_result(pools, acc):
@@ -594,10 +604,12 @@ class ContinuousBatchingEngine(LLMEngine):
                     (adapters not in (None, False), "adapters="),
                     (int(decode_block) > 1, "decode_block > 1"),
                     (megakernel not in (None, False), "megakernel="),
-                    (prefix_cache and (windows or len(self.groups) > 1),
+                    (prefix_cache and (
+                        windows or len(self.groups) > 1
+                        or any(g.index_width for g in self.groups)),
                      "prefix_cache=True (prefix sharing across window "
-                     "layers or several page groups; pass "
-                     "prefix_cache=False)")):
+                     "layers, several page groups or a group with index "
+                     "keys; pass prefix_cache=False)")):
                 if on:
                     raise UnsupportedByDescription(
                         f"{what} is written for a plain description "
@@ -1441,7 +1453,7 @@ class ContinuousBatchingEngine(LLMEngine):
         states = collections.Counter(
             r.state for r in self._requests.values())
         groups = [{"window": g.window, "layers": len(g.layers),
-                   "kind": "latent" if g.latent else "heads",
+                   "kind": g.kind,
                    "row_width": g.row_width,
                    "index_width": g.index_width,
                    "kv_heads": g.n_kv_heads, "pages_total": g.n_pages,
@@ -1462,7 +1474,7 @@ class ContinuousBatchingEngine(LLMEngine):
             # dead keys too; queries / indexer layers = rows decoded
             visible, attended, scored, queries = (
                 int(route[k][0]) * (1 << 24) + int(route[k][1])
-                for k in latent.SPARSE_COUNTS)
+                for k in SPARSE_COUNTS)
             sparse = {"keys_visible": visible, "keys_attended": attended,
                       "index_keys_scored": scored,
                       "decode_queries": queries}
@@ -2013,10 +2025,12 @@ class ContinuousBatchingEngine(LLMEngine):
                 oob = jnp.int32(g.n_pages * p)
                 ad_li = None if ad is None else \
                     self._ad_sel(AD, aid, li)
-                if a.latent is not None:
-                    # rows (and index keys) written, then the absorbed
-                    # form over the LIVE key blocks (inference/latent.py)
-                    attn, kp, vp = latent.prefill_layer(
+                rows_layer = _rows_layer(a)
+                if rows_layer is not None:
+                    # rows (and index keys) written, then attention over
+                    # the LIVE key blocks (inference/latent.py, the
+                    # absorbed form; inference/sparse_heads.py)
+                    attn, kp, vp = rows_layer.prefill_layer(
                         self, W, wset, h, k_pages_all[li],
                         v_pages_all[li], tab, pos, t_end, li)
                     k_pages_all = _pools_put(k_pages_all, li, kp, new_k)
@@ -2756,9 +2770,11 @@ class ContinuousBatchingEngine(LLMEngine):
             nkv = a.n_kv_heads // self.tp
             oob = jnp.int32(g.n_pages * p)
             ad_li = None if ad is None else self._ad_sel(AD, aid, li)
-            if a.latent is not None:
-                # one row a token, no value pool (inference/latent.py)
-                attn, kp, vp, counts = latent.decode_layer(
+            rows_layer = _rows_layer(a)
+            if rows_layer is not None:
+                # one row a token, index keys in the second pool
+                # (inference/latent.py, inference/sparse_heads.py)
+                attn, kp, vp, counts = rows_layer.decode_layer(
                     self, W, wset, h, k_pages_all[li], v_pages_all[li],
                     tab, lens, active, li)
                 if sparse_counts is not None:
@@ -2978,7 +2994,7 @@ class ContinuousBatchingEngine(LLMEngine):
                         touched=route["touched"] + jnp.sum(
                             rows > 0, axis=1, dtype=jnp.int32),
                         steps=route["steps"] + 1)
-                for name, add in zip(latent.SPARSE_COUNTS, zip(*sparse)):
+                for name, add in zip(SPARSE_COUNTS, zip(*sparse)):
                     low = route[name][1] + sum(add)
                     route[name] = jnp.stack(
                         [route[name][0] + (low >> 24),
@@ -3041,7 +3057,7 @@ class ContinuousBatchingEngine(LLMEngine):
                 steps=jnp.zeros((), jnp.int32))
         if self.desc.has_indexer:
             zeros.update({k: jnp.zeros((2,), jnp.int32)
-                          for k in latent.SPARSE_COUNTS})
+                          for k in SPARSE_COUNTS})
         return zeros
 
     def _route_read(self):

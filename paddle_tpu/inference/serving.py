@@ -233,12 +233,16 @@ _F32_KEYS = ("router", "router_bias", "sink", "ix_wq", "ix_wk", "ix_kn_w",
 
 class PageGroup:
     """The layers that share one page-pool shape, one page table and one
-    freeing policy: equal (KV heads, key width, value width, window), or
-    for LATENT layers equal (row width, index key width, window): such a
-    group keeps ONE row a token for all heads ([c_kv ; k_r], padded to
-    the 128 lanes: a gather of 576-wide rows ran 3.3 times slower on the
-    v5e than of 640-wide ones, PERF.md PR 30), no value pool, and beside
-    it the indexer's key where its layers have an indexer.
+    freeing policy (`description.GroupKey`). Per-head K and V ("heads"):
+    a key pool and a value pool. LATENT layers: ONE row a token for all
+    heads ([c_kv ; k_r], padded to the 128 lanes: a gather of 576-wide
+    rows ran 3.3 times slower on the v5e than of 640-wide ones, PERF.md
+    PR 30) and no value pool. Where its layers have an indexer, either
+    kind keeps the indexer's key a token in a second pool on the same
+    page table, and a per-head group then keeps a token's K and V as ONE
+    row too ([K ; V] of all its KV heads, `ops/sparse_attention.kv_row`):
+    decode gathers the SELECTED tokens' rows, and one gathered row then
+    brings both (docs/serving.md "Per-head groups with index keys").
     A FULL group (window None) keeps every token of a sequence: its pages
     are priced and claimed at admission, as the engine always did. A
     WINDOW group claims a page when a position is first written and
@@ -249,10 +253,15 @@ class PageGroup:
     def __init__(self, index, key, layers, page_size, max_batch,
                  pages_per_seq, chunk, plain=True):
         self.index = index
-        self.n_kv_heads, self.qk_dim, self.v_dim, self.window = key[:4]
-        self.latent = len(key) > 4
-        self.row_width = self.qk_dim if self.latent else 0
-        self.index_width = self.v_dim if self.latent else 0
+        self.kind = key.kind
+        self.n_kv_heads, self.qk_dim, self.v_dim = key[1:4]
+        self.window, self.index_width = key.window, key.index_width
+        self.latent = key.kind == "latent"
+        # one row a token: a latent group's, or [K ; V] beside index keys
+        self.row_width = (
+            self.qk_dim if self.latent
+            else self.n_kv_heads * (self.qk_dim + self.v_dim)
+            if self.index_width else 0)
         self.row_pad = -(-self.row_width // 128) * 128
         self.layers = tuple(layers)
         self.page_size = page_size
@@ -263,7 +272,7 @@ class PageGroup:
         # and its pool result another and copies the whole pool between
         # them every step (PERF.md, PR 26). A plain description keeps
         # the shape every mode reads.
-        self.k_flat = not plain and not self.latent \
+        self.k_flat = not plain and not self.row_width \
             and self.qk_dim % 128 != 0
         if self.window is None:
             self.n_pages = max_batch * pages_per_seq
@@ -286,9 +295,9 @@ class PageGroup:
         return self.n_pages - self.allocator.available
 
     def pool_shapes(self):
-        """(keys, values) of one layer; a latent group's are (rows, index
+        """(keys, values) of one layer; a group of rows has (rows, index
         keys), the second empty where its layers have no indexer."""
-        if self.latent:
+        if self.row_width:
             return ((self.n_pages, self.page_size, self.row_pad),
                     (self.n_pages, self.page_size, self.index_width)
                     if self.index_width else (0,))
@@ -456,11 +465,13 @@ class LLMEngine:
                 raise UnsupportedByDescription(
                     "quant='int8' has no grouped int8 product for routed "
                     "experts yet; serve this description unquantized")
-            if quant is not None and any(layer.attn.latent is not None
-                                         for layer in desc.layers):
+            if quant is not None and (desc.has_indexer or any(
+                    layer.attn.latent is not None
+                    for layer in desc.layers)):
                 raise UnsupportedByDescription(
                     "quant='int8' does not cover a latent layer's "
-                    "projections; serve this description unquantized")
+                    "projections or an indexer; serve this description "
+                    "unquantized")
         # tensor parallelism: tp > 1 runs every compiled dispatch under
         # shard_map on a 1-D "mp" mesh — heads + KV pools sharded over
         # heads, matmuls column/row-parallel (inference/tp.py). The
@@ -523,14 +534,21 @@ class LLMEngine:
         # prefill/step never closure-capture arrays (HLO-constant bloat):
         # one (cos, sin) pair per distinct (rotary width, base), which a
         # plain description has one of, under the names it always had
+        # (an indexer rotates its own width on its layer's base: one
+        # more pair where that width is not the layer's)
+        def rope_kinds(a):
+            return [(a.rope_dim, a.rope_theta)] + (
+                [(a.indexer.rope_dim, a.rope_theta)] if a.indexer else [])
+
         kinds = []
         for layer in desc.layers:
-            kind = (layer.attn.rope_dim, layer.attn.rope_theta)
-            if kind not in kinds:
-                kinds.append(kind)
-        self._layer_rope = tuple(
-            kinds.index((layer.attn.rope_dim, layer.attn.rope_theta))
-            for layer in desc.layers)
+            for kind in rope_kinds(layer.attn):
+                if kind not in kinds:
+                    kinds.append(kind)
+        self._layer_rope = tuple(kinds.index(rope_kinds(layer.attn)[0])
+                                 for layer in desc.layers)
+        self._index_rope = tuple(kinds.index(rope_kinds(layer.attn)[-1])
+                                 for layer in desc.layers)
         tables = [_rope_cache(max_len, d, theta, jnp.float32)
                   for d, theta in kinds]
         if len(tables) == 1:
@@ -570,10 +588,12 @@ class LLMEngine:
                     self.k_pages.append(jnp.zeros(k_shape, self.kv_dtype))
                     self.v_pages.append(jnp.zeros(v_shape, self.kv_dtype))
 
-    def _rope_of(self, W, li):
-        """(cos, sin) tables of layer li: [max_len, rotary width / 2]."""
+    def _rope_of(self, W, li, indexer=False):
+        """(cos, sin) tables of layer li, or of its indexer: [max_len,
+        rotary width / 2]."""
         if "rope" in W:
-            return W["rope"][self._layer_rope[li]]
+            return W["rope"][(self._index_rope if indexer
+                              else self._layer_rope)[li]]
         return W["cos"], W["sin"]
 
     def _require_plain(self, what):
@@ -747,6 +767,9 @@ class LLMEngine:
         q = q.reshape(b, t, -1, a.qk_dim)
         k = k.reshape(b, t, -1, a.qk_dim)
         v = v.reshape(b, t, -1, a.v_dim)
+        if a.qk_norm:               # every head, before the rotation
+            q = _rms(q, wset["q_hn"], W["eps"])
+            k = _rms(k, wset["k_hn"], W["eps"])
         if a.value_scale != 1.0:
             v = v * jnp.asarray(a.value_scale, v.dtype)
         # GQA: k/v STAY at nh_kv heads — the paged cache stores the
@@ -793,9 +816,10 @@ class LLMEngine:
         ffn = self.desc.layers[li].ffn
         if ffn.kind == "experts":
             y, rows = routed_experts(
-                x.reshape(b * t, -1), wset["router"], wset["router_bias"],
-                wset["w_gu"], wset["w_d"], ffn.held, ffn.top_k,
-                interpret=self.interpret)
+                x.reshape(b * t, -1), wset["router"],
+                wset.get("router_bias"), wset["w_gu"], wset["w_d"],
+                ffn.held, ffn.top_k, interpret=self.interpret,
+                score=ffn.score)
             if expert_rows is not None:
                 expert_rows.append(rows)
             if ffn.shared_width:    # the shared expert, on every token

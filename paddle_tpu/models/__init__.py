@@ -6,3 +6,4 @@ from .bert import (BertConfig, BertModel, BertForMaskedLM,
                    BertForSequenceClassification)
 from .mimo_v2 import MiMoV2Config, MiMoV2ForCausalLM
 from .dots3_note import Dots3NoteConfig, Dots3NoteForCausalLM
+from .keye_vl2 import KeyeVL2Config, KeyeVL2ForCausalLM

@@ -13,18 +13,10 @@ Absorbed form (what the engine runs, decode and prefill): q~_h = [q_n,h
 W_uk,h^T ; q_r,h] scores the rows directly, the weighted sum runs over
 c_kv, and W_uv,h comes after it.
 
-Indexer (`a.indexer`): q^I_j = c_q W^I_q, k^I = LayerNorm(x W^I_k), both
-rotated on their leading dims, w = x W^I_w;  I(t, s) = sum_j w_j(t)
-relu(q^I_j(t) . k^I(s)), float32 at precision "highest" like the router;
-a query attends to the top_k visible positions by I (all while fewer are
-visible). The selection is exact, in the form its consumer takes:
-`select_top` (lax.top_k) gives decode the LIST of positions its row
-gather needs, `top_mask` (a radix select on the float's bits,
-`kth_largest`, then one cumulative count) gives prefill the MASK its
-walk over key blocks needs. Turning one into the other is a sort or a
-scatter of [queries, max_len] on this chip, dearer than either; both
-send ties at the k-th value to the lowest positions, and one test holds
-both to a stable argsort of the reference's scores.
+Indexer (`a.indexer`): its query comes from the query latent, q^I_j = c_q
+W^I_q; the index score, the exact top-k selection (both forms) and the
+selection counters are ops/sparse_attention.py's, which per-head layers
+with an indexer call too.
 
 Products take their operands in the WEIGHTS' dtype with float32 sums.
 """
@@ -33,26 +25,16 @@ import math
 import jax
 import jax.numpy as jnp
 
+from .sparse_attention import (index_qkw, index_scores, rope_half,
+                               select_top)
+from .sparse_attention import rms as _rms
+
 _HI = jax.lax.Precision.HIGHEST
 _NEG = -1e30
 
 
 def _dot(x, w):
     return jnp.dot(x.astype(w.dtype), w, preferred_element_type=jnp.float32)
-
-
-def _rms(x, w, eps):
-    x = x.astype(jnp.float32)
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) \
-        * w.astype(jnp.float32)
-
-
-def rope_half(x, cos, sin):
-    """Rotate-half over ALL of x's last dim; cos/sin broadcast to
-    [..., d / 2]."""
-    d2 = x.shape[-1] // 2
-    x1, x2 = x[..., :d2], x[..., d2:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
 def softmax_scale(a):
@@ -95,73 +77,6 @@ def expand_values(o_lat, w_uv, a):
 def head_gate(x, w_gate):
     """sigmoid(x W_g) [..., H] float32."""
     return jax.nn.sigmoid(_dot(x, w_gate))
-
-
-def index_qkw(x, c_q, w, ix, cos, sin):
-    """(q^I [..., Hi, di], k^I [..., di], w [..., Hi]) float32; cos/sin
-    [..., ix.rope_dim / 2]."""
-    x, c_q = x.astype(jnp.float32), c_q.astype(jnp.float32)
-    q = jnp.dot(c_q, w["ix_wq"], precision=_HI).reshape(
-        *x.shape[:-1], ix.n_heads, ix.dim)
-    k = jnp.dot(x, w["ix_wk"], precision=_HI)
-    mu = jnp.mean(k, -1, keepdims=True)
-    var = jnp.mean(jnp.square(k - mu), -1, keepdims=True)
-    k = (k - mu) * jax.lax.rsqrt(var + ix.eps) * w["ix_kn_w"] + w["ix_kn_b"]
-    rd = ix.rope_dim
-    q = jnp.concatenate([rope_half(q[..., :rd], cos[..., None, :],
-                                   sin[..., None, :]), q[..., rd:]], -1)
-    k = jnp.concatenate([rope_half(k[..., :rd], cos, sin), k[..., rd:]], -1)
-    return q, k, jnp.dot(x, w["ix_ww"], precision=_HI)
-
-
-def index_scores(q, k, wt):
-    """I(t, s): q [..., t, Hi, di], k [..., s, di], wt [..., t, Hi] ->
-    [..., t, s] float32."""
-    s = jnp.einsum("...thd,...sd->...ths", q, k.astype(jnp.float32),
-                   precision=_HI)
-    s = jnp.sum(jax.nn.relu(s) * wt[..., None], axis=-2)
-    return jnp.where(s == 0, 0.0, s)    # one zero: ties break by position
-
-
-def select_top(scores, visible, k):
-    """The k visible positions with the largest score: (idx [..., k]
-    int32, valid [..., k] bool). Fewer visible: all of them, the rest
-    invalid."""
-    vals, idx = jax.lax.top_k(jnp.where(visible, scores, -jnp.inf),
-                              min(k, scores.shape[-1]))
-    return idx.astype(jnp.int32), vals > -jnp.inf
-
-
-def kth_largest(scores, k):
-    """The k-th largest of each row of scores [n, s] float32 (-inf where
-    the row has fewer than k finite entries among -inf padding): a radix
-    select over the bits, 32 counting passes, no sort."""
-    k = min(k, scores.shape[-1])
-    u = jax.lax.bitcast_convert_type(scores, jnp.uint32)
-    u = jnp.where(u >> 31 == 1, ~u, u | jnp.uint32(1 << 31))  # monotone
-
-    def body(i, prefix):
-        cand = prefix | (jnp.uint32(1) << (jnp.uint32(31) - i.astype(
-            jnp.uint32)))
-        cnt = jnp.sum(u >= cand[:, None], axis=1)
-        return jnp.where(cnt >= k, cand, prefix)
-
-    p = jax.lax.fori_loop(0, 32, body, jnp.zeros(scores.shape[0],
-                                                 jnp.uint32))
-    p = jnp.where(p >> 31 == 1, p & jnp.uint32((1 << 31) - 1), ~p)
-    return jax.lax.bitcast_convert_type(p, jnp.float32)
-
-
-def top_mask(scores, k):
-    """[n, s] bool: each row's k largest entries, ties at the k-th value
-    going to the lowest positions (what lax.top_k and a stable argsort
-    pick), from `kth_largest` and one cumulative count."""
-    thr = kth_largest(scores, k)[:, None]
-    above, ties = scores > thr, scores == thr
-    room = min(k, scores.shape[-1]) - jnp.sum(
-        above, axis=1, dtype=jnp.int32, keepdims=True)
-    return above | (ties & (jnp.cumsum(ties, axis=1, dtype=jnp.int32)
-                            <= room))
 
 
 def attend_rows(q_abs, rows, valid, kv_rank, scale):

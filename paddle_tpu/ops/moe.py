@@ -7,9 +7,12 @@ the layer runs without its exchange and nothing stands in for the absent
 chips; summed over every chip's `held` range the parts give the whole
 layer (tests/test_mimo_v2.py holds the shares to the uncut layer).
 
-Router (the DeepSeek-V3 / `noaux_tc` form, float32 throughout):
-  s = sigmoid(x W_g);  choose the top_k largest of s + b (b a stored
-  correction bias that only steers the choice);  w_e = s_e / sum_chosen s.
+Router, float32 throughout, by `score`. "sigmoid" (the DeepSeek-V3 /
+`noaux_tc` form): s = sigmoid(x W_g);  choose the top_k largest of s + b
+(b a stored correction bias that only steers the choice);  w_e = s_e /
+sum_chosen s. "softmax" (`norm_topk_prob`): p = softmax(x W_g) over all
+experts;  choose the top_k largest of p;  w_e = p_e / sum_chosen p; no
+bias.
 Expert e: SwiGLU, y_e = (silu(x G_e) * (x U_e)) D_e;  y = sum_chosen w_e y_e.
 
 The two products over the experts held are the Pallas grouped matmul
@@ -24,19 +27,24 @@ import jax.numpy as jnp
 from .pallas.grouped_matmul import grouped_matmul
 
 
-def route(x, router_w, router_bias, top_k):
+def route(x, router_w, router_bias, top_k, score="sigmoid"):
     """(expert ids [t, k] int32, weights [t, k] float32) of every token,
     over ALL experts, in float32 at precision "highest"."""
     logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
-    s = jax.nn.sigmoid(logits)
-    _, idx = jax.lax.top_k(s + router_bias.astype(jnp.float32), top_k)
+    if score == "softmax":
+        s = jax.nn.softmax(logits, -1)
+        _, idx = jax.lax.top_k(s, top_k)
+    else:
+        assert score == "sigmoid", score
+        s = jax.nn.sigmoid(logits)
+        _, idx = jax.lax.top_k(s + router_bias.astype(jnp.float32), top_k)
     chosen = jnp.take_along_axis(s, idx, axis=1)
     return idx.astype(jnp.int32), chosen / jnp.sum(chosen, -1, keepdims=True)
 
 
 def routed_experts(x, router_w, router_bias, w_gu, w_d, held, top_k,
-                   interpret=False):
+                   interpret=False, score="sigmoid"):
     """x [t, hidden] -> (y [t, hidden] in x's dtype: the held experts'
     part of the layer's result; rows [held] int32: how many (token,
     choice) rows each held expert received). The router reads x as it
@@ -45,14 +53,15 @@ def routed_experts(x, router_w, router_bias, w_gu, w_d, held, top_k,
     float32 sums, and the weighted sum over a token's choices is
     float32.
 
-    router_w [hidden, experts], router_bias [experts]; w_gu [held, hidden,
+    router_w [hidden, experts], router_bias [experts] (None under a
+    "softmax" `score`); w_gu [held, hidden,
     2 x width] with the gate's columns first; w_d [held, width, hidden];
     held = (lo, hi) expert ids, hi - lo == w_gu.shape[0]."""
     t, hidden = x.shape
     lo, hi = held
     n_held = hi - lo
     assert w_gu.shape[0] == n_held == w_d.shape[0], (held, w_gu.shape)
-    idx, wts = route(x, router_w, router_bias, top_k)
+    idx, wts = route(x, router_w, router_bias, top_k, score)
 
     # (token, choice) rows sorted by held expert; rows of experts held
     # elsewhere sort last under the sentinel n_held and join no group

@@ -24,6 +24,7 @@ from paddle_tpu.inference.description import (UnsupportedByDescription,
 from paddle_tpu.models import Dots3NoteConfig, Dots3NoteForCausalLM
 from paddle_tpu.models.mimo_v2 import rope_tables
 from paddle_tpu.ops import latent_attention as la
+from paddle_tpu.ops import sparse_attention as sa
 from paddle_tpu.ops.moe import routed_experts
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -254,17 +255,17 @@ def test_selection_equals_an_argsort_of_the_reference_scores(model, how):
     seen = jnp.asarray(np.tril(np.ones((s, s), bool)))
     want = np.asarray(REF.selection(ref_scores, seen, 12))
     _, _, _, c_q2 = la.latent_qkv(x, w, a, 1e-5, cos, sin)
-    q_i, k_i, w_i = la.index_qkw(x, c_q2, w, a.indexer, cos, sin)
-    scores = la.index_scores(q_i, k_i, w_i)[0]
+    q_i, k_i, w_i = sa.index_qkw(x, c_q2, w, a.indexer, cos, sin)
+    scores = sa.index_scores(q_i, k_i, w_i)[0]
     np.testing.assert_allclose(np.asarray(scores), np.asarray(ref_scores),
                                rtol=1e-5, atol=1e-4)
     if how == "top_k":
-        idx, valid = (np.asarray(v) for v in la.select_top(scores, seen, 12))
+        idx, valid = (np.asarray(v) for v in sa.select_top(scores, seen, 12))
         got = np.zeros((s, s), bool)
         for t in range(s):
             got[t, idx[t][valid[t]]] = True
     else:
-        got = np.asarray(seen & la.top_mask(
+        got = np.asarray(seen & sa.top_mask(
             jnp.where(seen, scores, -jnp.inf), 12))
     assert want.sum() == sum(min(t + 1, 12) for t in range(s))
     assert np.array_equal(got, want)
@@ -280,7 +281,7 @@ def test_kth_largest_is_the_sorted_kth(n, s, k):
     x = rng.normal(size=(n, s)).astype(np.float32) * 100
     x[:, ::5] = -np.inf                         # not visible
     x[0, 3] = x[0, 4] = 0.0                     # signed zero, a tie
-    got = np.asarray(la.kth_largest(jnp.asarray(x), k))
+    got = np.asarray(sa.kth_largest(jnp.asarray(x), k))
     want = np.sort(x, axis=1)[:, ::-1][:, min(k, s) - 1]
     assert np.array_equal(got, want)
 
@@ -387,9 +388,9 @@ def test_the_description_is_the_seam(model):
     desc = describe(model)
     assert not desc.plain and desc.has_experts and desc.has_indexer
     assert desc.layer_group == (0, 0, 1, 1)
-    # (1, row width, index key width, window, "latent")
-    assert desc.groups == ((1, 24, 16, None, "latent"),
-                           (1, 40, 0, 13, "latent"))
+    # (kind, 1, row width, no value width, window, index key width)
+    assert desc.groups == (("latent", 1, 24, 0, None, 16),
+                           ("latent", 1, 40, 0, 13, 0))
     full, win = desc.layers[1].attn, desc.layers[2].attn
     assert (full.n_heads, win.n_heads) == (4, 2)    # heads differ by kind
     assert full.gate and win.gate and win.indexer is None

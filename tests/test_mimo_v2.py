@@ -348,7 +348,7 @@ def test_the_description_is_the_seam():
     mimo = describe(MiMoV2ForCausalLM(MiMoV2Config.tiny()))
     assert not mimo.plain and mimo.has_experts
     assert mimo.layer_group == (0, 1, 1, 0)
-    assert [g[3] for g in mimo.groups] == [None, 8]
+    assert [g.window for g in mimo.groups] == [None, 8]
     with pytest.raises(TypeError, match="serving_description"):
         ContinuousBatchingEngine(object())
     # no check on a model's class is left in the engine

@@ -9,35 +9,53 @@ tiled kernels. TPU-first design:
   (d % 128 == 0 — the d=128 LLM geometries), q/k/v are taken as
   [b, s, h*d] VIEWS of the model's native [b, s, h, d] layout (a free
   reshape) and the grid's head dimension indexes lane-blocks of size d
-  directly. The round-4 wrapper's [b,s,h,d]→[b*h,s,d] swapaxes+reshape
-  pair (measured ~13 ms/step at bs32) disappears. Head dims that are
-  not lane multiples fall back to the transposed [b*h, s, d] layout —
-  the SAME kernels with a single lane-covering "head" (Mosaic requires
-  the block's trailing two dims to be 8/128-divisible or dim-covering,
-  so a squeezed head dim cannot sit in sublane position).
+  directly. Head dims that are not lane multiples fall back to the
+  transposed [b*h, s, d] layout — the SAME kernels with a single
+  lane-covering "head" (Mosaic requires the block's trailing two dims
+  to be 8/128-divisible or dim-covering, so a squeezed head dim cannot
+  sit in sublane position).
 - K/V are streamed from HBM block-by-block via the grid's innermost
   dimension (Pallas double-buffers the DMAs); only [bk, d] tiles are
   ever VMEM-resident, so sequence length is bounded by HBM, not VMEM.
 - The [s, s] score matrix is never materialized. Softmax statistics
   (running max + logsumexp) live in VMEM scratch that persists across
   the innermost grid dimension.
-- Backward is ONE fused kernel (round-4 profile: the former separate
-  dQ and dK/dV kernels each recomputed p = exp(logits - lse) and
-  dp = dO @ V^T, re-streaming K/V — 7 matmuls + 2 exp per block pair;
-  fused: 5 matmuls + 1 exp). The grid runs K/V blocks outer, Q blocks
-  inner: dK/dV accumulate in VMEM scratch across the inner dimension,
-  while per-(k-block) dQ partials stream to an [nk, ...] HBM buffer —
-  each block written exactly once — and are reduced by one XLA sum
-  afterwards (the accumulation pattern of public TPU splash
-  attention's fused backward; no read-modify-write DMAs).
+- Backward is ONE fused kernel (5 matmuls + 1 exp per block pair where
+  separate dQ and dK/dV kernels took 7 + 2). The grid runs K/V blocks
+  outer, Q blocks inner: dK/dV accumulate in VMEM scratch across the
+  inner dimension, while per-(k-block) dQ partials stream to an
+  [nk, ...] float32 HBM buffer — each block written exactly once — and
+  are reduced by one XLA sum afterwards (the accumulation pattern of
+  public TPU splash attention's fused backward; no read-modify-write
+  DMAs). The buffer and the sum shrink with nk: 1.42 ms a call at
+  bk = 256, 0.36 at bk = 1024 (shape below).
 - Additive masks are supported natively as a blocked operand (bool
   masks are converted to additive form in the wrapper); causal masking
-  is computed inline from block indices with whole-block skipping.
-- Grid-step amortization: `nb` batch slices are processed per grid
-  step. At LLM-training shapes the per-step scalar-core/DMA overhead,
-  not the MXU, is the bottleneck (measured: b=32 h=16 s=1024 d=64 has
-  only ~4 MFLOP per 128x128 step); batching slices into one step cut
-  the grid from 32768 to 1024 steps and ~5x'd throughput on v5e.
+  is computed inline from block indices with whole-block skipping, and
+  the key-padding compare is built only where the padded length leaves
+  padding (`_block_valid`). Building the mask on the diagonal blocks
+  alone was tried (PR 33) and bought nothing: within 1 % at every
+  geometry of the sweep below. The mask is not what a step waits for.
+- Block geometry is drawn per call from its shape (`flash_geometry`):
+  the sequence pads to 256, the blocks grow to 1024 x 1024 where they
+  divide the padded length, and `_fit_geometry` shrinks the batch
+  slices a step, then bq, then bk under the VMEM budget. What a grid
+  step costs grows with its q rows times its k steps (the per-row
+  running max / sum / rescale is paid once a k step whatever bk is), so
+  wide k blocks win. Measured on one v5e (PR 33,
+  docs/probes/flash_train_probe.py, device time of one call at
+  (2, 4096, 16, 128) bf16 causal): forward 4.99 ms at 256 x 256 with two
+  slices a step (what every call ran before PR 33), 2.57 at 512 x 512,
+  1.66 at 512 x 1024, 1.45 at 1024 x 1024 with one slice; backward
+  kernel 5.21 / 2.89 / 2.59 / 2.61 ms.
+- `nb` batch slices share one grid step where the budget leaves room.
+  They pay where the blocks cannot grow: at (64, 256, 16, 128), one
+  256 x 256 block a head, eight slices a step ran the forward in 0.75 ms
+  and the backward kernel in 1.11 against 1.34 / 1.42 for one slice
+  (same probe, `--nb-max`). Where both fit, larger blocks beat more
+  slices: at (16, 1024, 16, 128) one slice at 1024 x 1024 took 1.02 ms
+  forward against 1.95 for eight at 256 x 256, which is why
+  `_fit_geometry` gives up slices before it shrinks a block.
 - lse/delta ride in 8-lane (not 128-lane) replicated layouts to bound
   the HBM footprint of the softmax stats at large batch.
 """
@@ -101,6 +119,22 @@ def _slice_id(bb, hh, j, nb, nheads):
     return (bb * nb + j) * nheads + hh
 
 
+def _block_valid(*, bq, bk, nk, s_true, q_start, k_start, causal):
+    """Validity mask of a live block, None where the shape alone says every
+    cell is valid (no causal mask and no key padding): computed ONCE per
+    grid step and shared by all nb slices (the iota/compare VPU work is
+    not per-slice)."""
+    cols = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1) + k_start
+    valid = None
+    if nk * bk > s_true:    # key padding beyond the true sequence
+        valid = cols < s_true
+    if causal:
+        rows = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) + q_start
+        below = rows >= cols
+        valid = below if valid is None else valid & below
+    return valid
+
+
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
@@ -129,26 +163,22 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, nb, bq, bk, nk, s_true, causal,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     def _compute():
-        cols = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1) + k_start
-        valid = cols < s_true  # key padding beyond the true sequence
-        if causal:
-            rows = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) + q_start
-            valid = valid & (rows >= cols)
+        valid = _block_valid(bq=bq, bk=bk, nk=nk, s_true=s_true,
+                             q_start=q_start, k_start=k_start, causal=causal)
         for j in range(nb):
-            # MXU matmuls run in the INPUT dtype (bf16 at training shapes —
-            # ~8x the f32 MXU rate) with f32 accumulation; only the softmax
-            # math is f32. Round-2 cast operands to f32 first, which put
-            # every pass on the slow f32 MXU path (measured 8.8 TFLOP/s).
+            # MXU matmuls run in the INPUT dtype (bf16 at training shapes)
+            # with f32 accumulation; only the softmax math is f32
             q = q_ref[j]
             k = k_ref[j]
-            logits = jax.lax.dot_general(
+            lg = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
                 precision=_prec(q.dtype)) * jnp.float32(scale)
             if mask_ref is not None:
                 mj = mask_ref[j] if mask_batched else mask_ref[0]
-                logits = logits + mj.astype(jnp.float32)
-            lg = jnp.where(valid, logits, jnp.float32(NEG_INF))
+                lg = lg + mj.astype(jnp.float32)
+            if valid is not None:
+                lg = jnp.where(valid, lg, jnp.float32(NEG_INF))
 
             m_prev = m_scr[j][:, :1]
             l_prev = l_scr[j][:, :1]
@@ -201,52 +231,122 @@ def _pick_nb(b, mask_group, nb_max=8):
     return nb
 
 
-VMEM_BUDGET = 12 * 1024 * 1024  # leave ~4MB of the ~16MB v5e VMEM free
+VMEM_BUDGET = 15 * 1024 * 1024  # of Mosaic's 16 MiB scoped default on v5e
 
 
-def _step_vmem_bytes(nb, bq, bk, d, isz, has_mask, mask_batched):
+def _step_vmem_bytes(nb, bq, bk, d, isz, has_mask, mask_batched,
+                     dropout=False):
     """Worst-kernel (fused backward) per-grid-step VMEM bytes:
     double-buffered operand blocks (q, do, k, v, lse, delta, mask),
-    double-buffered outputs (dq partial, dk, dv), f32 dk/dv scratch."""
+    double-buffered outputs (dq partial, dk, dv), f32 dk/dv scratch, and
+    the body's temporaries: [bq, bk] float32 tiles (one and a half with
+    16-bit operands, five where float32 operands take the multi-pass
+    matmul, two more for dropout's hash and keep mask) and the float32
+    results of the three [., d] matmuls. VMEM tiles are 128 lanes wide:
+    a d = 64 block and the ROW_LANES-wide lse / delta rows take a whole
+    tile. An upper bound, 0.6 to 4.5 MiB above the least limit the
+    compiler accepts in each of 17 classes of call (PR 33:
+    `docs/probes/flash_train_probe.py --aot --classes`, PERF.md 6)."""
     db = 2  # Pallas double-buffers HBM<->VMEM block DMAs
-    ins = (2 * nb * bq * d + 2 * nb * bk * d) * isz + 2 * nb * bq * 8 * 4
+    d = -(-d // 128) * 128
+    ins = (2 * nb * bq * d + 2 * nb * bk * d) * isz + 2 * nb * bq * 128 * 4
     if has_mask:
         ins += (nb if mask_batched else 1) * bq * bk * 4
     outs = nb * bq * d * 4 + 2 * nb * bk * d * isz  # dq partial is f32
     scratch = 2 * nb * bk * d * 4
-    return db * (ins + outs) + scratch
+    tiles = (1.5 if isz <= 2 else 5) + 2 * dropout
+    temps = int(tiles * bq * bk * 4) + (2 * bk + bq) * d * 4
+    return db * (ins + outs) + scratch + temps
 
 
-def _fit_geometry(b, d, itemsize, has_mask, mask_group, bq, bk, nb_max):
-    """Shrink (nb, then bk, then bq) until the worst kernel's per-step
+def _fit_geometry(b, d, itemsize, has_mask, mask_group, bq, bk, nb_max,
+                  dropout=False):
+    """Shrink (nb, then bq, then bk) until the worst kernel's per-step
     VMEM fits the budget (ADVICE r2 medium: f32 inputs + d>=128 + a
     batch-varying mask at bq=bk=256/nb=8 exceed ~16MB and fail to
-    compile). mask_group: None (no mask) / 1 (per-slice mask) / g > 1
-    (one mask shared by groups of g slices — fallback layout)."""
+    compile). In that order because the chip's sweep says so (PR 33): a
+    step's cost grows with its q rows times its k steps, so a wide bk is
+    worth more than a tall bq, and either more than slices a step.
+    mask_group: None (no mask) / 1 (per-slice mask) / g > 1 (one mask
+    shared by groups of g slices — fallback layout)."""
     batched = mask_group == 1 if has_mask else False
     nb = _pick_nb(b, mask_group if has_mask else None, nb_max)
     while True:
-        if _step_vmem_bytes(nb, bq, bk, d, itemsize, has_mask,
-                            batched) <= VMEM_BUDGET:
+        if _step_vmem_bytes(nb, bq, bk, d, itemsize, has_mask, batched,
+                            dropout) <= VMEM_BUDGET:
             return bq, bk, nb
         if nb > 1:
             nb //= 2
-        elif bk > 128:
-            bk //= 2
         elif bq > 128:
             bq //= 2
+        elif bk > 128:
+            bk //= 2
         else:
             return bq, bk, nb  # minimal geometry; let Mosaic report
 
 
-def _mask_group(mask, B, h):
-    """nb-constraint/VMEM descriptor for the mask: 1 = per-slice
-    (batched block), g > 1 = one mask shared by groups of g slices
-    (fallback layout; nb must divide g), None = shared by everything
-    (no nb constraint, single-row block)."""
+BLOCK_UNIT = 256            # sequences pad to a multiple of this
+BLOCK_TARGET = (1024, 1024)  # (bq, bk) drawn where the padded length allows
+
+
+def _drawn_block(s_pad, target):
+    """The largest block <= target that is BLOCK_UNIT times a power of
+    two and divides the padded length."""
+    blk = BLOCK_UNIT
+    while blk * 2 <= target and s_pad % (blk * 2) == 0:
+        blk *= 2
+    return blk
+
+
+def _geometry(B, d, itemsize, has_mask, mask_group, s_true, bq, bk, nb_max,
+              dropout=False):
+    """(bq, bk, nb, s_pad) of a call. Explicit bq / bk are taken as given
+    (the sequence pads to the larger); None draws the block from what the
+    call shows: the sequence pads to BLOCK_UNIT whatever the target, so a
+    larger block never inflates a short sequence (s <= 256 keeps
+    256 x 256), and `_fit_geometry` then shrinks nb and the blocks under
+    the VMEM budget (operand size, head dim, mask kind)."""
+    unit = max(bq or BLOCK_UNIT, bk or BLOCK_UNIT)
+    s_pad = -(-s_true // unit) * unit
+    bq = min(bq, s_pad) if bq else _drawn_block(s_pad, BLOCK_TARGET[0])
+    bk = min(bk, s_pad) if bk else _drawn_block(s_pad, BLOCK_TARGET[1])
+    return _fit_geometry(B, d, itemsize, has_mask, mask_group, bq, bk,
+                         nb_max, dropout) + (s_pad,)
+
+
+def _mask_rows(mask_shape, b, h, fast):
+    """Leading extent of the mask as the kernels see it: `_prep`
+    broadcasts the batch dim to 1 or b and the head dim to 1 or h, and
+    the fallback layout folds per-head masks into per-slice rows."""
+    mb = mask_shape[0] if mask_shape[0] in (1, b) else b
+    mh = mask_shape[1] if mask_shape[1] in (1, h) else h
+    return b * h if (not fast and mh > 1) else mb
+
+
+def flash_geometry(q_shape, dtype, mask_shape=None, bq=None, bk=None,
+                   nb_max=8, dropout=False):
+    """(bq, bk, nb, s_pad) that `make_flash_attention(bq, bk, nb_max=)`
+    runs a [b, s, h, d] call of this dtype with (mask_shape: the additive
+    mask's [b|1, h|1, sq, sk], None without one; dropout: through one of
+    the build's dropout entries)."""
+    b, s, h, d = q_shape
+    fast = d % 128 == 0
+    B, hk = (b, h) if fast else (b * h, 1)
+    mg = None
+    if mask_shape is not None:
+        mg = _mask_group(_mask_rows(mask_shape, b, h, fast), B, hk)
+    return _geometry(B, d, jnp.dtype(dtype).itemsize, mask_shape is not None,
+                     mg, s, bq, bk, nb_max, dropout)
+
+
+def _mask_group(rows, B, h):
+    """nb-constraint/VMEM descriptor for a mask of `rows` leading rows:
+    1 = per-slice (batched block), g > 1 = one mask shared by groups of
+    g slices (fallback layout; nb must divide g), None = shared by
+    everything (no nb constraint, single-row block)."""
     if h > 1:  # fast path: head/batch grid dims index the mask directly
-        return 1 if mask.shape[0] > 1 else None
-    g = B // mask.shape[0]
+        return 1 if rows > 1 else None
+    g = B // rows
     return g if g > 1 else 1
 
 
@@ -321,19 +421,17 @@ def _mask_spec(mask, B, h_grid, nb, bq, bk, bwd, causal=False):
     return pl.BlockSpec((1, None, bq, bk), imap), False, group
 
 
-def _flash_fwd(q, k, v, mask, h, causal, scale, bq, bk, s_true, interpret,
-               nb_max=8, dropout_p=0.0, seed=None):
+def _flash_fwd(q, k, v, mask, h, causal, scale, bq, bk, nb, s_true,
+               interpret, dropout_p=0.0, seed=None):
     """q,k,v: [B, s, h*d] (seq padded to block multiples) where B carries
     the batch (fast path) or batch*heads with h == 1 (fallback); mask:
     [b|1, h|1, s, s] additive | None; s_true = unpadded sequence length
-    (keys beyond it are masked out). Returns (out [B, s, h*d],
-    lse [B, h, s, ROW_LANES] — lane-replicated logsumexp)."""
+    (keys beyond it are masked out); (bq, bk, nb) from `_geometry`.
+    Returns (out [B, s, h*d], lse [B, h, s, ROW_LANES] — lane-replicated
+    logsumexp)."""
     B, s, H = q.shape
     d = H // h
     has_mask = mask is not None
-    mg = _mask_group(mask, B, h) if has_mask else None
-    bq, bk, nb = _fit_geometry(B, d, q.dtype.itemsize, has_mask, mg,
-                               bq, bk, nb_max)
     nq = s // bq
     nk = s // bk
 
@@ -395,6 +493,7 @@ def _flash_fwd(q, k, v, mask, h, causal, scale, bq, bk, s_true, interpret,
                 dimension_semantics=("parallel", "parallel", "parallel",
                                      "arbitrary")),
             interpret=interpret,
+            name="flash_attention_fwd",
         )(*args)
     return out, lse
 
@@ -402,17 +501,6 @@ def _flash_fwd(q, k, v, mask, h, causal, scale, bq, bk, s_true, interpret,
 # ---------------------------------------------------------------------------
 # fused backward: one kernel, grid (batch, head, k-blocks, q-blocks)
 # ---------------------------------------------------------------------------
-
-def _block_valid(*, bq, bk, s_true, q_start, k_start, causal):
-    """Per-block validity mask — computed ONCE per grid step and shared by
-    all nb slices (the iota/compare VPU work is not per-slice)."""
-    cols = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1) + k_start
-    valid = cols < s_true
-    if causal:
-        rows = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) + q_start
-        valid = valid & (rows >= cols)
-    return valid
-
 
 def _block_p(q, k, mask_val, lse_col, valid, *, scale):
     # q/k arrive in input dtype (bf16 fast path); accumulate f32 on the MXU
@@ -422,12 +510,13 @@ def _block_p(q, k, mask_val, lse_col, valid, *, scale):
         precision=_prec(q.dtype)) * jnp.float32(scale)
     if mask_val is not None:
         logits = logits + mask_val
-    logits = jnp.where(valid, logits, jnp.float32(NEG_INF))
+    if valid is not None:
+        logits = jnp.where(valid, logits, jnp.float32(NEG_INF))
     return jnp.exp(logits - lse_col)
 
 
 def _fused_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                      *rest, nb, bq, bk, nq, s_true, causal, scale,
+                      *rest, nb, bq, bk, nq, nk, s_true, causal, scale,
                       has_mask, mask_batched, nheads, dropout_p=0.0):
     """One K/V-block visit computes dV, dK partials (VMEM-accumulated
     across the inner q dimension) AND the dQ partial for this k block
@@ -453,8 +542,8 @@ def _fused_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
     def _compute():
-        valid = _block_valid(bq=bq, bk=bk, s_true=s_true, q_start=q_start,
-                             k_start=k_start, causal=causal)
+        valid = _block_valid(bq=bq, bk=bk, nk=nk, s_true=s_true,
+                             q_start=q_start, k_start=k_start, causal=causal)
         for j in range(nb):
             mj = None
             if mask_ref is not None:
@@ -513,16 +602,13 @@ def _fused_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
 
 
-def _flash_bwd(q, k, v, o, lse_l, do, mask, h, causal, scale, bq, bk,
-               s_true, interpret, nb_max=8, dropout_p=0.0, seed=None):
-    """All [B, s, h*d] (seq padded); lse_l [B, h, s, ROW_LANES].
-    Returns dq, dk, dv in the same layout."""
+def _flash_bwd(q, k, v, o, lse_l, do, mask, h, causal, scale, bq, bk, nb,
+               s_true, interpret, dropout_p=0.0, seed=None):
+    """All [B, s, h*d] (seq padded); lse_l [B, h, s, ROW_LANES]; the
+    forward's (bq, bk, nb). Returns dq, dk, dv in the same layout."""
     B, s, H = q.shape
     d = H // h
     has_mask = mask is not None
-    mg = _mask_group(mask, B, h) if has_mask else None
-    bq, bk, nb = _fit_geometry(B, d, q.dtype.itemsize, has_mask, mg,
-                               bq, bk, nb_max)
     nq = s // bq
     nk = s // bk
 
@@ -569,7 +655,7 @@ def _flash_bwd(q, k, v, o, lse_l, do, mask, h, causal, scale, bq, bk,
     with jax.enable_x64(False):
         dq_part, dk, dv = pl.pallas_call(
             functools.partial(_fused_bwd_kernel, nb=nb, bq=bq, bk=bk,
-                              nq=nq, s_true=s_true, causal=causal,
+                              nq=nq, nk=nk, s_true=s_true, causal=causal,
                               scale=scale, has_mask=has_mask,
                               mask_batched=mask_batched, nheads=h,
                               dropout_p=dropout_p),
@@ -598,6 +684,7 @@ def _flash_bwd(q, k, v, o, lse_l, do, mask, h, causal, scale, bq, bk,
                 dimension_semantics=("parallel", "parallel", "parallel",
                                      "arbitrary")),
             interpret=interpret,
+            name="flash_attention_bwd",
         )(*args)
     # one streaming reduce over the f32 k-block partials
     if nk == 1:
@@ -641,9 +728,10 @@ def _xla_ref(q, k, v, causal, scale, mask=None):
 # public API
 # ---------------------------------------------------------------------------
 
-def make_flash_attention(bq=256, bk=256, interpret=False, nb_max=8,
+def make_flash_attention(bq=None, bk=None, interpret=False, nb_max=8,
                          dropout_p=0.0):
-    """Build the custom-vjp flash attention for given block sizes.
+    """Build the custom-vjp flash attention. bq / bk: explicit block sizes
+    (the tests'), None = drawn per call from its shape (`flash_geometry`).
 
     Signature: flash(q, k, v, causal, scale) with [b, s, h, d] inputs,
     and flash_masked(q, k, v, mask, causal, scale) where mask is additive
@@ -655,12 +743,21 @@ def make_flash_attention(bq=256, bk=256, interpret=False, nb_max=8,
     never materialized. The plain entries stay deterministic.
     """
 
-    def _prep(q, k, v, mask):
+    def _geo(x, hk, mask, s_true, dropout):
+        """(bq, bk, nb, s_pad) for operands x in the kernels' layout
+        [B, s, hk * d] and the prepared mask: the same from the forward's
+        unpadded operands and from the backward's residuals."""
+        B, _, H = x.shape
+        return _geometry(
+            B, H // hk, x.dtype.itemsize, mask is not None,
+            None if mask is None else _mask_group(mask.shape[0], B, hk),
+            s_true, bq, bk, nb_max, dropout)
+
+    def _prep(q, k, v, mask, dropout):
         b, s_true, h, d = q.shape
         # transpose-free fast path: head dim is a lane multiple — take
         # [b, s, h*d] views and index heads as lane-blocks on the grid
         fast = d % 128 == 0
-        blk = max(bq, bk)
         if fast:
             B, hk = b, h
             qr = q.reshape(b, s_true, h * d)
@@ -671,10 +768,6 @@ def make_flash_attention(bq=256, bk=256, interpret=False, nb_max=8,
             qr = jnp.swapaxes(q, 1, 2).reshape(B, s_true, d)
             kr = jnp.swapaxes(k, 1, 2).reshape(B, s_true, d)
             vr = jnp.swapaxes(v, 1, 2).reshape(B, s_true, d)
-        qp = _pad_seq(qr, blk, 1)
-        kp = _pad_seq(kr, blk, 1)
-        vp = _pad_seq(vr, blk, 1)
-        mp = None
         if mask is not None:
             mb, mh, sq, sk = mask.shape
             # broadcast query/key dims FIRST: a [b,1,1,sk] key-padding mask
@@ -694,10 +787,14 @@ def make_flash_attention(bq=256, bk=256, interpret=False, nb_max=8,
                 mask = jnp.broadcast_to(
                     mask, (b, h) + mask.shape[2:]
                 ).reshape(b * h, 1, s_true, s_true)
+        bq_, bk_, nb, s_pad = _geo(qr, hk, mask, s_true, dropout)
+        qp, kp, vp = (_pad_seq(x, s_pad, 1) for x in (qr, kr, vr))
+        mp = mask
+        if mask is not None:
             # pad query axis with 0 (rows sliced off); padded keys are
             # excluded by the kernel's s_true column mask
-            mp = _pad_seq(_pad_seq(mask, blk, 2), blk, 3)
-        return qp, kp, vp, mp, (b, h, fast), s_true
+            mp = _pad_seq(_pad_seq(mask, s_pad, 2), s_pad, 3)
+        return qp, kp, vp, mp, (b, h, fast), s_true, (bq_, bk_, nb)
 
     def _unlayout(x, bhf, s_true):
         b, h, fast = bhf
@@ -711,27 +808,24 @@ def make_flash_attention(bq=256, bk=256, interpret=False, nb_max=8,
         # (seed provided); the plain entries on the same build stay
         # deterministic
         dp = dropout_p if seed is not None else 0.0
-        qp, kp, vp, mp, bhf, s_true = _prep(q, k, v, mask)
+        qp, kp, vp, mp, bhf, s_true, geo = _prep(q, k, v, mask, dp > 0.0)
         o, lse_l = _flash_fwd(qp, kp, vp, mp, bhf[1] if bhf[2] else 1,
-                              causal, scale,
-                              min(bq, qp.shape[1]), min(bk, kp.shape[1]),
-                              s_true, interpret, nb_max, dp, seed)
+                              causal, scale, *geo, s_true, interpret, dp,
+                              seed)
         return o, lse_l, qp, kp, vp, mp, bhf, s_true
 
     def _bwd_impl(res_pack, g, mask, causal, scale, dp=0.0, seed=None):
         qp, kp, vp, o, lse_l, bhf, s_true = res_pack
         b, h, fast = bhf
-        blk = max(bq, bk)
+        hk = h if fast else 1
+        geo = _geo(qp, hk, mask, s_true, dp > 0.0)  # the forward's again
         if fast:
             gr = g.reshape(b, s_true, -1)
         else:
             gr = jnp.swapaxes(g, 1, 2).reshape(b * h, s_true, -1)
-        gp = _pad_seq(gr, blk, 1)
-        dq, dk, dv = _flash_bwd(qp, kp, vp, o, lse_l, gp, mask,
-                                h if fast else 1, causal, scale,
-                                min(bq, qp.shape[1]),
-                                min(bk, kp.shape[1]), s_true, interpret,
-                                nb_max, dp, seed)
+        gp = _pad_seq(gr, geo[3], 1)
+        dq, dk, dv = _flash_bwd(qp, kp, vp, o, lse_l, gp, mask, hk, causal,
+                                scale, *geo[:3], s_true, interpret, dp, seed)
         return (_unlayout(dq, bhf, s_true), _unlayout(dk, bhf, s_true),
                 _unlayout(dv, bhf, s_true))
 

@@ -120,6 +120,31 @@ class TestFlashAttentionLowering:
         _lower_tpu(fwd, q, q, q)
 
 
+    @pytest.mark.parametrize("blocks", [None, (512, 1024), (1024, 512)])
+    def test_training_cells_shape(self, blocks):
+        """Both training cells' call, (2, 4096, 16, 128) bf16 causal, at
+        the geometry the rule draws there (PR 33: 1024 x 1024, one slice
+        a step) and at two explicit ones with bq != bk."""
+        from paddle_tpu.ops.pallas.flash_attention import (
+            flash_geometry, make_flash_attention)
+        q = _sds((2, 4096, 16, 128), jnp.bfloat16)
+        if blocks is None:
+            flash = make_flash_attention()
+            assert flash_geometry(q.shape, q.dtype) == (1024, 1024, 1, 4096)
+        else:
+            flash = make_flash_attention(bq=blocks[0], bk=blocks[1])
+            assert flash_geometry(q.shape, q.dtype, bq=blocks[0],
+                                  bk=blocks[1]) == blocks + (2, 4096)
+
+        def fwd(q_, k_, v_):
+            return flash(q_, k_, v_, True, 0.088)
+
+        _lower_tpu(fwd, q, q, q)
+        _lower_tpu(lambda q_, k_, v_: jax.grad(lambda a, b_, c: jnp.sum(
+            fwd(a, b_, c).astype(jnp.float32) ** 2),
+            argnums=(0, 1, 2))(q_, k_, v_), q, q, q)
+
+
 class TestOtherKernelsLowering:
     def test_rms_norm_fwd_bwd(self):
         from paddle_tpu.ops.pallas.rms_norm import make_rms_norm

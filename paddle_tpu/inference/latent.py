@@ -18,9 +18,12 @@ scores the cached rows directly, W_uv and the gate come after the sum.
                  index scores block by block and marks each query's top-k
                  in it (a radix select for the k-th value, no sort).
 
-The four device phases carry `jax.named_scope`s (the name stack reaches
-the profiler's event metadata; docs/observability.md): sparse_index_
-scores, sparse_select, sparse_attend, window_latent_attend.
+Every operation sits under a model phase (`profiler.phase`; the name
+stack reaches the profiler's event metadata; docs/observability.md
+"Device phases"): `attn_proj` the projections before and after the sum,
+`kv_write` the rows and index keys into the pools, `attend` the rest.
+Inside `attend` four plain `jax.named_scope`s split the attention:
+sparse_index_scores, sparse_select, sparse_attend, window_latent_attend.
 
 `decode_selection`, `prefill_selection` and `block_pages` (the index scan
 and the exact selection through a page table) know nothing latent: a
@@ -32,6 +35,7 @@ import jax.numpy as jnp
 from ..ops import latent_attention as la
 from ..ops import sparse_attention as sa
 from ..ops.sparse_attention import SPARSE_COUNTS
+from ..profiler import phase
 from .serving import _rms
 
 KEY_BLOCK_PAGES = 4     # pages a prefill key block gathers
@@ -109,17 +113,18 @@ def _front(eng, W, wset, h, pos_ids, li):
     padding; gate [b, t, H] float32 or None; the indexer's (q^I, k^I, w)
     float32 or None). Products take bf16 operands on the chip, their sums
     and the norms between them are float32."""
-    a = eng.desc.layers[li].attn
-    g = eng.groups[eng.desc.layer_group[li]]
-    cos, sin = eng._rope_of(W, li)
-    cos, sin = cos[pos_ids], sin[pos_ids]
-    x = _rms(h, wset["ln1"], W["eps"])
-    q_n, q_r, row, c_q = la.latent_qkv(x, wset, a, W["eps"], cos, sin)
-    row = jnp.pad(row, [(0, 0)] * 2 + [(0, g.row_pad - g.row_width)])
-    gate = la.head_gate(x, wset["w_gate"]) if a.gate else None
-    ix = sa.index_qkw(x, c_q, wset, a.indexer, cos, sin) \
-        if a.indexer is not None else None
-    return q_n, q_r, row.astype(eng.kv_dtype), gate, ix
+    with phase("attn_proj"):
+        a = eng.desc.layers[li].attn
+        g = eng.groups[eng.desc.layer_group[li]]
+        cos, sin = eng._rope_of(W, li)
+        cos, sin = cos[pos_ids], sin[pos_ids]
+        x = _rms(h, wset["ln1"], W["eps"])
+        q_n, q_r, row, c_q = la.latent_qkv(x, wset, a, W["eps"], cos, sin)
+        row = jnp.pad(row, [(0, 0)] * 2 + [(0, g.row_pad - g.row_width)])
+        gate = la.head_gate(x, wset["w_gate"]) if a.gate else None
+        ix = sa.index_qkw(x, c_q, wset, a.indexer, cos, sin) \
+            if a.indexer is not None else None
+        return q_n, q_r, row.astype(eng.kv_dtype), gate, ix
 
 
 def _gated(o, gate, dtype):
@@ -131,9 +136,10 @@ def _gated(o, gate, dtype):
 def _absorbed_query(eng, wset, q_n, q_r, a, g):
     """The query carried into the latent space, zero over the row's
     padding, in the cache's dtype."""
-    q_abs = la.absorb_query(q_n, q_r, wset["w_uk"], a)
-    pad = [(0, 0)] * (q_abs.ndim - 1) + [(0, g.row_pad - g.row_width)]
-    return jnp.pad(q_abs, pad).astype(eng.kv_dtype)
+    with phase("attn_proj"):
+        q_abs = la.absorb_query(q_n, q_r, wset["w_uk"], a)
+        pad = [(0, 0)] * (q_abs.ndim - 1) + [(0, g.row_pad - g.row_width)]
+        return jnp.pad(q_abs, pad).astype(eng.kv_dtype)
 
 
 def decode_layer(eng, W, wset, h, rows_pool, ix_pool, tab, lens, active, li):
@@ -147,39 +153,48 @@ def decode_layer(eng, W, wset, h, rows_pool, ix_pool, tab, lens, active, li):
     r, scale = a.latent.kv_rank, la.softmax_scale(a)
     q_n, q_r, row, gate, ix = _front(eng, W, wset, h, lens[:, None], li)
     q_abs = _absorbed_query(eng, wset, q_n, q_r, a, g)
-    slots = jnp.where(active, tab[jnp.arange(w), lens // p] * p + lens % p,
-                      g.n_pages * p)
-    rows_pool = _write(rows_pool, slots, row[:, 0])
-    flat = rows_pool.reshape(-1, g.row_pad)
-    n_vis = jnp.where(active, lens + 1, 0)
-    if a.indexer is not None:
-        q_i, k_i, w_i = ix
-        ix_pool = _write(ix_pool, slots, k_i[:, 0])
-        idx, valid, counts = decode_selection(
-            ix_pool, tab, q_i, w_i, n_vis, active, a.indexer, p)
-        with jax.named_scope("sparse_attend"):
-            sel = jnp.take_along_axis(tab, idx // p, axis=1) * p + idx % p
-            o_lat = la.attend_rows(q_abs[:, 0], flat[sel], valid, r, scale)
-    else:
-        with jax.named_scope("window_latent_attend"):
-            # the pages the window of the query at `lens` touches
-            n_ctx = min(mp, g.bound(1))
-            first = jnp.maximum(lens - a.window + 1, 0) // p
-            page_ix = first[:, None] + jnp.arange(n_ctx)[None, :]
-            pages = jnp.take_along_axis(
-                tab, jnp.minimum(page_ix, mp - 1), axis=1)
-            kpos = (page_ix[:, :, None] * p
-                    + jnp.arange(p)[None, None, :]).reshape(w, n_ctx * p)
-            qpos = lens[:, None]
-            valid = (kpos <= qpos) & (kpos > qpos - a.window) \
-                & active[:, None]
-            o_lat = la.attend_rows(
-                q_abs[:, 0], rows_pool[pages].reshape(w, n_ctx * p, -1),
-                valid, r, scale)
-        counts = (jnp.int32(0),) * len(SPARSE_COUNTS)
-    o = la.expand_values(o_lat, wset["w_uv"], a)    # W_uv after the sum
-    return (_gated(o[:, None], gate, eng.kv_dtype), rows_pool, ix_pool,
-            counts)
+    with phase("kv_write"):
+        slots = jnp.where(active,
+                          tab[jnp.arange(w), lens // p] * p + lens % p,
+                          g.n_pages * p)
+        rows_pool = _write(rows_pool, slots, row[:, 0])
+        if a.indexer is not None:
+            ix_pool = _write(ix_pool, slots, ix[1][:, 0])
+    with phase("attend"):
+        flat = rows_pool.reshape(-1, g.row_pad)
+        n_vis = jnp.where(active, lens + 1, 0)
+        if a.indexer is not None:
+            q_i, _, w_i = ix
+            idx, valid, counts = decode_selection(
+                ix_pool, tab, q_i, w_i, n_vis, active, a.indexer, p)
+            with jax.named_scope("sparse_attend"):
+                sel = jnp.take_along_axis(tab, idx // p, axis=1) * p \
+                    + idx % p
+                o_lat = la.attend_rows(q_abs[:, 0], flat[sel], valid, r,
+                                       scale)
+        else:
+            with jax.named_scope("window_latent_attend"):
+                # the pages the window of the query at `lens` touches
+                n_ctx = min(mp, g.bound(1))
+                first = jnp.maximum(lens - a.window + 1, 0) // p
+                page_ix = first[:, None] + jnp.arange(n_ctx)[None, :]
+                pages = jnp.take_along_axis(
+                    tab, jnp.minimum(page_ix, mp - 1), axis=1)
+                kpos = (page_ix[:, :, None] * p
+                        + jnp.arange(p)[None, None, :]).reshape(
+                            w, n_ctx * p)
+                qpos = lens[:, None]
+                valid = (kpos <= qpos) & (kpos > qpos - a.window) \
+                    & active[:, None]
+                o_lat = la.attend_rows(
+                    q_abs[:, 0],
+                    rows_pool[pages].reshape(w, n_ctx * p, -1),
+                    valid, r, scale)
+            counts = (jnp.int32(0),) * len(SPARSE_COUNTS)
+    with phase("attn_proj"):
+        o = la.expand_values(o_lat, wset["w_uv"], a)    # W_uv after the sum
+        return (_gated(o[:, None], gate, eng.kv_dtype), rows_pool, ix_pool,
+                counts)
 
 
 def prefill_layer(eng, W, wset, h, rows_pool, ix_pool, tab, pos, t_end, li):
@@ -192,38 +207,44 @@ def prefill_layer(eng, W, wset, h, rows_pool, ix_pool, tab, pos, t_end, li):
     p, chunk = eng.page_size, pos.shape[0]
     q_n, q_r, row, gate, ix = _front(eng, W, wset, h, pos[None, :], li)
     q_abs = _absorbed_query(eng, wset, q_n, q_r, a, g)
-    slots = jnp.where(pos < t_end, tab[pos // p] * p + pos % p,
-                      g.n_pages * p)
-    rows_pool = _write(rows_pool, slots, row[0])
-    kb = KEY_BLOCK_PAGES * p
-    qpos = pos[:, None]
-    last = jnp.minimum(pos[0] + chunk, t_end) - 1   # last real position
-    if a.indexer is not None:
-        q_i, k_i, w_i = ix
-        ix_pool = _write(ix_pool, slots, k_i[0])
-        hi_blk = last // kb + 1
-        chosen = prefill_selection(ix_pool, tab, q_i[0], w_i[0], qpos,
-                                   hi_blk, a.indexer, p)
+    with phase("kv_write"):
+        slots = jnp.where(pos < t_end, tab[pos // p] * p + pos % p,
+                          g.n_pages * p)
+        rows_pool = _write(rows_pool, slots, row[0])
+        if a.indexer is not None:
+            ix_pool = _write(ix_pool, slots, ix[1][0])
+    with phase("attend"):
+        kb = KEY_BLOCK_PAGES * p
+        qpos = pos[:, None]
+        last = jnp.minimum(pos[0] + chunk, t_end) - 1  # last real position
+        if a.indexer is not None:
+            q_i, _, w_i = ix
+            hi_blk = last // kb + 1
+            chosen = prefill_selection(ix_pool, tab, q_i[0], w_i[0], qpos,
+                                       hi_blk, a.indexer, p)
 
-        def block(j):
-            pages, kpos = block_pages(tab, j, p)
-            sel = jax.lax.dynamic_slice(
-                chosen, (jnp.zeros((), j.dtype), j * kb), (chunk, kb))
-            return rows_pool[pages].reshape(kb, -1), \
-                sel & (kpos[None, :] <= qpos)
+            def block(j):
+                pages, kpos = block_pages(tab, j, p)
+                sel = jax.lax.dynamic_slice(
+                    chosen, (jnp.zeros((), j.dtype), j * kb), (chunk, kb))
+                return rows_pool[pages].reshape(kb, -1), \
+                    sel & (kpos[None, :] <= qpos)
 
-        lo_blk, scope = 0, "sparse_attend"
-    else:
-        def block(j):
-            pages, kpos = block_pages(tab, j, p)
-            return rows_pool[pages].reshape(kb, -1), \
-                (kpos[None, :] <= qpos) & (kpos[None, :] > qpos - a.window)
+            lo_blk, scope = 0, "sparse_attend"
+        else:
+            def block(j):
+                pages, kpos = block_pages(tab, j, p)
+                return rows_pool[pages].reshape(kb, -1), \
+                    (kpos[None, :] <= qpos) \
+                    & (kpos[None, :] > qpos - a.window)
 
-        # from the page the first query's window starts in
-        lo_blk = jnp.maximum(pos[0] - a.window + 1, 0) // kb
-        hi_blk, scope = last // kb + 1, "window_latent_attend"
-    with jax.named_scope(scope):
-        o_lat = la.attend_key_blocks(q_abs[0], block, lo_blk, hi_blk,
-                                     a.latent.kv_rank, la.softmax_scale(a))
-    o = la.expand_values(o_lat[None], wset["w_uv"], a)
-    return _gated(o, gate, eng.kv_dtype), rows_pool, ix_pool
+            # from the page the first query's window starts in
+            lo_blk = jnp.maximum(pos[0] - a.window + 1, 0) // kb
+            hi_blk, scope = last // kb + 1, "window_latent_attend"
+        with jax.named_scope(scope):
+            o_lat = la.attend_key_blocks(
+                q_abs[0], block, lo_blk, hi_blk, a.latent.kv_rank,
+                la.softmax_scale(a))
+    with phase("attn_proj"):
+        o = la.expand_values(o_lat[None], wset["w_uv"], a)
+        return _gated(o, gate, eng.kv_dtype), rows_pool, ix_pool

@@ -51,7 +51,7 @@ from .adapters import AdapterError, UnknownAdapterError
 from .sampling import (GREEDY, NEG, SamplingParams, TokenMaskAutomaton,
                        apply_penalties, fold_keys, select_from_topk,
                        stop_hit)
-from ..profiler import record_counters
+from ..profiler import phase, record_counters
 from . import latent, sparse_heads
 from ..ops.sparse_attention import SPARSE_COUNTS
 from .description import UnsupportedByDescription
@@ -2009,10 +2009,11 @@ class ContinuousBatchingEngine(LLMEngine):
         def prefill(W, ids, k_pages_all, v_pages_all, table, t_start,
                     t_end, toks=None, slot=0, AD=None, aid=None):
             ad = None if AD is None else (AD, aid)
-            h = jnp.take(W["emb"], ids, axis=0).astype(
-                jnp.float32 if self.f32_stream else self.kv_dtype)
-            pos = t_start + jnp.arange(chunk, dtype=jnp.int32)
-            pos_ids = pos[None, :]
+            with phase("embed"):
+                h = jnp.take(W["emb"], ids, axis=0).astype(
+                    jnp.float32 if self.f32_stream else self.kv_dtype)
+                pos = t_start + jnp.arange(chunk, dtype=jnp.int32)
+                pos_ids = pos[None, :]
             new_k, new_v = [], []
             for li, wset in enumerate(W["layers"]):
                 a = self.desc.layers[li].attn
@@ -2039,76 +2040,79 @@ class ContinuousBatchingEngine(LLMEngine):
                     continue
                 q, k, v = self._layer_qkv(W, wset, h, pos_ids, ad=ad_li,
                                           li=li)
-                slots = tab[pos // p] * p + pos % p
-                # padded tail positions (>= the true prompt end) write
-                # NOTHING — scatter-drop, so cached pages stay garbage-
-                # free and shared pages are never touched
-                slots = jnp.where(pos < t_end, slots, oob)
-                vp = v_pages_all[li].reshape(-1, nkv, a.v_dim)
-                vp = vp.at[slots].set(v[0].astype(self.kv_dtype),
-                                      mode="drop")
-                vp = vp.reshape(g.n_pages, p, nkv, a.v_dim)
-                k_pool = k_pages_all[li].shape  # flat or by head
-                kp = k_pages_all[li].reshape((-1,) + k_pool[2:])
-                kp = kp.at[slots].set(
-                    k[0].astype(self.kv_dtype).reshape(
-                        (chunk,) + k_pool[2:]), mode="drop")
-                kp = kp.reshape(k_pool)
-                k_pages_all = _pools_put(k_pages_all, li, kp, new_k)
-                v_pages_all = _pools_put(v_pages_all, li, vp, new_v)
-                # gather this sequence's context back out of the pool:
-                # [pages*p, h_kv, d]; keys past the causal horizon carry
-                # finite garbage and mask to exact zero weight. A full
-                # layer gathers every logical page; a window layer only
-                # the pages the chunk's windows can touch (the ones
-                # behind them are freed and their table entries dead)
-                if a.window is None:
-                    page_ix = jnp.arange(mp, dtype=jnp.int32)
-                    live = None
-                else:
-                    n_ctx = min(mp, g.bound(chunk))
-                    first = jnp.maximum(t_start - a.window + 1, 0) // p
-                    page_ix = first + jnp.arange(n_ctx, dtype=jnp.int32)
-                    live = jnp.repeat(page_ix < mp, p)
-                    page_ix = jnp.minimum(page_ix, mp - 1)
-                n_keys = page_ix.shape[0] * p
-                ck = kp[tab[page_ix]].reshape(n_keys, nkv, a.qk_dim)
-                cv = vp[tab[page_ix]].reshape(n_keys, nkv, a.v_dim)
-                ck = expand_kv_heads(ck, q.shape[2])
-                cv = expand_kv_heads(cv, q.shape[2])
-                logits = jnp.einsum("qhd,khd->hqk", q[0], ck) \
-                    / math.sqrt(a.qk_dim)
-                kpos = (page_ix[:, None] * p + jnp.arange(
-                    p, dtype=jnp.int32)[None, :]).reshape(
-                        n_keys)[None, None, :]
-                qpos = pos[None, :, None]
-                seen = kpos <= qpos
-                if a.window is not None:
-                    seen = seen & (kpos > qpos - a.window) \
-                        & live[None, None, :]
-                logits = jnp.where(seen, logits, -1e30)
-                logits = logits.astype(jnp.float32)
-                if a.sink:
-                    # the learned sink: one more term in the denominator
-                    sk = wset["sink"][:, None, None]
-                    m = jnp.maximum(jnp.max(logits, -1, keepdims=True), sk)
-                    e = jnp.exp(logits - m)
-                    w = (e / (jnp.sum(e, -1, keepdims=True)
-                              + jnp.exp(sk - m))).astype(q.dtype)
-                else:
-                    w = jax.nn.softmax(logits, -1).astype(q.dtype)
-                attn = jnp.einsum("hqk,khd->qhd", w, cv)[None]
+                with phase("kv_write"):
+                    slots = tab[pos // p] * p + pos % p
+                    # padded tail positions (>= the true prompt end) write
+                    # NOTHING — scatter-drop, so cached pages stay garbage-
+                    # free and shared pages are never touched
+                    slots = jnp.where(pos < t_end, slots, oob)
+                    vp = v_pages_all[li].reshape(-1, nkv, a.v_dim)
+                    vp = vp.at[slots].set(v[0].astype(self.kv_dtype),
+                                          mode="drop")
+                    vp = vp.reshape(g.n_pages, p, nkv, a.v_dim)
+                    k_pool = k_pages_all[li].shape  # flat or by head
+                    kp = k_pages_all[li].reshape((-1,) + k_pool[2:])
+                    kp = kp.at[slots].set(
+                        k[0].astype(self.kv_dtype).reshape(
+                            (chunk,) + k_pool[2:]), mode="drop")
+                    kp = kp.reshape(k_pool)
+                    k_pages_all = _pools_put(k_pages_all, li, kp, new_k)
+                    v_pages_all = _pools_put(v_pages_all, li, vp, new_v)
+                with phase("attend"):
+                    # gather this sequence's context back out of the pool:
+                    # [pages*p, h_kv, d]; keys past the causal horizon carry
+                    # finite garbage and mask to exact zero weight. A full
+                    # layer gathers every logical page; a window layer only
+                    # the pages the chunk's windows can touch (the ones
+                    # behind them are freed and their table entries dead)
+                    if a.window is None:
+                        page_ix = jnp.arange(mp, dtype=jnp.int32)
+                        live = None
+                    else:
+                        n_ctx = min(mp, g.bound(chunk))
+                        first = jnp.maximum(t_start - a.window + 1, 0) // p
+                        page_ix = first + jnp.arange(n_ctx, dtype=jnp.int32)
+                        live = jnp.repeat(page_ix < mp, p)
+                        page_ix = jnp.minimum(page_ix, mp - 1)
+                    n_keys = page_ix.shape[0] * p
+                    ck = kp[tab[page_ix]].reshape(n_keys, nkv, a.qk_dim)
+                    cv = vp[tab[page_ix]].reshape(n_keys, nkv, a.v_dim)
+                    ck = expand_kv_heads(ck, q.shape[2])
+                    cv = expand_kv_heads(cv, q.shape[2])
+                    logits = jnp.einsum("qhd,khd->hqk", q[0], ck) \
+                        / math.sqrt(a.qk_dim)
+                    kpos = (page_ix[:, None] * p + jnp.arange(
+                        p, dtype=jnp.int32)[None, :]).reshape(
+                            n_keys)[None, None, :]
+                    qpos = pos[None, :, None]
+                    seen = kpos <= qpos
+                    if a.window is not None:
+                        seen = seen & (kpos > qpos - a.window) \
+                            & live[None, None, :]
+                    logits = jnp.where(seen, logits, -1e30)
+                    logits = logits.astype(jnp.float32)
+                    if a.sink:
+                        # the learned sink: one more term in the denominator
+                        sk = wset["sink"][:, None, None]
+                        m = jnp.maximum(jnp.max(logits, -1, keepdims=True), sk)
+                        e = jnp.exp(logits - m)
+                        w = (e / (jnp.sum(e, -1, keepdims=True)
+                                  + jnp.exp(sk - m))).astype(q.dtype)
+                    else:
+                        w = jax.nn.softmax(logits, -1).astype(q.dtype)
+                    attn = jnp.einsum("hqk,khd->qhd", w, cv)[None]
                 h = self._layer_tail(W, wset, h, attn, ad=ad_li, li=li)
-            h = _rms(h, W["norm"], W["eps"])
-            last = jnp.clip(t_end - 1 - t_start, 0, chunk - 1)
-            h_last = jax.lax.dynamic_index_in_dim(h, last, axis=1)
-            loc = (_mm_f32 if self.f32_stream else _mm)(
-                h_last, W["head"], self.interpret)[:, 0]
-            if toks is None:        # a caller that only compiles it
-                toks = jnp.zeros((self.max_batch,), jnp.int32)
-            first = self._tp_greedy_token(loc)[0].astype(toks.dtype)
-            toks = jnp.where(t_start + chunk >= t_end,
-                             toks.at[slot].set(first), toks)
+            with phase("head"):
+                h = _rms(h, W["norm"], W["eps"])
+                last = jnp.clip(t_end - 1 - t_start, 0, chunk - 1)
+                h_last = jax.lax.dynamic_index_in_dim(h, last, axis=1)
+                loc = (_mm_f32 if self.f32_stream else _mm)(
+                    h_last, W["head"], self.interpret)[:, 0]
+                if toks is None:        # a caller that only compiles it
+                    toks = jnp.zeros((self.max_batch,), jnp.int32)
+                first = self._tp_greedy_token(loc)[0].astype(toks.dtype)
+                toks = jnp.where(t_start + chunk >= t_end,
+                                 toks.at[slot].set(first), toks)
             return (self._gather_logits(loc), toks,
                     _pools_result(k_pages_all, new_k),
                     _pools_result(v_pages_all, new_v))
@@ -2697,27 +2701,33 @@ class ContinuousBatchingEngine(LLMEngine):
         "layer" mode and the no-head fallback materialize + lax.top_k
         (same bits — the fold is selection only)."""
         p = self.page_size
-        h = jnp.take(W["emb"], tok, axis=0).astype(self.kv_dtype)  # [w, H]
-        cos_sel = W["cos"][lens].astype(h.dtype)
-        sin_sel = W["sin"][lens].astype(h.dtype)
-        slots_raw = (tables[jnp.arange(w), lens // p] * p + lens % p)
-        act_i = active.astype(jnp.int32)
+        with phase("embed"):
+            h = jnp.take(W["emb"], tok, axis=0).astype(
+                self.kv_dtype)                                 # [w, H]
+            cos_sel = W["cos"][lens].astype(h.dtype)
+            sin_sel = W["sin"][lens].astype(h.dtype)
+            act_i = active.astype(jnp.int32)
+        # the kernel is ONE device operation under its own name
+        # (`_decode_megakernel`): no phase can look inside it
         h, k_all, v_all, tok_g, maxv, loc = self._mk_walk(
             W, h, k_pages_all, v_pages_all, tables, lens, act_i,
             cos_sel, sin_sel, head_k=topk)
-        new_k, new_v = self._mk_scatter(k_pages_all, v_pages_all,
-                                        k_all, v_all, slots_raw, active)
-        if topk is not None:
-            if tok_g is None:      # "layer" mode / head fold off
+        with phase("kv_write"):
+            slots_raw = (tables[jnp.arange(w), lens // p] * p + lens % p)
+            new_k, new_v = self._mk_scatter(k_pages_all, v_pages_all,
+                                            k_all, v_all, slots_raw, active)
+        with phase("head"):
+            if topk is not None:
+                if tok_g is None:      # "layer" mode / head fold off
+                    hN = _rms(h[:, None], W["norm"], W["eps"])
+                    loc = _mm(hN, W["head"], self.interpret)[:, 0]
+                    maxv, tok_g = self._tp_topk(loc, topk)
+                return maxv, tok_g, new_k, new_v
+            if loc is None:
                 hN = _rms(h[:, None], W["norm"], W["eps"])
                 loc = _mm(hN, W["head"], self.interpret)[:, 0]
-                maxv, tok_g = self._tp_topk(loc, topk)
-            return maxv, tok_g, new_k, new_v
-        if loc is None:
-            hN = _rms(h[:, None], W["norm"], W["eps"])
-            loc = _mm(hN, W["head"], self.interpret)[:, 0]
-            tok_g = self._tp_greedy_token(loc)
-        return self._gather_logits(loc), tok_g, new_k, new_v
+                tok_g = self._tp_greedy_token(loc)
+            return self._gather_logits(loc), tok_g, new_k, new_v
 
     def _cb_decode_math(self, W, tok, k_pages_all, v_pages_all, tables,
                         lens, active, w, ad=None, topk=None,
@@ -2757,9 +2767,10 @@ class ContinuousBatchingEngine(LLMEngine):
         p = self.page_size
         mp = self.pages_per_seq
         layer_group = self.desc.layer_group
-        h = jnp.take(W["emb"], tok[:, None], axis=0).astype(
-            jnp.float32 if self.f32_stream else self.kv_dtype)
-        pos_ids = lens[:, None]
+        with phase("embed"):
+            h = jnp.take(W["emb"], tok[:, None], axis=0).astype(
+                jnp.float32 if self.f32_stream else self.kv_dtype)
+            pos_ids = lens[:, None]
         new_k, new_v = [], []
         for li, wset in enumerate(W["layers"]):
             a = self.desc.layers[li].attn
@@ -2786,40 +2797,43 @@ class ContinuousBatchingEngine(LLMEngine):
                 continue
             q, k, v = self._layer_qkv(W, wset, h, pos_ids, ad=ad_li,
                                       li=li)
-            slots = (tab[jnp.arange(w), lens // p] * p + lens % p)
-            slots = jnp.where(active, slots, oob)
-            vp = v_pages_all[li].reshape(-1, nkv, a.v_dim)
-            vp = vp.at[slots].set(v[:, 0].astype(self.kv_dtype),
-                                  mode="drop")
-            vp = vp.reshape(g.n_pages, p, nkv, a.v_dim)
-            k_pool = k_pages_all[li].shape      # flat or by head
-            kp = k_pages_all[li].reshape((-1,) + k_pool[2:])
-            kp = kp.at[slots].set(
-                k[:, 0].astype(self.kv_dtype).reshape((w,) + k_pool[2:]),
-                mode="drop")
-            kp = kp.reshape(k_pool)
-            k_pages_all = _pools_put(k_pages_all, li, kp, new_k)
-            v_pages_all = _pools_put(v_pages_all, li, vp, new_v)
-            attn = paged_attention(
-                q[:, 0], kp, vp, tab,
-                jnp.where(active, lens + 1, 0),
-                interpret=self.interpret,
-                active=active.astype(jnp.int32),
-                window=a.window, sinks=wset.get("sink"),
-                k_flat=g.k_flat)
+            with phase("kv_write"):
+                slots = (tab[jnp.arange(w), lens // p] * p + lens % p)
+                slots = jnp.where(active, slots, oob)
+                vp = v_pages_all[li].reshape(-1, nkv, a.v_dim)
+                vp = vp.at[slots].set(v[:, 0].astype(self.kv_dtype),
+                                      mode="drop")
+                vp = vp.reshape(g.n_pages, p, nkv, a.v_dim)
+                k_pool = k_pages_all[li].shape      # flat or by head
+                kp = k_pages_all[li].reshape((-1,) + k_pool[2:])
+                kp = kp.at[slots].set(
+                    k[:, 0].astype(self.kv_dtype).reshape((w,) + k_pool[2:]),
+                    mode="drop")
+                kp = kp.reshape(k_pool)
+                k_pages_all = _pools_put(k_pages_all, li, kp, new_k)
+                v_pages_all = _pools_put(v_pages_all, li, vp, new_v)
+            with phase("attend"):
+                attn = paged_attention(
+                    q[:, 0], kp, vp, tab,
+                    jnp.where(active, lens + 1, 0),
+                    interpret=self.interpret,
+                    active=active.astype(jnp.int32),
+                    window=a.window, sinks=wset.get("sink"),
+                    k_flat=g.k_flat)
             h = self._layer_tail(W, wset, h, attn[:, None], ad=ad_li,
                                  li=li, expert_rows=expert_rows)
-        h = _rms(h, W["norm"], W["eps"])
-        loc = (_mm_f32 if self.f32_stream else _mm)(
-            h, W["head"], self.interpret)[:, 0]
-        if topk is not None:
-            topv, topi = self._tp_topk(loc, topk)
-            return (topv, topi,
+        with phase("head"):
+            h = _rms(h, W["norm"], W["eps"])
+            loc = (_mm_f32 if self.f32_stream else _mm)(
+                h, W["head"], self.interpret)[:, 0]
+            if topk is not None:
+                topv, topi = self._tp_topk(loc, topk)
+                return (topv, topi,
+                        _pools_result(k_pages_all, new_k),
+                        _pools_result(v_pages_all, new_v))
+            return (self._gather_logits(loc), self._tp_greedy_token(loc),
                     _pools_result(k_pages_all, new_k),
                     _pools_result(v_pages_all, new_v))
-        return (self._gather_logits(loc), self._tp_greedy_token(loc),
-                _pools_result(k_pages_all, new_k),
-                _pools_result(v_pages_all, new_v))
 
     def _cb_spec_verify_math(self, W, feed, k_pages_all, v_pages_all,
                              tables, lens, active, rem, dlen, w,
@@ -2969,8 +2983,9 @@ class ContinuousBatchingEngine(LLMEngine):
         sK = self.sample_k
 
         def put(tok, tok_g, active):
-            return tok.at[:w].set(
-                jnp.where(active, tok_g.astype(tok.dtype), tok[:w]))
+            with phase("head"):     # the write into the token vector
+                return tok.at[:w].set(
+                    jnp.where(active, tok_g.astype(tok.dtype), tok[:w]))
 
         if self._counted:
             # a description with routed experts or an indexer: the same
@@ -2987,18 +3002,21 @@ class ContinuousBatchingEngine(LLMEngine):
                 if route is None:
                     route = self._route_zeros()
                 route = dict(route)
+                # the counters are by-products of the phase they count
                 if rows:
-                    rows = jnp.stack(rows)      # [expert layers, held]
-                    route.update(
-                        rows=route["rows"] + rows,
-                        touched=route["touched"] + jnp.sum(
-                            rows > 0, axis=1, dtype=jnp.int32),
-                        steps=route["steps"] + 1)
-                for name, add in zip(SPARSE_COUNTS, zip(*sparse)):
-                    low = route[name][1] + sum(add)
-                    route[name] = jnp.stack(
-                        [route[name][0] + (low >> 24),
-                         low & ((1 << 24) - 1)]).astype(jnp.int32)
+                    with phase("ffn"):
+                        rows = jnp.stack(rows)  # [expert layers, held]
+                        route.update(
+                            rows=route["rows"] + rows,
+                            touched=route["touched"] + jnp.sum(
+                                rows > 0, axis=1, dtype=jnp.int32),
+                            steps=route["steps"] + 1)
+                with phase("attend"):
+                    for name, add in zip(SPARSE_COUNTS, zip(*sparse)):
+                        low = route[name][1] + sum(add)
+                        route[name] = jnp.stack(
+                            [route[name][0] + (low >> 24),
+                             low & ((1 << 24) - 1)]).astype(jnp.int32)
                 if fold:
                     return out + (route,)
                 logits, tok_g, kps, vps = out

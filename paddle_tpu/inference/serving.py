@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from ..failsafe import fault_point
-from ..profiler import RecordEvent
+from ..profiler import RecordEvent, phase
 from ..tensor.tensor import Tensor
 from ..autograd import tape
 from ..models.llama import _rope_cache
@@ -741,52 +741,53 @@ class LLMEngine:
         # how many leading dims rotate and on which base, the value
         # scale); the modes that serve plain descriptions only leave it
         # at 0, every layer being the same.
-        a = self.desc.layers[li].attn
-        cos, sin = self._rope_of(W, li)
-        b, t, H = h.shape
-        x = _rms(h, wset["ln1"], W["eps"])
-        if self.f32_stream:         # operands in the cache's dtype
-            x = x.astype(self.kv_dtype)
-        if "wqkv" in wset:
-            # one fused projection, columns q | k | v: a layout of the
-            # weights, the mathematics is three projections
-            qkv = _mm(x, wset["wqkv"], self.interpret)
-            nq = a.n_heads * a.qk_dim
-            nk = a.n_kv_heads * a.qk_dim
-            q, k, v = qkv[..., :nq], qkv[..., nq:nq + nk], \
-                qkv[..., nq + nk:]
-        else:
-            q = _mm(x, wset["wq"], self.interpret)
-            k = _mm(x, wset["wk"], self.interpret)
-            v = _mm(x, wset["wv"], self.interpret)
-        if ad is not None:
-            from .adapters import lora_apply
-            q = lora_apply(q, x, "wq", ad)
-            k = lora_apply(k, x, "wk", ad)
-            v = lora_apply(v, x, "wv", ad)
-        q = q.reshape(b, t, -1, a.qk_dim)
-        k = k.reshape(b, t, -1, a.qk_dim)
-        v = v.reshape(b, t, -1, a.v_dim)
-        if a.qk_norm:               # every head, before the rotation
-            q = _rms(q, wset["q_hn"], W["eps"])
-            k = _rms(k, wset["k_hn"], W["eps"])
-        if a.value_scale != 1.0:
-            v = v * jnp.asarray(a.value_scale, v.dtype)
-        # GQA: k/v STAY at nh_kv heads — the paged cache stores the
-        # checkpoint's kv width (1/rep the HBM of an expanded cache) and
-        # the decode kernel maps q head i -> kv head i // rep natively
-        c = cos[pos_ids][..., None, :].astype(q.dtype)
-        s = sin[pos_ids][..., None, :].astype(q.dtype)
-        d2 = a.rope_dim // 2
+        with phase("attn_proj"):
+            a = self.desc.layers[li].attn
+            cos, sin = self._rope_of(W, li)
+            b, t, H = h.shape
+            x = _rms(h, wset["ln1"], W["eps"])
+            if self.f32_stream:         # operands in the cache's dtype
+                x = x.astype(self.kv_dtype)
+            if "wqkv" in wset:
+                # one fused projection, columns q | k | v: a layout of the
+                # weights, the mathematics is three projections
+                qkv = _mm(x, wset["wqkv"], self.interpret)
+                nq = a.n_heads * a.qk_dim
+                nk = a.n_kv_heads * a.qk_dim
+                q, k, v = qkv[..., :nq], qkv[..., nq:nq + nk], \
+                    qkv[..., nq + nk:]
+            else:
+                q = _mm(x, wset["wq"], self.interpret)
+                k = _mm(x, wset["wk"], self.interpret)
+                v = _mm(x, wset["wv"], self.interpret)
+            if ad is not None:
+                from .adapters import lora_apply
+                q = lora_apply(q, x, "wq", ad)
+                k = lora_apply(k, x, "wk", ad)
+                v = lora_apply(v, x, "wv", ad)
+            q = q.reshape(b, t, -1, a.qk_dim)
+            k = k.reshape(b, t, -1, a.qk_dim)
+            v = v.reshape(b, t, -1, a.v_dim)
+            if a.qk_norm:               # every head, before the rotation
+                q = _rms(q, wset["q_hn"], W["eps"])
+                k = _rms(k, wset["k_hn"], W["eps"])
+            if a.value_scale != 1.0:
+                v = v * jnp.asarray(a.value_scale, v.dtype)
+            # GQA: k/v STAY at nh_kv heads — the paged cache stores the
+            # checkpoint's kv width (1/rep the HBM of an expanded cache) and
+            # the decode kernel maps q head i -> kv head i // rep natively
+            c = cos[pos_ids][..., None, :].astype(q.dtype)
+            s = sin[pos_ids][..., None, :].astype(q.dtype)
+            d2 = a.rope_dim // 2
 
-        def rope(x_):
-            x1, x2 = x_[..., :d2], x_[..., d2:a.rope_dim]
-            out = [x1 * c - x2 * s, x2 * c + x1 * s]
-            if a.rope_dim < a.qk_dim:       # partial rotary: the rest
-                out.append(x_[..., a.rope_dim:])        # passes through
-            return jnp.concatenate(out, -1)
+            def rope(x_):
+                x1, x2 = x_[..., :d2], x_[..., d2:a.rope_dim]
+                out = [x1 * c - x2 * s, x2 * c + x1 * s]
+                if a.rope_dim < a.qk_dim:       # partial rotary: the rest
+                    out.append(x_[..., a.rope_dim:])        # passes through
+                return jnp.concatenate(out, -1)
 
-        return rope(q), rope(k), v
+            return rope(q), rope(k), v
 
     def _layer_tail(self, W, wset, h, attn_out, ad=None, li=0,
                     expert_rows=None):
@@ -808,39 +809,43 @@ class LLMEngine:
         b, t = attn_out.shape[:2]
         # the two products whose results join the residual stream
         mm_out = _mm_f32 if self.f32_stream else _mm
-        attn_out = self._tp_gather_heads(attn_out)
-        o = mm_out(attn_out.reshape(b, t, -1), wset["wo"], self.interpret)
-        o = self._tp_reduce(o)
-        h = h + o
-        x = _rms(h, wset["ln2"], W["eps"])
-        ffn = self.desc.layers[li].ffn
-        if ffn.kind == "experts":
-            y, rows = routed_experts(
-                x.reshape(b * t, -1), wset["router"],
-                wset.get("router_bias"), wset["w_gu"], wset["w_d"],
-                ffn.held, ffn.top_k, interpret=self.interpret,
-                score=ffn.score)
-            if expert_rows is not None:
-                expert_rows.append(rows)
-            if ffn.shared_width:    # the shared expert, on every token
-                y = y + la.swiglu(x.reshape(b * t, -1), wset["ws_g"],
-                                  wset["ws_u"], wset["ws_d"])
-            return h + y.reshape(b, t, -1)
-        if self.f32_stream:
-            x = x.astype(self.kv_dtype)
-        g = _mm(x, wset["wg"], self.interpret)
-        u = _mm(x, wset["wu"], self.interpret)
-        if ad is not None:
-            from .adapters import lora_apply
-            g = lora_apply(g, x, "wg", ad)
-            u = lora_apply(u, x, "wu", ad)
-        act = jax.nn.silu(g.astype(jnp.float32)).astype(g.dtype) * u
-        act = self._tp_gather_cols(act)
-        d = mm_out(act, wset["wd"], self.interpret)
-        if ad is not None:
-            from .adapters import lora_apply
-            d = lora_apply(d, act, "wd", ad)
-        return h + self._tp_reduce(d)
+        with phase("attn_proj"):   # the output projection
+            attn_out = self._tp_gather_heads(attn_out)
+            o = mm_out(attn_out.reshape(b, t, -1), wset["wo"], self.interpret)
+            o = self._tp_reduce(o)
+            h = h + o
+        with phase("ffn"):
+            x = _rms(h, wset["ln2"], W["eps"])
+            ffn = self.desc.layers[li].ffn
+            if ffn.kind == "experts":
+                y, rows = routed_experts(
+                    x.reshape(b * t, -1), wset["router"],
+                    wset.get("router_bias"), wset["w_gu"], wset["w_d"],
+                    ffn.held, ffn.top_k, interpret=self.interpret,
+                    score=ffn.score)
+                if expert_rows is not None:
+                    expert_rows.append(rows)
+                if ffn.shared_width:    # the shared expert, on every token
+                    with jax.named_scope("shared_expert"):
+                        y = y + la.swiglu(
+                            x.reshape(b * t, -1), wset["ws_g"],
+                            wset["ws_u"], wset["ws_d"])
+                return h + y.reshape(b, t, -1)
+            if self.f32_stream:
+                x = x.astype(self.kv_dtype)
+            g = _mm(x, wset["wg"], self.interpret)
+            u = _mm(x, wset["wu"], self.interpret)
+            if ad is not None:
+                from .adapters import lora_apply
+                g = lora_apply(g, x, "wg", ad)
+                u = lora_apply(u, x, "wu", ad)
+            act = jax.nn.silu(g.astype(jnp.float32)).astype(g.dtype) * u
+            act = self._tp_gather_cols(act)
+            d = mm_out(act, wset["wd"], self.interpret)
+            if ad is not None:
+                from .adapters import lora_apply
+                d = lora_apply(d, act, "wd", ad)
+            return h + self._tp_reduce(d)
 
     # -- prefill ------------------------------------------------------------
     def _build_prefill(self, t_pad):

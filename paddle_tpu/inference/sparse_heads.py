@@ -13,7 +13,8 @@ shapes, docs/serving.md "Per-head groups with index keys" the design).
                  softmax): no tensor against all `pages_per_seq` pages
                  exists.
 
-The index scan and the selection are inference/latent.py's, the scopes
+The index scan and the selection are inference/latent.py's, the model
+phases (`attn_proj`, `kv_write`, `attend`), the scopes inside `attend`
 (sparse_index_scores, sparse_select, sparse_attend) and the counters
 (`SPARSE_COUNTS`) the latent layers' own, so one set of readers serves
 both layer kinds.
@@ -22,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import sparse_attention as sa
+from ..profiler import phase
 from .latent import (KEY_BLOCK_PAGES, _write, block_pages, decode_selection,
                      prefill_selection)
 from .serving import _rms
@@ -35,12 +37,14 @@ def _front(eng, W, wset, h, pos_ids, li):
     a = eng.desc.layers[li].attn
     g = eng.groups[eng.desc.layer_group[li]]
     q, k, v = eng._layer_qkv(W, wset, h, pos_ids, li=li)
-    row = jnp.pad(sa.kv_row(k, v),
-                  [(0, 0)] * 2 + [(0, g.row_pad - g.row_width)])
-    cos, sin = eng._rope_of(W, li, indexer=True)
-    x = _rms(h, wset["ln1"], W["eps"])
-    ix = sa.index_qkw(x, x, wset, a.indexer, cos[pos_ids], sin[pos_ids])
-    return q, row.astype(eng.kv_dtype), ix
+    with phase("attn_proj"):
+        row = jnp.pad(sa.kv_row(k, v),
+                      [(0, 0)] * 2 + [(0, g.row_pad - g.row_width)])
+        cos, sin = eng._rope_of(W, li, indexer=True)
+        x = _rms(h, wset["ln1"], W["eps"])
+        ix = sa.index_qkw(x, x, wset, a.indexer, cos[pos_ids],
+                          sin[pos_ids])
+        return q, row.astype(eng.kv_dtype), ix
 
 
 def decode_layer(eng, W, wset, h, rows_pool, ix_pool, tab, lens, active, li):
@@ -51,18 +55,21 @@ def decode_layer(eng, W, wset, h, rows_pool, ix_pool, tab, lens, active, li):
     g = eng.groups[eng.desc.layer_group[li]]
     p, w = eng.page_size, lens.shape[0]
     q, row, (q_i, k_i, w_i) = _front(eng, W, wset, h, lens[:, None], li)
-    slots = jnp.where(active, tab[jnp.arange(w), lens // p] * p + lens % p,
-                      g.n_pages * p)
-    rows_pool = _write(rows_pool, slots, row[:, 0])
-    ix_pool = _write(ix_pool, slots, k_i[:, 0])
-    idx, valid, counts = decode_selection(
-        ix_pool, tab, q_i, w_i, jnp.where(active, lens + 1, 0), active,
-        a.indexer, p)
-    with jax.named_scope("sparse_attend"):
-        sel = jnp.take_along_axis(tab, idx // p, axis=1) * p + idx % p
-        o = sa.attend_selected(
-            q[:, 0], rows_pool.reshape(-1, g.row_pad)[sel], valid, a)
-    return o[:, None].astype(eng.kv_dtype), rows_pool, ix_pool, counts
+    with phase("kv_write"):
+        slots = jnp.where(active,
+                          tab[jnp.arange(w), lens // p] * p + lens % p,
+                          g.n_pages * p)
+        rows_pool = _write(rows_pool, slots, row[:, 0])
+        ix_pool = _write(ix_pool, slots, k_i[:, 0])
+    with phase("attend"):
+        idx, valid, counts = decode_selection(
+            ix_pool, tab, q_i, w_i, jnp.where(active, lens + 1, 0), active,
+            a.indexer, p)
+        with jax.named_scope("sparse_attend"):
+            sel = jnp.take_along_axis(tab, idx // p, axis=1) * p + idx % p
+            o = sa.attend_selected(
+                q[:, 0], rows_pool.reshape(-1, g.row_pad)[sel], valid, a)
+        return o[:, None].astype(eng.kv_dtype), rows_pool, ix_pool, counts
 
 
 def prefill_layer(eng, W, wset, h, rows_pool, ix_pool, tab, pos, t_end, li):
@@ -74,23 +81,25 @@ def prefill_layer(eng, W, wset, h, rows_pool, ix_pool, tab, pos, t_end, li):
     g = eng.groups[eng.desc.layer_group[li]]
     p, chunk = eng.page_size, pos.shape[0]
     q, row, (q_i, k_i, w_i) = _front(eng, W, wset, h, pos[None, :], li)
-    slots = jnp.where(pos < t_end, tab[pos // p] * p + pos % p,
-                      g.n_pages * p)
-    rows_pool = _write(rows_pool, slots, row[0])
-    ix_pool = _write(ix_pool, slots, k_i[0])
-    kb = KEY_BLOCK_PAGES * p
-    qpos = pos[:, None]
-    hi_blk = (jnp.minimum(pos[0] + chunk, t_end) - 1) // kb + 1
-    chosen = prefill_selection(ix_pool, tab, q_i[0], w_i[0], qpos, hi_blk,
-                               a.indexer, p)
+    with phase("kv_write"):
+        slots = jnp.where(pos < t_end, tab[pos // p] * p + pos % p,
+                          g.n_pages * p)
+        rows_pool = _write(rows_pool, slots, row[0])
+        ix_pool = _write(ix_pool, slots, k_i[0])
+    with phase("attend"):
+        kb = KEY_BLOCK_PAGES * p
+        qpos = pos[:, None]
+        hi_blk = (jnp.minimum(pos[0] + chunk, t_end) - 1) // kb + 1
+        chosen = prefill_selection(ix_pool, tab, q_i[0], w_i[0], qpos,
+                                   hi_blk, a.indexer, p)
 
-    def block(j):
-        pages, kpos = block_pages(tab, j, p)
-        sel = jax.lax.dynamic_slice(
-            chosen, (jnp.zeros((), j.dtype), j * kb), (chunk, kb))
-        return rows_pool[pages].reshape(kb, -1), \
-            sel & (kpos[None, :] <= qpos)
+        def block(j):
+            pages, kpos = block_pages(tab, j, p)
+            sel = jax.lax.dynamic_slice(
+                chosen, (jnp.zeros((), j.dtype), j * kb), (chunk, kb))
+            return rows_pool[pages].reshape(kb, -1), \
+                sel & (kpos[None, :] <= qpos)
 
-    with jax.named_scope("sparse_attend"):
-        o = sa.attend_kv_blocks(q[0], block, 0, hi_blk, a)
-    return o[None].astype(eng.kv_dtype), rows_pool, ix_pool
+        with jax.named_scope("sparse_attend"):
+            o = sa.attend_kv_blocks(q[0], block, 0, hi_blk, a)
+        return o[None].astype(eng.kv_dtype), rows_pool, ix_pool

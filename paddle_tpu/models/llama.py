@@ -19,6 +19,7 @@ from ..nn.layer.container import LayerList
 from ..nn.layer.norm import RMSNorm
 from ..nn import functional as F
 from ..ops import apply
+from ..profiler import phase
 from ..tensor.tensor import Tensor
 from ..tensor import manipulation as M
 from ..distributed.fleet.meta_parallel import (
@@ -134,56 +135,59 @@ class LlamaAttention(Layer):
     def forward(self, hidden_states):
         from ..distributed.mesh import in_spmd_region
         b = hidden_states.shape[0]
-        if self.sequence_parallel:
-            from ..distributed.fleet.utils.sequence_parallel_utils import (
-                all_gather_sp)
-            hidden_states = all_gather_sp(hidden_states)
-        q = self.q_proj(hidden_states)
-        k = self.k_proj(hidden_states)
-        v = self.v_proj(hidden_states)
-        # under Megatron-SP the projections GATHERED the sequence: q/k/v
-        # carry the full (sep-local) sequence even though hidden_states
-        # arrived sequence-sharded over 'model' — derive s from q
-        s = q.shape[1]
-        cos, sin = self._cos, self._sin
-        hd = self.head_dim
-        # context parallelism: activations arrive sequence-sharded over
-        # 'sep'; rope positions are GLOBAL (rank offset), attention runs
-        # the KV-rotating ring (parallel_layers/ring_attention.py)
-        sp = in_spmd_region("sep")
+        with phase("attn_proj"):
+            if self.sequence_parallel:
+                from ..distributed.fleet.utils.sequence_parallel_utils import (
+                    all_gather_sp)
+                hidden_states = all_gather_sp(hidden_states)
+            q = self.q_proj(hidden_states)
+            k = self.k_proj(hidden_states)
+            v = self.v_proj(hidden_states)
+            # under Megatron-SP the projections GATHERED the sequence: q/k/v
+            # carry the full (sep-local) sequence even though hidden_states
+            # arrived sequence-sharded over 'model' — derive s from q
+            s = q.shape[1]
+            cos, sin = self._cos, self._sin
+            hd = self.head_dim
+            # context parallelism: activations arrive sequence-sharded over
+            # 'sep'; rope positions are GLOBAL (rank offset), attention runs
+            # the KV-rotating ring (parallel_layers/ring_attention.py)
+            sp = in_spmd_region("sep")
 
-        def rotary(qa, ka, va):
-            import jax.lax as lax
-            # per-tensor head counts: under GQA k/v carry fewer heads
-            qa = qa.reshape(b, s, qa.shape[-1] // hd, hd)
-            ka = ka.reshape(b, s, ka.shape[-1] // hd, hd)
-            va = va.reshape(b, s, va.shape[-1] // hd, hd)
-            if sp:
-                from jax.lax import axis_size as _axis_size
-                n_sep = _axis_size("sep")
-                if s * n_sep > cos.shape[0]:
-                    raise ValueError(
-                        f"global sequence {s * n_sep} (local {s} x sep "
-                        f"{n_sep}) exceeds max_position_embeddings "
-                        f"{cos.shape[0]} — dynamic_slice would silently "
-                        f"clamp rotary positions")
-                off = lax.axis_index("sep") * s
-                c = lax.dynamic_slice_in_dim(cos, off, s, axis=0)
-                sn = lax.dynamic_slice_in_dim(sin, off, s, axis=0)
-            else:
-                c, sn = cos[:s], sin[:s]
-            qa = apply_rotary(qa, c.astype(qa.dtype), sn.astype(qa.dtype))
-            ka = apply_rotary(ka, c.astype(ka.dtype), sn.astype(ka.dtype))
-            return qa, ka, va
+            def rotary(qa, ka, va):
+                import jax.lax as lax
+                # per-tensor head counts: under GQA k/v carry fewer heads
+                qa = qa.reshape(b, s, qa.shape[-1] // hd, hd)
+                ka = ka.reshape(b, s, ka.shape[-1] // hd, hd)
+                va = va.reshape(b, s, va.shape[-1] // hd, hd)
+                if sp:
+                    from jax.lax import axis_size as _axis_size
+                    n_sep = _axis_size("sep")
+                    if s * n_sep > cos.shape[0]:
+                        raise ValueError(
+                            f"global sequence {s * n_sep} (local {s} x sep "
+                            f"{n_sep}) exceeds max_position_embeddings "
+                            f"{cos.shape[0]} — dynamic_slice would silently "
+                            f"clamp rotary positions")
+                    off = lax.axis_index("sep") * s
+                    c = lax.dynamic_slice_in_dim(cos, off, s, axis=0)
+                    sn = lax.dynamic_slice_in_dim(sin, off, s, axis=0)
+                else:
+                    c, sn = cos[:s], sin[:s]
+                qa = apply_rotary(qa, c.astype(qa.dtype), sn.astype(qa.dtype))
+                ka = apply_rotary(ka, c.astype(ka.dtype), sn.astype(ka.dtype))
+                return qa, ka, va
 
-        q, k, v = apply(rotary, q, k, v, n_outputs=3, name="rotary_qkv")
+            q, k, v = apply(rotary, q, k, v, n_outputs=3, name="rotary_qkv")
         # RingFlashAttention self-dispatches: KV-rotating ring when 'sep'
         # is live, plain sdpa (Pallas flash on TPU) otherwise
         from ..distributed.fleet.meta_parallel.parallel_layers \
             .ring_attention import RingFlashAttention
-        out = RingFlashAttention("sep", causal=True)(q, k, v)
-        out = M.reshape(out, [b, s, -1])
-        return self.o_proj(out)
+        with phase("attend"):
+            out = RingFlashAttention("sep", causal=True)(q, k, v)
+        with phase("attn_proj"):
+            out = M.reshape(out, [b, s, -1])
+            return self.o_proj(out)
 
 
 class LlamaMLP(Layer):
@@ -232,14 +236,19 @@ class LlamaDecoderLayer(Layer):
                 self.post_attention_layernorm.weight)
 
     def forward(self, hidden_states):
+        # model phases (profiler.PHASES): the attention opens its own
+        # around the projections and the kernel
         residual = hidden_states
-        h = self.input_layernorm(hidden_states)
+        with phase("attn_proj"):
+            h = self.input_layernorm(hidden_states)
         h = self.self_attn(h)
-        h = residual + h
-        residual = h
-        h2 = self.post_attention_layernorm(h)
-        h2 = self.mlp(h2)
-        return residual + h2
+        with phase("attn_proj"):
+            h = residual + h
+        with phase("ffn"):
+            residual = h
+            h2 = self.post_attention_layernorm(h)
+            h2 = self.mlp(h2)
+            return residual + h2
 
 
 class LlamaModel(Layer):

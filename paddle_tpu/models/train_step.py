@@ -39,6 +39,7 @@ from jax.sharding import PartitionSpec as P, NamedSharding
 
 from ..autograd import tape
 from ..framework import random as frnd
+from ..profiler import phase
 from ..tensor.tensor import Tensor
 from ..distributed.mesh import spmd_axes
 from ..distributed.comm_compress import resolve_chunk as _resolve_chunk
@@ -746,7 +747,8 @@ class SpmdTrainer:
             return [self._ungather_outer(c, i) for i, c in enumerate(outer)]
 
         def apply_embed(outer, ids):
-            with _Swap(outer_tensors, materialize_outer(outer)), \
+            with phase("embed"), \
+                    _Swap(outer_tensors, materialize_outer(outer)), \
                     tape.no_grad():
                 return embed(Tensor(ids)).data
 
@@ -780,7 +782,8 @@ class SpmdTrainer:
             ignore_index = getattr(ce_obj, "ignore_index", -100)
 
             def apply_tail_loss(outer, h, labels):
-                with _Swap(outer_tensors, materialize_outer(outer)), \
+                with phase("loss"), \
+                        _Swap(outer_tensors, materialize_outer(outer)), \
                         tape.no_grad():
                     if sp_active:
                         # tail is replicated computation: gather the
@@ -806,7 +809,8 @@ class SpmdTrainer:
                     return total / jnp.float32(flat.shape[0])
         else:
             def apply_tail_loss(outer, h, labels):
-                with _Swap(outer_tensors, materialize_outer(outer)), \
+                with phase("loss"), \
+                        _Swap(outer_tensors, materialize_outer(outer)), \
                         tape.no_grad():
                     if sp_active:
                         h = sp_gather_raw(h)
@@ -914,19 +918,21 @@ class SpmdTrainer:
                     # mesh-independent canonical moment contract
                     loss = _untied_psum("pipe")(acc / M)
                 # batch-mean across data/sharding (+ sequence) ranks
-                for ax in batch_axes + sep_axes:
-                    loss = lax.pmean(loss, ax)
-                if "model" in axis_names and mesh.shape["model"] > 1:
-                    # value-neutral re-share of the (already replicated)
-                    # loss that DIVIDES the cotangent by the tp degree:
-                    # /M then identity-transpose psum. (A plain pmean here
-                    # is gradient-NEUTRAL: its internal tied psum
-                    # multiplies the seed back by M.) This cancels the one
-                    # tied psum inside the CE completion, making grads —
-                    # and Adam moments — mesh-independent (the canonical
-                    # checkpoint contract).
-                    loss = _untied_psum("model")(
-                        loss / mesh.shape["model"])
+                with phase("loss"):
+                    for ax in batch_axes + sep_axes:
+                        loss = lax.pmean(loss, ax)
+                    if "model" in axis_names and mesh.shape["model"] > 1:
+                        # value-neutral re-share of the (already
+                        # replicated) loss that DIVIDES the cotangent by
+                        # the tp degree: /M then identity-transpose psum.
+                        # (A plain pmean here is gradient-NEUTRAL: its
+                        # internal tied psum multiplies the seed back by
+                        # M.) This cancels the one tied psum inside the CE
+                        # completion, making grads — and Adam moments —
+                        # mesh-independent (the canonical checkpoint
+                        # contract).
+                        loss = _untied_psum("model")(
+                            loss / mesh.shape["model"])
                 return loss
 
         def _adamw_core(pl, gl, st, step, lr):
@@ -950,28 +956,32 @@ class SpmdTrainer:
             shape = p.shape
             n = int(np.prod(shape))
             pad = (-n) % S_shard
-            gf = g.reshape(-1).astype(jnp.float32)
-            if pad:
-                gf = jnp.concatenate([gf, jnp.zeros(pad, jnp.float32)])
-            pf = p.reshape(-1).astype(jnp.float32)
-            if pad:
-                pf = jnp.concatenate([pf, jnp.zeros(pad, jnp.float32)])
+            with phase("optimizer"):
+                gf = g.reshape(-1).astype(jnp.float32)
+                if pad:
+                    gf = jnp.concatenate([gf, jnp.zeros(pad, jnp.float32)])
+                pf = p.reshape(-1).astype(jnp.float32)
+                if pad:
+                    pf = jnp.concatenate([pf, jnp.zeros(pad, jnp.float32)])
             err = None
             if S_shard > 1:
-                chunk = gf.shape[0] // S_shard
-                gl, err = scatter(gf)
-                r = lax.axis_index("sharding")
-                pl = lax.dynamic_slice_in_dim(pf, r * chunk, chunk)
-            else:
-                gl, pl = gf, pf
-            pl, stn = _adamw_core(pl, gl, st, step, lr)
-            if S_shard > 1:
-                pf = lax.all_gather(pl, "sharding", axis=0, tiled=True)
-            else:
-                pf = pl
-            if pad:
-                pf = pf[:n]
-            return pf.reshape(shape).astype(p.dtype), stn, err
+                with phase("grad_sync"):    # the sum, reduced to its owner
+                    gl, err = scatter(gf)
+            with phase("optimizer"):
+                if S_shard > 1:
+                    chunk = gf.shape[0] // S_shard
+                    r = lax.axis_index("sharding")
+                    pl = lax.dynamic_slice_in_dim(pf, r * chunk, chunk)
+                else:
+                    gl, pl = gf, pf
+                pl, stn = _adamw_core(pl, gl, st, step, lr)
+                if S_shard > 1:
+                    pf = lax.all_gather(pl, "sharding", axis=0, tiled=True)
+                else:
+                    pf = pl
+                if pad:
+                    pf = pf[:n]
+                return pf.reshape(shape).astype(p.dtype), stn, err
 
         def adamw_update12(p, g, st, step, lr):
             """stage 1/2: p is the full local block; g is psum'd over 'data'
@@ -989,9 +999,10 @@ class SpmdTrainer:
             """stage 3: p IS the owned chunk; g arrived reduce-scattered by
             the AD transpose of the gather-on-use all_gather. Elementwise
             update, nothing re-gathered (ref: group_sharded_stage3.py:486)."""
-            pl, stn = _adamw_core(p.astype(jnp.float32),
-                                  g.astype(jnp.float32), st, step, lr)
-            return pl.astype(p.dtype), stn
+            with phase("optimizer"):
+                pl, stn = _adamw_core(p.astype(jnp.float32),
+                                      g.astype(jnp.float32), st, step, lr)
+                return pl.astype(p.dtype), stn
 
         adamw_update = adamw_update3 if stage3 else adamw_update12
 
@@ -1010,18 +1021,19 @@ class SpmdTrainer:
                 are identical across A's ranks — next step every rank
                 feeds them back, so the psum over A would scale them by
                 |A| without the division)."""
-                v = g.astype(jnp.float32) + ef
-                err_tot = jnp.zeros(v.shape, jnp.float32)
-                out, repl = v, 1
-                for ax in data_axes + sep_axes:
-                    nax = int(mesh.shape[ax])
-                    if nax == 1:
-                        continue
-                    out, err = _cc.quantized_psum(out, ax, axis_size=nax,
-                                                  chunk=cchunk)
-                    err_tot = err_tot + err / repl
-                    repl *= nax
-                return out, err_tot, repl
+                with phase("grad_sync"):
+                    v = g.astype(jnp.float32) + ef
+                    err_tot = jnp.zeros(v.shape, jnp.float32)
+                    out, repl = v, 1
+                    for ax in data_axes + sep_axes:
+                        nax = int(mesh.shape[ax])
+                        if nax == 1:
+                            continue
+                        out, err = _cc.quantized_psum(
+                            out, ax, axis_size=nax, chunk=cchunk)
+                        err_tot = err_tot + err / repl
+                        repl *= nax
+                    return out, err_tot, repl
 
             def adamw_update12_c(p, g, ef, st, step, lr):
                 """stage 1/2 update with int8 DP psum + int8 'sharding'
@@ -1036,7 +1048,9 @@ class SpmdTrainer:
                                                     scatter)
                 if err_s is not None:
                     n = int(np.prod(p.shape))
-                    err_tot = err_tot + (err_s[:n].reshape(p.shape) / repl)
+                    with phase("grad_sync"):
+                        err_tot = err_tot \
+                            + (err_s[:n].reshape(p.shape) / repl)
                 return pn, stn, err_tot
 
             def adamw_update3_c(p, g, ef, st, step, lr):
@@ -1044,9 +1058,10 @@ class SpmdTrainer:
                 — in int8 when grad_compress is on, via the gather-on-use
                 custom VJP); compress the remaining DP psum with EF."""
                 gr, err_tot, _ = compress_reduce(g, ef)
-                pl, stn = _adamw_core(p.astype(jnp.float32), gr, st,
-                                      step, lr)
-                return pl.astype(p.dtype), stn, err_tot
+                with phase("optimizer"):
+                    pl, stn = _adamw_core(p.astype(jnp.float32), gr, st,
+                                          step, lr)
+                    return pl.astype(p.dtype), stn, err_tot
 
             adamw_update_c = adamw_update3_c if stage3 else adamw_update12_c
 
@@ -1169,24 +1184,25 @@ class SpmdTrainer:
             # the gather-on-use (stage 3). With grad_compress both of
             # those syncs ride chunked int8 inside the per-param update
             # (compress_reduce / quantized_psum_scatter) instead.
-            if not comp:
-                def reduce_grad(g):
-                    for ax in data_axes + sep_axes:
-                        g = lax.psum(g, ax)
-                    return g
+            with phase("grad_sync"):
+                if not comp:
+                    def reduce_grad(g):
+                        for ax in data_axes + sep_axes:
+                            g = lax.psum(g, ax)
+                        return g
 
-                grads = jax.tree_util.tree_map(reduce_grad, grads)
-            # Megatron-SP: norm weights saw only this rank's sequence
-            # shard — complete their grads across the TP group (exact:
-            # the model axis is not a compressed path)
-            if sp_active:
-                grads["stacked"] = [
-                    lax.psum(g, "model") if flag else g
-                    for g, flag in zip(grads["stacked"], sp_flags)]
-            # pipe-replicated outer params: sum partials across stages
-            if S > 1:
-                grads["outer"] = [lax.psum(g, "pipe")
-                                  for g in grads["outer"]]
+                    grads = jax.tree_util.tree_map(reduce_grad, grads)
+                # Megatron-SP: norm weights saw only this rank's sequence
+                # shard — complete their grads across the TP group (exact:
+                # the model axis is not a compressed path)
+                if sp_active:
+                    grads["stacked"] = [
+                        lax.psum(g, "model") if flag else g
+                        for g, flag in zip(grads["stacked"], sp_flags)]
+                # pipe-replicated outer params: sum partials across stages
+                if S > 1:
+                    grads["outer"] = [lax.psum(g, "pipe")
+                                      for g in grads["outer"]]
             new_params = {"outer": [], "stacked": []}
             new_opt = {"outer": [], "stacked": []}
             if comp:

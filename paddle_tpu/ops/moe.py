@@ -19,7 +19,9 @@ The two products over the experts held are the Pallas grouped matmul
 (ops/pallas/grouped_matmul.py): the (token, choice) rows are sorted by
 expert, rows of experts held elsewhere go last and belong to no group.
 The model's eager forward and the serving engine's step programs both
-call this function.
+call this function; the engine calls it inside its `ffn` phase
+(profiler.PHASES), and the `router` / `experts` scopes here only split
+that phase for a human reading a trace.
 """
 import jax
 import jax.numpy as jnp
@@ -61,27 +63,29 @@ def routed_experts(x, router_w, router_bias, w_gu, w_d, held, top_k,
     lo, hi = held
     n_held = hi - lo
     assert w_gu.shape[0] == n_held == w_d.shape[0], (held, w_gu.shape)
-    idx, wts = route(x, router_w, router_bias, top_k, score)
+    with jax.named_scope("router"):
+        idx, wts = route(x, router_w, router_bias, top_k, score)
 
-    # (token, choice) rows sorted by held expert; rows of experts held
-    # elsewhere sort last under the sentinel n_held and join no group
-    mine = jnp.logical_and(idx >= lo, idx < hi)
-    key = jnp.where(mine, idx - lo, n_held).reshape(-1)
-    order = jnp.argsort(key, stable=True)
-    rows = jnp.sum(key[:, None] == jnp.arange(n_held, dtype=jnp.int32),
-                   axis=0, dtype=jnp.int32)
-    token = jnp.arange(t * top_k, dtype=jnp.int32) // top_k
-    xs = jnp.take(x, token[order], axis=0).astype(w_gu.dtype)
+    with jax.named_scope("experts"):
+        # (token, choice) rows sorted by held expert; rows of experts held
+        # elsewhere sort last under the sentinel n_held and join no group
+        mine = jnp.logical_and(idx >= lo, idx < hi)
+        key = jnp.where(mine, idx - lo, n_held).reshape(-1)
+        order = jnp.argsort(key, stable=True)
+        rows = jnp.sum(key[:, None] == jnp.arange(n_held, dtype=jnp.int32),
+                       axis=0, dtype=jnp.int32)
+        token = jnp.arange(t * top_k, dtype=jnp.int32) // top_k
+        xs = jnp.take(x, token[order], axis=0).astype(w_gu.dtype)
 
-    gu = grouped_matmul(xs, w_gu, rows, interpret=interpret)
-    width = gu.shape[1] // 2
-    act = jax.nn.silu(gu[:, :width].astype(jnp.float32)).astype(gu.dtype) \
-        * gu[:, width:]
-    ys = grouped_matmul(act, w_d, rows, interpret=interpret)
+        gu = grouped_matmul(xs, w_gu, rows, interpret=interpret)
+        width = gu.shape[1] // 2
+        act = jax.nn.silu(gu[:, :width].astype(jnp.float32)).astype(gu.dtype) \
+            * gu[:, width:]
+        ys = grouped_matmul(act, w_d, rows, interpret=interpret)
 
-    # back to (token, choice) order; rows of absent experts are zeros
-    back = jnp.zeros_like(order).at[order].set(
-        jnp.arange(t * top_k, dtype=order.dtype))
-    ys = jnp.take(ys, back, axis=0).reshape(t, top_k, hidden)
-    y = jnp.sum(wts[..., None] * ys.astype(jnp.float32), axis=1)
+        # back to (token, choice) order; rows of absent experts are zeros
+        back = jnp.zeros_like(order).at[order].set(
+            jnp.arange(t * top_k, dtype=order.dtype))
+        ys = jnp.take(ys, back, axis=0).reshape(t, top_k, hidden)
+        y = jnp.sum(wts[..., None] * ys.astype(jnp.float32), axis=1)
     return y.astype(x.dtype), rows

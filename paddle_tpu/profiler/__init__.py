@@ -68,6 +68,24 @@ def export_chrome_tracing(dir_name, worker_name=None):
     return handler
 
 
+# The model phases of a step program (docs/observability.md "Device
+# phases"): a closed vocabulary, opened in the program as `with
+# phase(name):` at the seams every op chain shares. A `jax.named_scope`
+# is metadata of the traced operations, nothing at run time; any
+# `jax.profiler` trace shows it in each device operation's name stack,
+# and perf/harness/phase_times.py splits a program's device time by it.
+PHASES = ("embed", "attn_proj", "kv_write", "attend", "ffn", "head",
+          "loss", "grad_sync", "optimizer")
+
+
+def phase(name):
+    """`jax.named_scope(name)` for a name of `PHASES`; ValueError for any
+    other, so a trace never holds a phase no reader knows."""
+    if name not in PHASES:
+        raise ValueError(f"no model phase {name!r} (have {PHASES})")
+    return jax.named_scope(name)
+
+
 def span_totals():
     """{name: (count, seconds)} over every RecordEvent span ended in this
     process so far: cumulative, never reset, there whether or not any

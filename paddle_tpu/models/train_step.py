@@ -16,7 +16,11 @@ covering
              stage 1/2 — params replicated; grads reduce-SCATTERED to the
                owning 'sharding' rank (lax.psum_scatter — true
                reduce-to-owner, not allreduce+slice); adam moments sharded;
-               updated param shards all-gathered.
+               updated param slices exchanged back into the (donated)
+               block in place. All of it in the local block's OWN shape,
+               the owned slice along one axis of it (moment_axis): no
+               flattened float32 copy, which on a TPU is a whole-tensor
+               relayout.
              stage 3 — params STORED as flat per-rank chunks over
                'sharding'; all-gathered on use per pipeline stage (inside
                the layer scan, so with recompute only one stage's full
@@ -39,7 +43,7 @@ from jax.sharding import PartitionSpec as P, NamedSharding
 
 from ..autograd import tape
 from ..framework import random as frnd
-from ..profiler import phase
+from ..profiler import phase, record_counters
 from ..tensor.tensor import Tensor
 from ..distributed.mesh import spmd_axes
 from ..distributed.comm_compress import resolve_chunk as _resolve_chunk
@@ -77,6 +81,37 @@ def _local_shape(gshape, spec, mesh):
         for a in axes:
             loc[d] //= mesh.shape[a]
     return tuple(loc)
+
+
+FLAT = -1     # moment_axis(): no axis divides, padded flat chunks
+
+
+def moment_axis(block_shape, n_shard):
+    """THE rule for where the stage-1/2 AdamW moments of a local parameter
+    block live, read off the block's shape alone. `None`: the whole block
+    (nothing shards it). `k >= 0`: each 'sharding' rank owns the slice
+    `[r * d/S, (r + 1) * d/S)` along axis k, the FIRST axis whose extent
+    S divides (axis 0 wherever it does: a decoder stack's layers of this
+    pipe stage, vocabulary rows); the reduce-to-owner, the owned slice of
+    the parameter and the re-gather all run along k, in the block's own
+    shape. `FLAT`: no axis divides, so the block is flattened, zero-padded
+    to a multiple of S and owned in rank-1 chunks (a relayout on a TPU:
+    update_layout() counts how often)."""
+    if n_shard <= 1:
+        return None
+    for k, d in enumerate(block_shape):
+        if d and d % n_shard == 0:
+            return k
+    return FLAT
+
+
+def _padded_flat(x, n_shard):
+    """x as rank 1, zero-padded to a multiple of n_shard (FLAT only)."""
+    flat = x.reshape(-1)
+    pad = (-flat.shape[0]) % n_shard
+    if pad:
+        flat = jnp.concatenate([flat, jnp.zeros(pad, flat.dtype)])
+    return flat
 
 
 class SpmdTrainer:
@@ -314,10 +349,9 @@ class SpmdTrainer:
             return jax.tree_util.tree_map(
                 lambda s: {"m": s, "v": s},
                 self._param_specs(), is_leaf=lambda x: isinstance(x, P))
-        all_axes = P(tuple(self.mesh.axis_names))
-        return jax.tree_util.tree_map(
-            lambda s: {"m": all_axes, "v": all_axes},
-            self._param_specs12(), is_leaf=lambda x: isinstance(x, P))
+        return {kind: [{"m": lay["spec"], "v": lay["spec"]}
+                       for lay in lays]
+                for kind, lays in self._moment_layouts().items()}
 
     def _state_specs(self):
         specs = {"params": self._param_specs(), "opt": self._opt_specs(),
@@ -327,6 +361,107 @@ class SpmdTrainer:
             # (stage 1/2: local-block shaped; stage 3: chunk shaped), f32
             specs["ef"] = self._param_specs()
         return specs
+
+    # ---- stage-1/2 moments: where they live (moment_axis) -----------------
+    def _moment_layouts(self):
+        """What follows from moment_axis() for every stage-1/2 parameter:
+        its axis, its local block, the moments' per-device and global
+        shapes and their PartitionSpec. Along an axis the moments are
+        GLOBALLY param-shaped, the parameter's spec with 'sharding' joined
+        to that axis; a FLAT chunk varies over the parameter's mesh axes
+        and 'sharding'."""
+        mesh, S = self.mesh, self.S_shard
+
+        def layout(gshape, spec):
+            block = _local_shape(gshape, spec, mesh)
+            k = moment_axis(block, S)
+            if k is None:
+                return {"axis": k, "block": block, "local": block,
+                        "shape": tuple(gshape), "spec": spec}
+            named = [() if e is None else e if isinstance(e, tuple) else (e,)
+                     for e in tuple(spec) + (None,) * (len(block) - len(spec))]
+            if k == FLAT:
+                chunk = -(-int(np.prod(block)) // S)
+                axes = tuple(a for e in named for a in e) + ("sharding",)
+                n = int(np.prod([mesh.shape[a] for a in axes]))
+                return {"axis": k, "block": block, "local": (chunk,),
+                        "shape": (chunk * n,), "spec": P(axes)}
+            named[k] += ("sharding",)
+            return {"axis": k, "block": block,
+                    "local": block[:k] + (block[k] // S,) + block[k + 1:],
+                    "shape": tuple(gshape),
+                    "spec": P(*[e or None for e in named])}
+
+        return {"outer": [layout(tuple(p.shape), s) for p, s in
+                          zip(self.outer_tensors, self.outer_specs)],
+                "stacked": [layout((self.n_layers,) + tuple(p.shape), s)
+                            for p, s in zip(self.layer_param_tensors,
+                                            self.stacked_specs)]}
+
+    def _owned(self, x, k):
+        """This 'sharding' rank's slice of a local block whose
+        moment_axis() is k (inside shard_map)."""
+        if k is None:
+            return x
+        if k == FLAT:
+            x, k = _padded_flat(x, self.S_shard), 0
+        chunk = x.shape[k] // self.S_shard
+        return lax.dynamic_slice_in_dim(
+            x, lax.axis_index("sharding") * chunk, chunk, axis=k)
+
+    def _regathered(self, x, k, block, into=None):
+        """Inverse of _owned: every rank's slice, back as the block.
+        `into`, a block the caller gives up (the step's donated
+        parameter), takes the slices IN PLACE: this rank's by
+        dynamic_update_slice, each other rank's as it arrives by
+        collective-permute. On the chip the result of a whole-block
+        all_gather is copied once more on its way out of the program and
+        the donated block once on its way in (PERF.md 6, PR 35: 15 ms of
+        a 679 ms four-chip step); written slice by slice, neither."""
+        if k is None:
+            return x
+        if k == FLAT:
+            flat = lax.all_gather(x, "sharding", axis=0, tiled=True)
+            return flat[:int(np.prod(block))].reshape(block)
+        if into is None:
+            return lax.all_gather(x, "sharding", axis=k, tiled=True)
+        S, r, chunk = self.S_shard, lax.axis_index("sharding"), x.shape[k]
+        into = lax.dynamic_update_slice_in_dim(into, x, r * chunk, axis=k)
+        for j in range(1, S):
+            got = lax.ppermute(x, "sharding",
+                               [(i, (i + j) % S) for i in range(S)])
+            into = lax.dynamic_update_slice_in_dim(
+                into, got, ((r - j) % S) * chunk, axis=k)
+        return into
+
+    def update_layout(self):
+        """How often the in-shape update engages: the parameter tensors
+        and the share of (per-device) ELEMENTS whose AdamW update runs in
+        the block's own shape (`in_shape_share`; of it along axis 0 and
+        along another axis where ZeRO shards the moments) and through the
+        flat fallback (`flat_share`; stage 3 stores flat chunks, all of
+        it). _build() records it: profiler.counter_history("trainer")."""
+        if self.sharding_stage == 3:
+            lays = [{"axis": FLAT, "block": s} for s in
+                    self.outer_loc_shapes + self.layer_loc_shapes]
+        else:
+            lays = sum(self._moment_layouts().values(), [])
+        elems = {"whole": 0, "axis0": 0, "other_axis": 0, "flat": 0}
+        tensors = dict(elems)
+        for lay in lays:
+            k = lay["axis"]
+            how = ("whole" if k is None else "flat" if k == FLAT
+                   else "axis0" if k == 0 else "other_axis")
+            elems[how] += int(np.prod(lay["block"]))
+            tensors[how] += 1
+        total = max(sum(elems.values()), 1)
+        return {"tensors": len(lays), "tensors_flat": tensors["flat"],
+                "tensors_axis0": tensors["axis0"],
+                "tensors_other_axis": tensors["other_axis"],
+                "in_shape_share": 1.0 - elems["flat"] / total,
+                "axis0_share": elems["axis0"] / total,
+                "other_axis_share": elems["other_axis"] / total,
+                "flat_share": elems["flat"] / total}
 
     # ---- stage-3 chunk <-> block conversion (runs inside shard_map) --------
     def _chunkify_outer(self, p_loc, i):
@@ -418,7 +553,6 @@ class SpmdTrainer:
 
     def init_state(self):
         params12 = self._init_params12()
-        S = self.S_shard
 
         if self.sharding_stage == 3:
             def to_chunks(p12):
@@ -447,23 +581,19 @@ class SpmdTrainer:
                 state["ef"] = self._init_ef(params)
             return state
 
-        # stage 1/2: AdamW moments created INSIDE the SPMD region so chunk
-        # sizes follow the LOCAL (model/pipe-sharded) param shapes; flat dim
-        # then chunks over 'sharding' (ZeRO).
-        def init_fn(p):
-            def zstate(a):
-                n = int(np.prod(a.shape))
-                pad = (-n) % S
-                chunk = (n + pad) // S
-                return {"m": jnp.zeros(chunk, self._mdt),
-                        "v": jnp.zeros(chunk, self._mdt)}
-            return jax.tree_util.tree_map(zstate, p,
-                                          is_leaf=lambda x: hasattr(x, "shape"))
+        # stage 1/2: AdamW moments created INSIDE the SPMD region, each
+        # device its owned slice of the LOCAL (model/pipe-sharded) block
+        # (_moment_layouts)
+        layouts = self._moment_layouts()
 
-        smapped = shard_map(init_fn, mesh=self.mesh,
-                            in_specs=(self._param_specs12(),),
+        def init_fn():
+            return {kind: [{k: jnp.zeros(lay["local"], self._mdt)
+                            for k in ("m", "v")} for lay in lays]
+                    for kind, lays in layouts.items()}
+
+        smapped = shard_map(init_fn, mesh=self.mesh, in_specs=(),
                             out_specs=self._opt_specs(), check_vma=False)
-        opt = jax.jit(smapped)(params12)
+        opt = jax.jit(smapped)()
         state = {"params": params12, "opt": opt,
                  "step": jax.device_put(
                          jnp.zeros((), jnp.int32),
@@ -482,15 +612,6 @@ class SpmdTrainer:
                 for kind in ("outer", "stacked")}
 
     # ---- mesh-independent canonical state (cross-mesh restore) -------------
-    def _stage12_moment_geom(self):
-        """Stage-1/2 AdamW moments are flat per-rank chunks of the
-        FLATTENED LOCAL param block: (n, chunk) per outer/stacked param."""
-        S = max(self.S_shard, 1)
-        outer = [(n, (n + (-n) % S) // S) for n in self.outer_loc_n]
-        stacked = [(self.per * n, (self.per * n + (-(self.per * n)) % S) // S)
-                   for n in self.layer_loc_n]
-        return outer, stacked
-
     def canonical_state(self, state):
         """Convert a live state into its MESH-INDEPENDENT canonical form:
         params and AdamW moments as GLOBAL param-shaped arrays, decoder
@@ -503,13 +624,8 @@ class SpmdTrainer:
         restart-from-checkpoint under a CHANGED world,
         hybrid_parallel_pp_save_load.py)."""
         specs12 = self._param_specs12()
-        mg_outer, mg_stacked = self._stage12_moment_geom()
+        layouts = self._moment_layouts()
         stage3 = self.sharding_stage == 3
-
-        def gather_moment(flat, n, shape):
-            if self.S_shard > 1:
-                flat = lax.all_gather(flat, "sharding", axis=0, tiled=True)
-            return flat[:n].reshape(shape)
 
         def unshard(st):
             pr, opt = st["params"], st["opt"]
@@ -541,15 +657,11 @@ class SpmdTrainer:
                     ms.append(ent)
             else:
                 outer, stacked = pr["outer"], pr["stacked"]
-                mo = [{k: gather_moment(opt["outer"][i][k], n,
-                                        self.outer_loc_shapes[i])
-                       for k in ("m", "v")}
-                      for i, (n, _) in enumerate(mg_outer)]
-                ms = [{k: gather_moment(opt["stacked"][i][k], n,
-                                        (self.per,)
-                                        + self.layer_loc_shapes[i])
-                       for k in ("m", "v")}
-                      for i, (n, _) in enumerate(mg_stacked)]
+                mo, ms = [
+                    [{k: self._regathered(ent[k], lay["axis"], lay["block"])
+                      for k in ("m", "v")}
+                     for ent, lay in zip(opt[kind], layouts[kind])]
+                    for kind in ("outer", "stacked")]
             return {"params": {"outer": outer, "stacked": stacked},
                     "opt": {"outer": mo, "stacked": ms}, "step": st["step"]}
 
@@ -609,9 +721,8 @@ class SpmdTrainer:
         global param-shaped arrays into this mesh's state (casting to this
         trainer's param/moment dtypes)."""
         specs12 = self._param_specs12()
-        mg_outer, mg_stacked = self._stage12_moment_geom()
+        layouts = self._moment_layouts()
         stage3 = self.sharding_stage == 3
-        S = max(self.S_shard, 1)
 
         cast_p = (lambda a: a.astype(self._pdt)
                   if self._pdt is not None
@@ -641,16 +752,6 @@ class SpmdTrainer:
                         for ent, sp in zip(canon["opt"]["stacked"],
                                            specs12["stacked"])]}
 
-        def chunk_moment(loc, n, chunk):
-            flat = loc.reshape(-1)
-            pad = S * chunk - n
-            if pad:
-                flat = jnp.concatenate([flat, jnp.zeros(pad, flat.dtype)])
-            if S > 1:
-                r = lax.axis_index("sharding")
-                return lax.dynamic_slice_in_dim(flat, r * chunk, chunk)
-            return flat
-
         def reshard(p12, m12, step):
             if stage3:
                 params = {"outer": [self._chunkify_outer(p, i)
@@ -667,14 +768,10 @@ class SpmdTrainer:
                                    enumerate(m12["stacked"])]}
             else:
                 params = p12
-                opt = {"outer": [{k: chunk_moment(ent[k], n, c)
-                                  for k in ("m", "v")}
-                                 for (n, c), ent in zip(mg_outer,
-                                                        m12["outer"])],
-                       "stacked": [{k: chunk_moment(ent[k], n, c)
-                                    for k in ("m", "v")}
-                                   for (n, c), ent in zip(mg_stacked,
-                                                          m12["stacked"])]}
+                opt = {kind: [{k: self._owned(ent[k], lay["axis"])
+                               for k in ("m", "v")}
+                              for ent, lay in zip(m12[kind], layouts[kind])]
+                       for kind in ("outer", "stacked")}
             out = {"params": params, "opt": opt, "step": step}
             if self.grad_compress is not None:
                 # EF residuals are transient device state (sub-one-step
@@ -717,6 +814,7 @@ class SpmdTrainer:
 
     # ---- the step ---------------------------------------------------------
     def _build(self, ids_shape):
+        record_counters("trainer", self.update_layout())
         mesh = self.mesh
         axis_names = tuple(mesh.axis_names)
         S = self.S_pipe
@@ -949,48 +1047,35 @@ class SpmdTrainer:
             return pl, {"m": m.astype(mdt), "v": v.astype(mdt)}
 
         def _update12_scaffold(p, g, st, step, lr, scatter):
-            """stage 1/2 scaffold shared by the exact and int8 paths:
-            pad + flatten, reduce-to-owner via scatter(gf) -> (owned
-            grad chunk, residual-or-None), core update on the owned
-            chunk, re-gather, unpad. Returns (p', moments, residual)."""
-            shape = p.shape
-            n = int(np.prod(shape))
-            pad = (-n) % S_shard
-            with phase("optimizer"):
-                gf = g.reshape(-1).astype(jnp.float32)
-                if pad:
-                    gf = jnp.concatenate([gf, jnp.zeros(pad, jnp.float32)])
-                pf = p.reshape(-1).astype(jnp.float32)
-                if pad:
-                    pf = jnp.concatenate([pf, jnp.zeros(pad, jnp.float32)])
-            err = None
-            if S_shard > 1:
+            """stage 1/2 scaffold shared by the exact and int8 paths, in
+            the block's own shape along its moment_axis() k: reduce-to-
+            owner via scatter(g f32, k) -> (owned grad slice, residual-or-
+            None), core update on the owned slice of p (cast inside the
+            elementwise update), the updated slices back into p's block
+            in p's dtype. Nothing shards the update (k None): the core on
+            the block as it is. Returns (p', moments, residual)."""
+            k = moment_axis(p.shape, S_shard)
+            gl, err = g, None
+            if k is not None:
                 with phase("grad_sync"):    # the sum, reduced to its owner
-                    gl, err = scatter(gf)
+                    gl, err = scatter(g.astype(jnp.float32), k)
             with phase("optimizer"):
-                if S_shard > 1:
-                    chunk = gf.shape[0] // S_shard
-                    r = lax.axis_index("sharding")
-                    pl = lax.dynamic_slice_in_dim(pf, r * chunk, chunk)
-                else:
-                    gl, pl = gf, pf
-                pl, stn = _adamw_core(pl, gl, st, step, lr)
-                if S_shard > 1:
-                    pf = lax.all_gather(pl, "sharding", axis=0, tiled=True)
-                else:
-                    pf = pl
-                if pad:
-                    pf = pf[:n]
-                return pf.reshape(shape).astype(p.dtype), stn, err
+                pl, stn = _adamw_core(
+                    self._owned(p, k).astype(jnp.float32),
+                    gl.astype(jnp.float32), st, step, lr)
+                return (self._regathered(pl.astype(p.dtype), k, p.shape,
+                                         into=p), stn, err)
 
         def adamw_update12(p, g, st, step, lr):
             """stage 1/2: p is the full local block; g is psum'd over 'data'
             but still PARTIAL over 'sharding' — reduce-scatter completes the
-            sum while handing each rank exactly its owned chunk
+            sum while handing each rank exactly its owned slice
             (ref: group_sharded_stage2.py grad reduce-to-owner hooks)."""
-            def scatter(gf):
+            def scatter(gf, k):
+                if k == FLAT:
+                    gf, k = _padded_flat(gf, S_shard), 0
                 return lax.psum_scatter(gf, "sharding",
-                                        scatter_dimension=0,
+                                        scatter_dimension=k,
                                         tiled=True), None
             pn, stn, _ = _update12_scaffold(p, g, st, step, lr, scatter)
             return pn, stn
@@ -1041,16 +1126,22 @@ class SpmdTrainer:
                 plus the EF residual bookkeeping."""
                 gr, err_tot, repl = compress_reduce(g, ef)
 
-                def scatter(gf):
-                    return _cc.quantized_psum_scatter(
-                        gf, "sharding", axis_size=S_shard, chunk=cchunk)
+                def scatter(gf, k):
+                    # the quantised collective scatters along dim 0: the
+                    # owned axis goes to the front for it alone (axis 0:
+                    # the same rows as a flat block's)
+                    rows = (_padded_flat(gf, S_shard) if k == FLAT
+                            else jnp.moveaxis(gf, k, 0))
+                    y, err = _cc.quantized_psum_scatter(
+                        rows, "sharding", axis_size=S_shard, chunk=cchunk)
+                    if k == FLAT:
+                        return y, err[:gf.size].reshape(gf.shape)
+                    return jnp.moveaxis(y, 0, k), jnp.moveaxis(err, 0, k)
                 pn, stn, err_s = _update12_scaffold(p, gr, st, step, lr,
                                                     scatter)
                 if err_s is not None:
-                    n = int(np.prod(p.shape))
                     with phase("grad_sync"):
-                        err_tot = err_tot \
-                            + (err_s[:n].reshape(p.shape) / repl)
+                        err_tot = err_tot + err_s / repl
                 return pn, stn, err_tot
 
             def adamw_update3_c(p, g, ef, st, step, lr):
@@ -1273,9 +1364,6 @@ class SpmdTrainer:
         chunk_mul = 1
         for a in self._chunk_axes:
             chunk_mul *= int(self.mesh.shape[a])
-        n_dev = 1
-        for a in self.mesh.axis_names:
-            n_dev *= int(self.mesh.shape[a])
 
         if self.sharding_stage == 3:
             # global leaf = local chunk x product of the chunk axes
@@ -1298,12 +1386,10 @@ class SpmdTrainer:
                              pdt_of(jnp.dtype(p.dtype)),
                              specs["stacked"][i])
                          for i, p in enumerate(self.layer_param_tensors)]
-            mg_outer, mg_stacked = self._stage12_moment_geom()
-            all_axes = P(tuple(self.mesh.axis_names))
-            mo = [{k: sds((c * n_dev,), self._mdt, all_axes)
-                   for k in ("m", "v")} for (_, c) in mg_outer]
-            ms = [{k: sds((c * n_dev,), self._mdt, all_axes)
-                   for k in ("m", "v")} for (_, c) in mg_stacked]
+            layouts = self._moment_layouts()
+            mo, ms = [[{k: sds(lay["shape"], self._mdt, lay["spec"])
+                        for k in ("m", "v")} for lay in layouts[kind]]
+                      for kind in ("outer", "stacked")]
         out = {"params": {"outer": p_outer, "stacked": p_stacked},
                "opt": {"outer": mo, "stacked": ms},
                "step": sds((), jnp.int32, P())}

@@ -65,6 +65,7 @@ def _cases(interpret):
         paged_attention, paged_attention_dense, paged_attention_reference,
         ragged_paged_attention, ragged_paged_attention_reference,
         spec_verify_attention)
+    from paddle_tpu.ops.pallas.chunk_attention import paged_chunk_attention
     from paddle_tpu.ops.pallas.quantized_matmul import (quantized_matmul,
                                                         quantize_weights)
     from paddle_tpu.ops.pallas.decode_megakernel import (
@@ -297,6 +298,33 @@ def _cases(interpret):
                 ragged_case(g, w, page, False)
             cases[f"spec_verify_attention_{gname}_T4_w{w}"] = \
                 ragged_case(g, w, 4, True)
+
+    def chunk_case(nh, nh_kv, chunk, start, window):
+        """The K=1 prefill's chunk attention of ONE sequence through its
+        page table (what a non-plain description with lane-aligned heads
+        runs a chunk), grouped queries nh : nh_kv, against the ragged
+        kernel's XLA reference at one slot."""
+        def run():
+            kp, vp, table = pool(1, nh_kv, 128, jnp.bfloat16)
+            q = jnp.asarray(rng.randn(chunk, nh, 128) * 0.3, jnp.bfloat16)
+            end = start + chunk - 3         # three padded rows
+            out = jax.jit(lambda *a: paged_chunk_attention(
+                *a, window=window, interpret=interpret))(
+                    q, kp, vp, table[0], jnp.int32(start), jnp.int32(end))
+            ref = ragged_paged_attention_reference(
+                q[None], kp, vp, np.asarray(table), [start + chunk],
+                [start], window=window)[0]
+            return close("attn", out[:chunk - 3], ref[:chunk - 3])
+        return run
+
+    # 128 query heads over 8 KV heads x 128 (the parallel-block cell), a
+    # chunk deep in the context and one at its start, full and windowed
+    cq, ck = (8, 2) if interpret else (128, 8)
+    for start in (0, 3 * page + 5):
+        for window in (None, 2 * page):
+            cases[f"paged_chunk_attention_{cq}q{ck}kv_chunk{2 * page}"
+                  f"_start{start}_w{window}"] = chunk_case(
+                      cq, ck, 2 * page, start, window)
 
     def make_layer(g, kind, nh_l=None, ffn_l=None):
         """One decoder layer's weights, unit-variance activations:

@@ -8,13 +8,23 @@ A model that wants to be served implements two methods and nothing else:
       and shapes (the dataclasses below, all static and hashable);
   serving_parameters()  -> {"emb", "norm", "head", "layers": [{...}]}
       its parameters (Layer parameters, possibly lazy) under the
-      engine's canonical names: `ln1 ln2 wo` and either `wq wk wv` or one
-      fused `wqkv` ([hidden, q | k | v] columns); `sink` ([heads]) where
-      the layer has one; a dense FFN as `wg wu wd`; routed experts as
-      `router` ([hidden, experts]), `router_bias` ([experts]), `w_gu`
+      engine's canonical names: `ln1 wo`, `ln2` (a PARALLEL layer,
+      `LayerSpec.parallel`, has none: its FFN reads what its projections
+      read) and either `wq wk wv` or one fused `wqkv` ([hidden, q | k |
+      v] columns); `sink` ([heads]) where the layer has one; a dense FFN
+      as `wg wu wd`; routed experts as `router` ([hidden, experts]),
+      `router_bias` ([experts], only where the router stores one), `w_gu`
       ([held, hidden, 2 x width], gate columns first) and `w_d` ([held,
-      width, hidden]) and, beside them, one shared expert as `ws_g ws_u
-      ws_d`. A LATENT attention layer (`AttentionSpec.latent`) has no
+      width, hidden]) and, beside them, ONE shared SwiGLU as `ws_g ws_u
+      ws_d` of `FFNSpec.shared_width` (n shared experts that are summed
+      or averaged are one SwiGLU of n times the width, the 1/n in the
+      down projection's rows: a layout of the weights). Norm weights are
+      an RMSNorm's or, under `ModelDescription.norm` "layer", a
+      mean-subtracting LayerNorm's without bias. Rotation is half-split
+      (dims i and i + rope_dim / 2 pair); a checkpoint that pairs
+      (2i, 2i + 1) hands `wq` / `wk` over with each head's columns
+      de-interleaved; `AttentionSpec.rope_dim` 0 is a position-free
+      layer. A LATENT attention layer (`AttentionSpec.latent`) has no
       `wq wk wv`: it gives `wq_a` ([hidden, query rank]), `q_norm`,
       `wq_b` ([query rank, heads x (no-position + rotary width)], a
       head's no-position columns first), `wkv_a` ([hidden, latent rank +
@@ -29,8 +39,9 @@ A model that wants to be served implements two methods and nothing else:
       x index width]: its index query reads the normed hidden state),
       and `q_hn k_hn` ([head width] each) where `AttentionSpec.qk_norm`
       norms every query and key head before the rotation. A router with
-      `FFNSpec.score` "softmax" has no `router_bias`. Matrices are
-      [in, out].
+      `FFNSpec.score` "softmax" has no `router_bias`. `head` is a matrix
+      of its own ([hidden, vocab]); a model with tied embeddings hands
+      over the transpose. Matrices are [in, out].
 
 The engine (serving.py, scheduler.py) reads the description and the
 canonical names and never asks what class the model is. What a
@@ -97,7 +108,8 @@ class AttentionSpec:
     n_kv_heads: int
     qk_dim: int                     # width of a query / key head
     v_dim: int                      # width of a value head
-    rope_dim: int                   # leading dims of q and k that rotate
+    rope_dim: int                   # leading dims of q and k that
+    #                                 rotate; 0 = a position-free layer
     rope_theta: float
     window: Optional[int] = None    # None = full causal attention
     sink: bool = False              # learned per-head sink logit
@@ -127,15 +139,19 @@ class FFNSpec:
     n_experts: int = 0              # router outputs (all chips' experts)
     top_k: int = 0
     held: Tuple[int, int] = (0, 0)  # [lo, hi): the experts held HERE
-    shared_width: int = 0           # one shared SwiGLU expert beside them
+    shared_width: int = 0           # one shared SwiGLU beside them (n
+    #                                 summed experts: n x their width)
     score: str = "sigmoid"          # the router (ops/moe.route): "sigmoid"
-    #                                 (+ a stored bias) | "softmax"
+    #                                 (+ a stored bias, if any) | "softmax"
 
 
 @dataclasses.dataclass(frozen=True)
 class LayerSpec:
     attn: AttentionSpec
     ffn: FFNSpec
+    # a PARALLEL block: y = x + Attn(n) + FFN(n) with ONE norm n =
+    # norm(x); the default is sequential, the FFN norming x + Attn
+    parallel: bool = False
 
 
 @dataclasses.dataclass(frozen=True)
@@ -144,6 +160,9 @@ class ModelDescription:
     vocab_size: int
     eps: float
     layers: Tuple[LayerSpec, ...]
+    norm: str = "rms"               # every layer norm and the final one:
+    #                                 "rms" | "layer" (mean-subtracted,
+    #                                 weight only)
 
     @property
     def groups(self):
@@ -170,6 +189,7 @@ class ModelDescription:
         first = self.layers[0]
         a = first.attn
         return (all(layer == first for layer in self.layers)
+                and self.norm == "rms" and not first.parallel
                 and first.ffn.kind == "dense"
                 and a.qk_dim == a.v_dim == a.rope_dim
                 and a.n_heads * a.qk_dim == self.hidden_size

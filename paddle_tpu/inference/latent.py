@@ -49,12 +49,13 @@ def _write(pool, slots, values):
                               mode="drop").reshape(pool.shape)
 
 
-def block_pages(tab, j, p):
-    """(pool pages [nb], key positions [kb]) of key block j of a sequence
-    whose table is tab [pages_per_seq]; a page past the table reads its
-    last entry and is masked by position."""
-    mp, nb = tab.shape[0], KEY_BLOCK_PAGES
-    page_ix = j * nb + jnp.arange(nb)
+def block_pages(tab, j, p, nb=KEY_BLOCK_PAGES, first=0):
+    """(pool pages [nb], key positions [kb]) of key block j (nb pages,
+    counted from logical page `first`) of a sequence whose table is tab
+    [pages_per_seq]; a page past the table reads its last entry and is
+    masked by position."""
+    mp = tab.shape[0]
+    page_ix = first + j * nb + jnp.arange(nb)
     kpos = (page_ix[:, None] * p + jnp.arange(p)[None, :]).reshape(nb * p)
     return tab[jnp.minimum(page_ix, mp - 1)], \
         jnp.where(jnp.repeat(page_ix < mp, p), kpos, mp * p)

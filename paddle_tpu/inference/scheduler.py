@@ -1460,7 +1460,9 @@ class ContinuousBatchingEngine(LLMEngine):
                    "pages_free": g.allocator.available,
                    "pages_used": g.used,
                    "used_page_steps": g.used_page_steps,
-                   "freed_behind_window": g.freed_behind_window}
+                   "freed_behind_window": g.freed_behind_window,
+                   "kv_tokens_read": g.kv_tokens_read,
+                   "prefill_pairs": g.prefill_pairs}
                   for g in self.groups]
         experts = sparse = None
         route = self._route_read() if self._counted else None
@@ -1482,6 +1484,7 @@ class ContinuousBatchingEngine(LLMEngine):
         # profiler.span_totals() (docs/observability.md): a reader that
         # knows two moments differences the samples nearest them
         counters = {"steps": self.steps,
+                    "prefill_steps": self.prefill_steps,
                     "ahead.dispatched": self.ahead_dispatched,
                     "ahead.overrun_rows": self.ahead_overrun_rows,
                     "ahead.resolved_first": sum(
@@ -1494,6 +1497,8 @@ class ContinuousBatchingEngine(LLMEngine):
             counters[f"group{i}.window"] = g["window"] or 0
             counters[f"group{i}.freed_behind_window"] = \
                 g["freed_behind_window"]
+            counters[f"group{i}.kv_tokens_read"] = g["kv_tokens_read"]
+            counters[f"group{i}.prefill_pairs"] = g["prefill_pairs"]
         if experts is not None:
             counters["experts.decode_steps"] = experts["decode_steps"]
             counters["experts.rows"] = experts["rows"]
@@ -2059,51 +2064,19 @@ class ContinuousBatchingEngine(LLMEngine):
                     k_pages_all = _pools_put(k_pages_all, li, kp, new_k)
                     v_pages_all = _pools_put(v_pages_all, li, vp, new_v)
                 with phase("attend"):
-                    # gather this sequence's context back out of the pool:
-                    # [pages*p, h_kv, d]; keys past the causal horizon carry
-                    # finite garbage and mask to exact zero weight. A full
-                    # layer gathers every logical page; a window layer only
-                    # the pages the chunk's windows can touch (the ones
-                    # behind them are freed and their table entries dead)
-                    if a.window is None:
-                        page_ix = jnp.arange(mp, dtype=jnp.int32)
-                        live = None
+                    if not self.desc.plain:
+                        # a KV head's query heads as rows against the
+                        # UNREPEATED K and V, over the pages the chunk
+                        # can see under a running softmax
+                        attn = sparse_heads.attend_chunk(
+                            q[0], kp, vp, tab, pos, t_end, a, p,
+                            wset.get("sink"), self.interpret)[None]
                     else:
-                        n_ctx = min(mp, g.bound(chunk))
-                        first = jnp.maximum(t_start - a.window + 1, 0) // p
-                        page_ix = first + jnp.arange(n_ctx, dtype=jnp.int32)
-                        live = jnp.repeat(page_ix < mp, p)
-                        page_ix = jnp.minimum(page_ix, mp - 1)
-                    n_keys = page_ix.shape[0] * p
-                    ck = kp[tab[page_ix]].reshape(n_keys, nkv, a.qk_dim)
-                    cv = vp[tab[page_ix]].reshape(n_keys, nkv, a.v_dim)
-                    ck = expand_kv_heads(ck, q.shape[2])
-                    cv = expand_kv_heads(cv, q.shape[2])
-                    logits = jnp.einsum("qhd,khd->hqk", q[0], ck) \
-                        / math.sqrt(a.qk_dim)
-                    kpos = (page_ix[:, None] * p + jnp.arange(
-                        p, dtype=jnp.int32)[None, :]).reshape(
-                            n_keys)[None, None, :]
-                    qpos = pos[None, :, None]
-                    seen = kpos <= qpos
-                    if a.window is not None:
-                        seen = seen & (kpos > qpos - a.window) \
-                            & live[None, None, :]
-                    logits = jnp.where(seen, logits, -1e30)
-                    logits = logits.astype(jnp.float32)
-                    if a.sink:
-                        # the learned sink: one more term in the denominator
-                        sk = wset["sink"][:, None, None]
-                        m = jnp.maximum(jnp.max(logits, -1, keepdims=True), sk)
-                        e = jnp.exp(logits - m)
-                        w = (e / (jnp.sum(e, -1, keepdims=True)
-                                  + jnp.exp(sk - m))).astype(q.dtype)
-                    else:
-                        w = jax.nn.softmax(logits, -1).astype(q.dtype)
-                    attn = jnp.einsum("hqk,khd->qhd", w, cv)[None]
+                        attn = self._attend_chunk_dense(
+                            q, kp, vp, tab, pos, a)
                 h = self._layer_tail(W, wset, h, attn, ad=ad_li, li=li)
             with phase("head"):
-                h = _rms(h, W["norm"], W["eps"])
+                h = self._norm(h, W["norm"], W["eps"])
                 last = jnp.clip(t_end - 1 - t_start, 0, chunk - 1)
                 h_last = jax.lax.dynamic_index_in_dim(h, last, axis=1)
                 loc = (_mm_f32 if self.f32_stream else _mm)(
@@ -2137,6 +2110,26 @@ class ContinuousBatchingEngine(LLMEngine):
                             in_specs=(W, R, POOL, POOL, R, R, R, R, R),
                             out_specs=(R, R, POOL, POOL),
                             donate_argnums=(2, 3))
+
+    def _attend_chunk_dense(self, q, kp, vp, tab, pos, a):
+        """A plain description's chunk attention, as every mode it is
+        byte-compared with runs it: every logical page of the slot
+        gathered out of the pool ([pages*p, h_kv, d]; keys past the
+        causal horizon carry finite garbage and mask to exact zero
+        weight), K and V repeated to the query heads, one softmax over
+        [heads, chunk, keys]. q [1, chunk, H, d] -> [1, chunk, H, d]."""
+        p, mp = self.page_size, self.pages_per_seq
+        nkv = a.n_kv_heads // self.tp
+        ck = kp[tab].reshape(mp * p, nkv, a.qk_dim)
+        cv = vp[tab].reshape(mp * p, nkv, a.v_dim)
+        ck = expand_kv_heads(ck, q.shape[2])
+        cv = expand_kv_heads(cv, q.shape[2])
+        logits = jnp.einsum("qhd,khd->hqk", q[0], ck) \
+            / math.sqrt(a.qk_dim)
+        kpos = jnp.arange(mp * p, dtype=jnp.int32)[None, None, :]
+        logits = jnp.where(kpos <= pos[None, :, None], logits, -1e30)
+        w = jax.nn.softmax(logits.astype(jnp.float32), -1).astype(q.dtype)
+        return jnp.einsum("hqk,khd->qhd", w, cv)[None]
 
     def _prefill_step(self, r):
         with _span("cb.prefill.prepare"):
@@ -2192,6 +2185,13 @@ class ContinuousBatchingEngine(LLMEngine):
         # what the host knows without the chunk's result is booked HERE,
         # at dispatch; the first token at _resolve
         r.filled = end
+        seen = np.arange(start + 1, end + 1)    # keys a causal query sees
+        for g in self.groups:
+            # (query, key) pairs x layers this chunk's attention covers:
+            # what its products must compute, whatever computes them
+            g.prefill_pairs += len(g.layers) * int(
+                seen.sum() if g.window is None
+                else np.minimum(seen, g.window).sum())
         self._group_release(r, end)
         last = end >= r.t0
         if last:
@@ -2823,7 +2823,7 @@ class ContinuousBatchingEngine(LLMEngine):
             h = self._layer_tail(W, wset, h, attn[:, None], ad=ad_li,
                                  li=li, expert_rows=expert_rows)
         with phase("head"):
-            h = _rms(h, W["norm"], W["eps"])
+            h = self._norm(h, W["norm"], W["eps"])
             loc = (_mm_f32 if self.f32_stream else _mm)(
                 h, W["head"], self.interpret)[:, 0]
             if topk is not None:
@@ -3170,6 +3170,13 @@ class ContinuousBatchingEngine(LLMEngine):
         for r in decodes:
             self._lens_np[r.slot] += 1
             self._group_release(r, int(self._lens_np[r.slot]))
+        ctx = self._lens_np[[r.slot for r in decodes]]
+        for g in self.groups:
+            # cached tokens this step's queries read, layer by layer: the
+            # whole context, or what the window leaves of it
+            g.kv_tokens_read += len(g.layers) * int(
+                (ctx if g.window is None
+                 else np.minimum(ctx, g.window)).sum())
         if greedy:
             self._tok_dev = head[0]
         return _Dispatched(True, [(r, r.slot) for r in decodes], mode=mode,

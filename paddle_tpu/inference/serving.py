@@ -283,6 +283,10 @@ class PageGroup:
                 + -(-chunk // page_size)
         self.allocator = PageAllocator(self.n_pages)
         self.freed_behind_window = 0    # lifetime, pages
+        self.kv_tokens_read = 0         # lifetime: cached tokens x layers
+        #                                 that decode queries attended to
+        self.prefill_pairs = 0          # lifetime: (query, key) pairs x
+        #                                 layers of the prefill chunks
         self.used_page_steps = 0        # sum over steps of pages in use
 
     def bound(self, tokens):
@@ -385,6 +389,17 @@ def _rms(x, w, eps):
         * w.astype(x.dtype)
 
 
+def _layer_norm(x, w, eps):
+    """`ModelDescription.norm` "layer": the mean taken off, then the
+    RMSNorm of what is left; a weight and no bias."""
+    x32 = x.astype(jnp.float32)
+    return _rms(x32 - jnp.mean(x32, axis=-1, keepdims=True), w,
+                eps).astype(x.dtype)
+
+
+_NORMS = {"rms": _rms, "layer": _layer_norm}
+
+
 class LLMEngine:
     """Paged-KV decode engine for any model that describes itself
     (inference/description.py): the engine reads the per-layer
@@ -454,6 +469,21 @@ class LLMEngine:
                     f"num_attention_heads ({layer.attn.n_heads}) must be "
                     f"a multiple of num_key_value_heads "
                     f"({layer.attn.n_kv_heads})")
+        if desc.norm not in _NORMS:
+            raise UnsupportedByDescription(
+                f"ModelDescription.norm {desc.norm!r}: the engine has "
+                f"{sorted(_NORMS)}")
+        self._norm = _NORMS[desc.norm]
+        for layer in desc.layers:
+            a = layer.attn
+            rows = a.latent is not None or a.indexer is not None
+            if rows and (layer.parallel or desc.norm != "rms"
+                         or a.rope_dim == 0):
+                raise UnsupportedByDescription(
+                    "a latent layer or a layer with an indexer norms its "
+                    "own input with an RMSNorm and rotates: it is served "
+                    "in a sequential block, under `norm` \"rms\", with "
+                    "rope_dim > 0")
         if not desc.plain:
             if int(tp or 1) > 1:
                 raise UnsupportedByDescription(
@@ -536,8 +566,9 @@ class LLMEngine:
         # plain description has one of, under the names it always had
         # (an indexer rotates its own width on its layer's base: one
         # more pair where that width is not the layer's)
+        # (a position-free layer, rope_dim 0, has no table)
         def rope_kinds(a):
-            return [(a.rope_dim, a.rope_theta)] + (
+            return [(a.rope_dim, a.rope_theta)] * bool(a.rope_dim) + (
                 [(a.indexer.rope_dim, a.rope_theta)] if a.indexer else [])
 
         kinds = []
@@ -545,9 +576,14 @@ class LLMEngine:
             for kind in rope_kinds(layer.attn):
                 if kind not in kinds:
                     kinds.append(kind)
-        self._layer_rope = tuple(kinds.index(rope_kinds(layer.attn)[0])
+
+        def table_of(a, which):
+            mine = rope_kinds(a)
+            return kinds.index(mine[which]) if mine else None
+
+        self._layer_rope = tuple(table_of(layer.attn, 0)
                                  for layer in desc.layers)
-        self._index_rope = tuple(kinds.index(rope_kinds(layer.attn)[-1])
+        self._index_rope = tuple(table_of(layer.attn, -1)
                                  for layer in desc.layers)
         tables = [_rope_cache(max_len, d, theta, jnp.float32)
                   for d, theta in kinds]
@@ -743,9 +779,8 @@ class LLMEngine:
         # at 0, every layer being the same.
         with phase("attn_proj"):
             a = self.desc.layers[li].attn
-            cos, sin = self._rope_of(W, li)
             b, t, H = h.shape
-            x = _rms(h, wset["ln1"], W["eps"])
+            x = self._norm(h, wset["ln1"], W["eps"])
             if self.f32_stream:         # operands in the cache's dtype
                 x = x.astype(self.kv_dtype)
             if "wqkv" in wset:
@@ -760,6 +795,14 @@ class LLMEngine:
                 q = _mm(x, wset["wq"], self.interpret)
                 k = _mm(x, wset["wk"], self.interpret)
                 v = _mm(x, wset["wv"], self.interpret)
+                if self.f32_stream:
+                    # the products END here: left to fuse the reshape to
+                    # heads into them, the TPU compiler transposes the
+                    # WEIGHT inside every program to get [.., heads, d]
+                    # out of the product (4 x 134 MB a decode step at 128
+                    # heads x 128: 1.7 of 21.7 ms; PERF.md 6, PR 36); so
+                    # the few rows of activations are relaid instead
+                    q, k, v = jax.lax.optimization_barrier((q, k, v))
             if ad is not None:
                 from .adapters import lora_apply
                 q = lora_apply(q, x, "wq", ad)
@@ -776,6 +819,9 @@ class LLMEngine:
             # GQA: k/v STAY at nh_kv heads — the paged cache stores the
             # checkpoint's kv width (1/rep the HBM of an expanded cache) and
             # the decode kernel maps q head i -> kv head i // rep natively
+            if not a.rope_dim:          # a position-free layer
+                return q, k, v
+            cos, sin = self._rope_of(W, li)
             c = cos[pos_ids][..., None, :].astype(q.dtype)
             s = sin[pos_ids][..., None, :].astype(q.dtype)
             d2 = a.rope_dim // 2
@@ -805,7 +851,12 @@ class LLMEngine:
         # li: the layer, for its FFNSpec — a dense SwiGLU or the routed
         # experts held here (ops/moe.py); expert_rows, a list, collects
         # the rows each held expert received ([held] int32 per expert
-        # layer) for the engine's routing counters.
+        # layer) for the engine's routing counters. A PARALLEL layer
+        # (LayerSpec.parallel) has one norm: its FFN reads the block's
+        # input under `ln1`, as its projections did (the same expression
+        # in the same program: the compiler keeps one), and attention
+        # and FFN join the residual stream in one sum.
+        spec = self.desc.layers[li]
         b, t = attn_out.shape[:2]
         # the two products whose results join the residual stream
         mm_out = _mm_f32 if self.f32_stream else _mm
@@ -813,10 +864,14 @@ class LLMEngine:
             attn_out = self._tp_gather_heads(attn_out)
             o = mm_out(attn_out.reshape(b, t, -1), wset["wo"], self.interpret)
             o = self._tp_reduce(o)
-            h = h + o
+            if spec.parallel:
+                res = h + o             # what the FFN's result joins
+            else:
+                res = h = h + o
         with phase("ffn"):
-            x = _rms(h, wset["ln2"], W["eps"])
-            ffn = self.desc.layers[li].ffn
+            x = self._norm(h, wset["ln1" if spec.parallel else "ln2"],
+                           W["eps"])
+            ffn = spec.ffn
             if ffn.kind == "experts":
                 y, rows = routed_experts(
                     x.reshape(b * t, -1), wset["router"],
@@ -830,7 +885,7 @@ class LLMEngine:
                         y = y + la.swiglu(
                             x.reshape(b * t, -1), wset["ws_g"],
                             wset["ws_u"], wset["ws_d"])
-                return h + y.reshape(b, t, -1)
+                return res + y.reshape(b, t, -1)
             if self.f32_stream:
                 x = x.astype(self.kv_dtype)
             g = _mm(x, wset["wg"], self.interpret)
@@ -845,7 +900,7 @@ class LLMEngine:
             if ad is not None:
                 from .adapters import lora_apply
                 d = lora_apply(d, act, "wd", ad)
-            return h + self._tp_reduce(d)
+            return res + self._tp_reduce(d)
 
     # -- prefill ------------------------------------------------------------
     def _build_prefill(self, t_pad):

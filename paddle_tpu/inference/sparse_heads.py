@@ -1,8 +1,9 @@
-"""A per-head (grouped-query) attention layer with an indexer inside the
-continuous-batching engine's step programs: the group's [K ; V] row pool
-and its index-key pool written and read through a page table
-(ops/sparse_attention.py has the mathematics, serving.PageGroup the pool
-shapes, docs/serving.md "Per-head groups with index keys" the design).
+"""Per-head (grouped-query) attention inside the continuous-batching
+engine's step programs where the dense gather does not do. A layer with
+an indexer: the group's [K ; V] row pool and its index-key pool written
+and read through a page table (ops/sparse_attention.py has the
+mathematics, serving.PageGroup the pool shapes, docs/serving.md
+"Per-head groups with index keys" the design).
 
   decode_layer   one token a slot: score every visible index key of every
                  slot, select exactly the top-k positions, GATHER those
@@ -12,6 +13,19 @@ shapes, docs/serving.md "Per-head groups with index keys" the design).
                  sequence's LIVE pages under the selection mask (online
                  softmax): no tensor against all `pages_per_seq` pages
                  exists.
+
+A layer WITHOUT an indexer (separate K and V pools, window or full):
+
+  attend_chunk   one chunk of one sequence over the pages the chunk can
+                 SEE (a window layer: from the page of the first query's
+                 oldest key; a full layer: the live pages) under a running
+                 softmax: K and V are never repeated to the query head
+                 count and no [heads, chunk, max_len] logits exist
+                 (docs/serving.md "Chunk attention through the page
+                 table"). Lane-aligned heads take the Pallas kernel
+                 (ops/pallas/chunk_attention.py: a block's logits never
+                 leave VMEM), any other shape `attend_chunk_blocks`, the
+                 same walk as XLA operations over key blocks.
 
 The index scan and the selection are inference/latent.py's, the model
 phases (`attn_proj`, `kv_write`, `attend`), the scopes inside `attend`
@@ -23,6 +37,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import sparse_attention as sa
+from ..ops.pallas.chunk_attention import paged_chunk_attention
 from ..profiler import phase
 from .latent import (KEY_BLOCK_PAGES, _write, block_pages, decode_selection,
                      prefill_selection)
@@ -103,3 +118,52 @@ def prefill_layer(eng, W, wset, h, rows_pool, ix_pool, tab, pos, t_end, li):
         with jax.named_scope("sparse_attend"):
             o = sa.attend_kv_blocks(q[0], block, 0, hi_blk, a)
         return o[None].astype(eng.kv_dtype), rows_pool, ix_pool
+
+
+def attend_chunk(q, k_pool, v_pool, tab, pos, t_end, a, p, sink=None,
+                 interpret=False):
+    """One chunk of one sequence of a per-head layer without an indexer,
+    after its K and V were written (arguments as `attend_chunk_blocks`).
+    Which implementation runs is read off the shapes: pools by head
+    ([pages, p, kv heads, d], not flat) whose key and value widths fill
+    whole 128-lane registers, and a chunk the kernel's query blocks
+    divide, take the Pallas kernel; the rest the XLA key blocks."""
+    if k_pool.ndim == 4 and a.qk_dim % 128 == 0 and a.v_dim % 128 == 0 \
+            and pos.shape[0] % 8 == 0:
+        return paged_chunk_attention(
+            q, k_pool, v_pool, tab, pos[0], t_end, window=a.window,
+            sinks=sink, interpret=interpret).astype(k_pool.dtype)
+    return attend_chunk_blocks(q, k_pool, v_pool, tab, pos, t_end, a, p,
+                               sink)
+
+
+def attend_chunk_blocks(q, k_pool, v_pool, tab, pos, t_end, a, p, sink=None):
+    """One chunk of one sequence of a per-head layer without an indexer,
+    after its K and V were written: q [chunk, H, d] at positions pos
+    [chunk] against the pools ([pages, p, kv heads, d] or flat [pages, p,
+    kv heads * d]; values [pages, p, kv heads, dv]) through tab
+    [pages_per_seq] -> [chunk, H, dv] in the pools' dtype. Query i sees
+    keys j <= pos[i], and j > pos[i] - window in a window layer: the walk
+    starts at the PAGE of the first query's oldest key (the pages behind
+    it are freed, their table entries dead, and are never read) and ends
+    at the block of the chunk's last real position."""
+    chunk, nb = pos.shape[0], KEY_BLOCK_PAGES
+    kb = nb * p
+    qpos = pos[:, None]
+    first = 0 if a.window is None else \
+        jnp.maximum(pos[0] - a.window + 1, 0) // p
+    last = (jnp.minimum(pos[0] + chunk, t_end) - 1) // p    # a page
+    n_blk = (last - first) // nb + 1
+
+    def block(j):
+        pages, kpos = block_pages(tab, j, p, nb, first)
+        seen = kpos[None, :] <= qpos
+        if a.window is not None:
+            seen = seen & (kpos[None, :] > qpos - a.window)
+        return sa.kv_row(
+            k_pool[pages].reshape(kb, a.n_kv_heads, a.qk_dim),
+            v_pool[pages].reshape(kb, a.n_kv_heads, a.v_dim)), seen
+
+    with jax.named_scope("chunk_attend_blocks"):
+        o = sa.attend_kv_blocks(q, block, 0, n_blk, a, sink)
+    return o.astype(k_pool.dtype)

@@ -7,3 +7,4 @@ from .bert import (BertConfig, BertModel, BertForMaskedLM,
 from .mimo_v2 import MiMoV2Config, MiMoV2ForCausalLM
 from .dots3_note import Dots3NoteConfig, Dots3NoteForCausalLM
 from .keye_vl2 import KeyeVL2Config, KeyeVL2ForCausalLM
+from .cohere2_moe import Cohere2MoeConfig, Cohere2MoeForCausalLM

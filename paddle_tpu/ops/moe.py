@@ -9,8 +9,8 @@ layer (tests/test_mimo_v2.py holds the shares to the uncut layer).
 
 Router, float32 throughout, by `score`. "sigmoid" (the DeepSeek-V3 /
 `noaux_tc` form): s = sigmoid(x W_g);  choose the top_k largest of s + b
-(b a stored correction bias that only steers the choice);  w_e = s_e /
-sum_chosen s. "softmax" (`norm_topk_prob`): p = softmax(x W_g) over all
+(b a stored correction bias that only steers the choice; a router that
+stores none passes None and chooses by s);  w_e = s_e / sum_chosen s. "softmax" (`norm_topk_prob`): p = softmax(x W_g) over all
 experts;  choose the top_k largest of p;  w_e = p_e / sum_chosen p; no
 bias.
 Expert e: SwiGLU, y_e = (silu(x G_e) * (x U_e)) D_e;  y = sum_chosen w_e y_e.
@@ -40,7 +40,9 @@ def route(x, router_w, router_bias, top_k, score="sigmoid"):
     else:
         assert score == "sigmoid", score
         s = jax.nn.sigmoid(logits)
-        _, idx = jax.lax.top_k(s + router_bias.astype(jnp.float32), top_k)
+        _, idx = jax.lax.top_k(
+            s if router_bias is None
+            else s + router_bias.astype(jnp.float32), top_k)
     chosen = jnp.take_along_axis(s, idx, axis=1)
     return idx.astype(jnp.int32), chosen / jnp.sum(chosen, -1, keepdims=True)
 
@@ -56,7 +58,7 @@ def routed_experts(x, router_w, router_bias, w_gu, w_d, held, top_k,
     float32.
 
     router_w [hidden, experts], router_bias [experts] (None under a
-    "softmax" `score`); w_gu [held, hidden,
+    "softmax" `score`, or where a "sigmoid" router stores none); w_gu [held, hidden,
     2 x width] with the gate's columns first; w_d [held, width, hidden];
     held = (lo, hi) expert ids, hi - lo == w_gu.shape[0]."""
     t, hidden = x.shape

@@ -165,41 +165,51 @@ def attend_selected(q, rows, valid, a):
     return jnp.concatenate(outs, 1)
 
 
-def attend_kv_blocks(q, block, lo, hi, a):
+def attend_kv_blocks(q, block, lo, hi, a, sink=None):
     """t queries of one sequence over the key blocks lo..hi-1 that all of
     them share, one block at a time (online softmax): q [t, H, d];
-    block(j) -> (rows [n, row], seen [t, n]). Returns the heads' outputs
-    [t, H, dv] float32. No [H, t, all keys] tensor exists."""
-    t = q.shape[0]
-    rep = a.n_heads // a.n_kv_heads
+    block(j) -> (rows [n, row], seen [t, n]). A KV head's query heads
+    meet its keys as they are cached: nothing is repeated to the query
+    head count, and no [H, t, all keys] tensor exists. sink [H]: a
+    learned logit a head, in the softmax's denominator only. Returns the
+    heads' outputs [t, H, dv] float32."""
+    t, G = q.shape[0], a.n_kv_heads
+    rep = a.n_heads // G
     scale = 1.0 / math.sqrt(a.qk_dim)
+    nk = G * a.qk_dim
 
     def body(j, carry):
         m, l, acc = carry
         rows, seen = block(j)
-        qc = q.astype(rows.dtype)
-        lg = jnp.concatenate([
-            jnp.einsum("thd,nd->htn", qc[:, g * rep:(g + 1) * rep],
-                       _kv_of(rows, g, a)[0],
-                       preferred_element_type=jnp.float32)
-            for g in range(a.n_kv_heads)], 0) * scale
-        lg = jnp.where(seen[None], lg, _NEG)
+        # ONE product over the KV heads (a batch dimension), each head's
+        # rep query heads against its own keys; no concatenation of
+        # per-head results (on the v5e those were whole-tensor copies,
+        # 14 of a 63 ms chunk attention: PERF.md 6, PR 36)
+        k = rows[:, :nk].reshape(-1, G, a.qk_dim)
+        v = rows[:, nk:nk + G * a.v_dim].reshape(-1, G, a.v_dim)
+        qg = q.astype(rows.dtype).reshape(t, G, rep, a.qk_dim)
+        lg = jnp.einsum("tgrd,ngd->grtn", qg, k,
+                        preferred_element_type=jnp.float32) * scale
+        ok = seen[None, None]
+        lg = jnp.where(ok, lg, _NEG)
         m_new = jnp.maximum(m, jnp.max(lg, -1))
         alpha = jnp.exp(m - m_new)
-        p = jnp.where(seen[None], jnp.exp(lg - m_new[..., None]), 0.0)
-        pv = jnp.concatenate([
-            jnp.einsum("htn,nd->htd",
-                       p[g * rep:(g + 1) * rep].astype(rows.dtype),
-                       _kv_of(rows, g, a)[1],
-                       preferred_element_type=jnp.float32)
-            for g in range(a.n_kv_heads)], 0)
+        p = jnp.where(ok, jnp.exp(lg - m_new[..., None]), 0.0)
+        pv = jnp.einsum("grtn,ngd->grtd", p.astype(rows.dtype), v,
+                        preferred_element_type=jnp.float32)
         return m_new, l * alpha + jnp.sum(p, -1), acc * alpha[..., None] + pv
 
-    init = (jnp.full((a.n_heads, t), _NEG, jnp.float32),
-            jnp.zeros((a.n_heads, t), jnp.float32),
-            jnp.zeros((a.n_heads, t, a.v_dim), jnp.float32))
-    _, l, acc = jax.lax.fori_loop(lo, hi, body, init)
-    return jnp.swapaxes(acc / jnp.maximum(l, 1e-30)[..., None], 0, 1)
+    init = (jnp.full((G, rep, t), _NEG, jnp.float32),
+            jnp.zeros((G, rep, t), jnp.float32),
+            jnp.zeros((G, rep, t, a.v_dim), jnp.float32))
+    m, l, acc = jax.lax.fori_loop(lo, hi, body, init)
+    if sink is not None:
+        sk = sink.astype(jnp.float32).reshape(G, rep, 1)
+        m_fin = jnp.maximum(m, sk)
+        beta = jnp.exp(m - m_fin)
+        acc, l = acc * beta[..., None], l * beta + jnp.exp(sk - m_fin)
+    out = acc / jnp.maximum(l, 1e-30)[..., None]            # [G, rep, t, dv]
+    return jnp.moveaxis(out, 2, 0).reshape(t, a.n_heads, a.v_dim)
 
 
 def sparse_gqa_attention_dense(x, w, a, eps, cos, sin, ix_cos, ix_sin):

@@ -261,6 +261,43 @@ class TestHybridBlockKernelsLowering:
                    _sds((16, k, n), jnp.bfloat16), _sds((16,), jnp.int32))
 
 
+class TestParallelBlockKernelsLowering:
+    """The kernels of the parallel block's cell at its published widths:
+    128 query heads over 8 KV heads x 128, a 4,096-token window beside a
+    full layer, pages of 128, a 1,024-token chunk."""
+
+    @pytest.mark.parametrize("window,sink", [(None, False), (4096, False),
+                                             (4096, True)])
+    def test_chunk_attention_through_the_page_table(self, window, sink):
+        from paddle_tpu.ops.pallas.chunk_attention import (
+            paged_chunk_attention, query_block)
+        chunk, h, h_kv, d, p, n_pages, mp = 1024, 128, 8, 128, 128, 256, 128
+        assert query_block(chunk, h) == 64
+        args = [_sds((chunk, h, d), jnp.bfloat16),
+                _sds((n_pages, p, h_kv, d), jnp.bfloat16),
+                _sds((n_pages, p, h_kv, d), jnp.bfloat16),
+                _sds((mp,), jnp.int32), _sds((), jnp.int32),
+                _sds((), jnp.int32)]
+        if sink:
+            _lower_tpu(lambda q, k, v, t, a, b, s: paged_chunk_attention(
+                q, k, v, t, a, b, window=window, sinks=s), *args,
+                _sds((h,), jnp.float32))
+        else:
+            _lower_tpu(lambda q, k, v, t, a, b: paged_chunk_attention(
+                q, k, v, t, a, b, window=window), *args)
+
+    @pytest.mark.parametrize("window", [None, 4096])
+    def test_decode_attention_sixteen_to_one(self, window):
+        from paddle_tpu.ops.pallas.paged_attention import paged_attention
+        b, h, h_kv, d, p, n_pages, mp = 48, 128, 8, 128, 128, 256, 128
+        _lower_tpu(lambda q, k, v, t, ln: paged_attention(
+            q, k, v, t, ln, window=window),
+            _sds((b, h, d), jnp.bfloat16),
+            _sds((n_pages, p, h_kv, d), jnp.bfloat16),
+            _sds((n_pages, p, h_kv, d), jnp.bfloat16),
+            _sds((b, mp), jnp.int32), _sds((b,), jnp.int32))
+
+
 class TestDecodeMegakernelLowering:
     """decode_megakernel layer/multi x dense/int8 at a lane-aligned
     geometry (what megakernel_supported admits on a chip)."""

@@ -32,7 +32,7 @@ from harness import manifest, phase_times  # noqa: E402
 from paddle_tpu.profiler import PHASES, phase  # noqa: E402
 
 SERVING = ("tiny-serve", "tiny-mimo-serve", "tiny-dots3-serve",
-           "tiny-keye-serve")
+           "tiny-keye-serve", "tiny-cohere2-moe-serve")
 TRAINING = ("tiny-train", "tiny-train-hybrid")
 # the operations that carry a program's time: none may be unphased
 HEAVY = re.compile(r"dot_general|scatter|gather|sort|top_k|custom_call")

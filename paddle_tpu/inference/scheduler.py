@@ -58,7 +58,8 @@ from .description import UnsupportedByDescription
 from .serving import LLMEngine, EngineFullError, _rms, _mm, _mm_f32
 from .speculative import resolve_drafter
 
-from ..ops.pallas.paged_attention import (expand_kv_heads, paged_attention,
+from ..ops.pallas.paged_attention import (expand_kv_heads, mxu_operands,
+                                          paged_attention,
                                           ragged_paged_attention,
                                           spec_verify_attention)
 
@@ -694,6 +695,17 @@ class ContinuousBatchingEngine(LLMEngine):
         self._mk_head = False           # whole-step mode: final norm +
         self._mk_vl = 0                 # lm_head + argmax in-kernel
         self.mk_tile_plan = None        # set by _build_mk_pack
+        # what a layer call of the paged decode attention kernel does at
+        # a full batch: one grid step a slot, a loop over the slot's
+        # live pages, operands by the pool's type (static;
+        # health()["paged_decode"])
+        self.paged_decode = None
+        if not self.megakernel and not all(g.row_width
+                                           for g in self.groups):
+            self.paged_decode = {
+                "grid_steps_per_layer": self.max_batch, "pages": "live",
+                "mm_operand_dtype": jnp.dtype(
+                    mxu_operands(self.kv_dtype)[0]).name}
         if self.megakernel:
             with _span("setup.engine.mk_pack"):
                 self._build_mk_pack()
@@ -1462,6 +1474,7 @@ class ContinuousBatchingEngine(LLMEngine):
                    "used_page_steps": g.used_page_steps,
                    "freed_behind_window": g.freed_behind_window,
                    "kv_tokens_read": g.kv_tokens_read,
+                   "kv_pages_walked": g.kv_pages_walked,
                    "prefill_pairs": g.prefill_pairs}
                   for g in self.groups]
         experts = sparse = None
@@ -1498,6 +1511,7 @@ class ContinuousBatchingEngine(LLMEngine):
             counters[f"group{i}.freed_behind_window"] = \
                 g["freed_behind_window"]
             counters[f"group{i}.kv_tokens_read"] = g["kv_tokens_read"]
+            counters[f"group{i}.kv_pages_walked"] = g["kv_pages_walked"]
             counters[f"group{i}.prefill_pairs"] = g["prefill_pairs"]
         if experts is not None:
             counters["experts.decode_steps"] = experts["decode_steps"]
@@ -1555,6 +1569,12 @@ class ContinuousBatchingEngine(LLMEngine):
             # per projection [bk, bn] and the grid steps of one layer
             # call at a full batch; None on the op-chain path. Static
             "mk_tile_plan": self.mk_tile_plan,
+            # the op chain's twin of it: what the paged decode attention
+            # kernel (ops/pallas/paged_attention.py) does a layer call at
+            # a full batch; None where no layer calls it (the
+            # megakernel, a description whose every layer keeps one row
+            # a token). Static
+            "paged_decode": self.paged_decode,
             # tensor parallelism (inference/tp.py): shard count, tail
             # mode, and whether the per-token reduce rides int8
             "tp": self.tp,
@@ -3170,13 +3190,19 @@ class ContinuousBatchingEngine(LLMEngine):
         for r in decodes:
             self._lens_np[r.slot] += 1
             self._group_release(r, int(self._lens_np[r.slot]))
+        p = self.page_size
         ctx = self._lens_np[[r.slot for r in decodes]]
         for g in self.groups:
             # cached tokens this step's queries read, layer by layer: the
             # whole context, or what the window leaves of it
-            g.kv_tokens_read += len(g.layers) * int(
-                (ctx if g.window is None
-                 else np.minimum(ctx, g.window)).sum())
+            seen = ctx if g.window is None else np.minimum(ctx, g.window)
+            g.kv_tokens_read += len(g.layers) * int(seen.sum())
+            if not g.row_width:
+                # K and V pools by page: the paged decode kernel walks a
+                # seat's live pages [first, last), the page of its
+                # oldest visible key to the page of its newest
+                g.kv_pages_walked += len(g.layers) * int(
+                    (-(-ctx // p) - (ctx - seen) // p).sum())
         if greedy:
             self._tok_dev = head[0]
         return _Dispatched(True, [(r, r.slot) for r in decodes], mode=mode,

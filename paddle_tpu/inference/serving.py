@@ -285,6 +285,8 @@ class PageGroup:
         self.freed_behind_window = 0    # lifetime, pages
         self.kv_tokens_read = 0         # lifetime: cached tokens x layers
         #                                 that decode queries attended to
+        self.kv_pages_walked = 0        # lifetime: pages x layers the
+        #                                 paged decode kernel visited
         self.prefill_pairs = 0          # lifetime: (query, key) pairs x
         #                                 layers of the prefill chunks
         self.used_page_steps = 0        # sum over steps of pages in use

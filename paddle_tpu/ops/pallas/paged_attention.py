@@ -17,11 +17,36 @@ Layout:
                                      entries past the sequence are ignored)
   seq_lens   : [b] int32            (tokens filled per sequence)
 
-Grid (b, max_pages): pages stream through VMEM via the innermost grid
-dimension with the BLOCK INDEX taken from the scalar-prefetched page table
-(pl.BlockSpec index maps read the prefetch refs), so only pages actually
-referenced are fetched — KV for a sequence is gathered page-by-page with
-online softmax in VMEM scratch, never materialized contiguously.
+Grid (b,): ONE grid step a slot. The pools stay in HBM
+(`memory_space=pl.ANY`); inside the step a loop walks the slot's LIVE
+logical pages only — [first, last), `last` = ceil(seq_len / p) and
+`first` = the page of the oldest key a window still shows (0 without a
+window), no trip at all for an inactive or empty slot — and the kernel
+makes its own copies. `live_walk` lays the live pages of ALL slots out as
+one list of page ids in scalar memory; the loop trip of live page i
+starts the copy of page i + 2 of that list (`COPIES_AHEAD`; three VMEM
+buffers a pool, taken in turn along the list), waits for page i and
+multiplies it, so two copies are in flight at any time, over the step
+boundaries too: the copy engine never waits for a slot to finish. A
+table entry outside a slot's [first, last) is never dereferenced and a
+page outside the live set never fetched. KV for a sequence is gathered
+page by page under an online softmax in VMEM scratch (float32), never
+materialized contiguously: the kernel's time follows the bytes of the
+pages the slots hold (about 740 GB/s of them at 128 tokens x 8 heads x
+128 on a v5e), not the table's width. A pool whose page Mosaic cannot
+slice out of HBM (`_copyable`: a 64-wide head, a lone bf16 KV head)
+walks the same live pages a grid step a page through the pipelined index
+map, under the same softmax.
+
+Operands reach the MXU in the POOL's type (`mxu_operands`): bf16 pools,
+as on the chip, give one MXU pass a product (the package-wide matmul
+precision "highest" reaches into kernels and would make six of a float32
+copy); float32 pools keep float32 operands at full precision, which is
+what every bit-identity test on the interpret path runs. A head's [p, d]
+matrix of a page comes out of the buffer by ONE strided load
+(`_head_matrices`), not by slicing the loaded page: a token's heads sit
+in the sublanes of one tile, and parting them as values cost the chip
+more than the page's copy.
 """
 import functools
 import math
@@ -70,44 +95,127 @@ def _sink_finish(m, l, acc, sink):
     return acc * beta, l * beta + jnp.exp(sink - m_fin)
 
 
-def _decode_kernel(page_table_ref, seq_lens_ref, active_ref, q_ref, k_ref,
-                   v_ref, *rest, p, d, n_pages_max, scale, rep=1,
-                   window=None, has_sink=False, k_flat=False):
-    sink_ref = rest[0] if has_sink else None
-    o_ref, m_scr, l_scr, acc_scr = rest[-4:]
-    b = pl.program_id(0)
-    pi = pl.program_id(1)
+def mxu_operands(pool_dtype):
+    """(operand dtype, precision) of the decode kernel's products, ONE
+    rule for per-head and flat keys: K and V reach the MXU with the bits
+    they are stored in. A bf16 pool: bf16 operands in one pass (named,
+    because the package-wide "highest" reaches into kernels and a float32
+    copy of a bf16 page takes six passes and changes no value of it);
+    what is rounded anew is q * scale and the softmax weights, as in
+    flash attention and `paged_chunk_attention`. Any other pool: float32
+    operands at full precision. Sums are float32 either way."""
+    if jnp.dtype(pool_dtype) == jnp.bfloat16:
+        return jnp.bfloat16, jax.lax.Precision.DEFAULT
+    return jnp.float32, jax.lax.Precision.HIGHEST
 
-    @pl.when(pi == 0)
-    def _init():
+
+def _head_matrices(buf_ref, buf, n_kv):
+    """The page in buffer `buf` of a [buffers, p, n_kv, width] ref as n_kv
+    [p, width] matrices, with NO shuffle of its rows. A token's heads sit
+    in the sublanes of one tile, so a per-head slice of the page is a row
+    out of every tile: sliced as a value, the chip spends thousands of
+    rotates and selects a page on it (PERF.md 6, PR 37: 1.3 us of a 1.37
+    us loop trip). Read instead through the buffer's 2-D view
+    [p * n_kv, width], where head g is the rows g, g + n_kv, ...: ONE
+    strided load. A bf16 buffer packs two heads into a 32-bit sublane
+    (heads 2j and 2j + 1 are the low and high halves of row j of a
+    token), so its view is uint32 [p * n_kv / 2, width] and a load brings
+    a PAIR of heads, parted by a shift and a mask: a bf16 is the high
+    half of its float32, so both come out float32 with exactly the bits
+    they were stored with."""
+    _, p, _, width = buf_ref.shape
+    packed = buf_ref.dtype == jnp.bfloat16
+    if width % 128 or (packed and n_kv % 2) or \
+            not (packed or buf_ref.dtype == jnp.float32):
+        # shapes Mosaic's strided load does not take (a width that is no
+        # multiple of the lanes, a lone packed head): slices of the
+        # loaded page, as the table walk took them
+        page = buf_ref[buf]
+        return [page[:, g, :] for g in range(n_kv)]
+    if not packed:
+        rows = buf_ref.reshape(buf_ref.shape[0], p * n_kv, width)
+        return [rows[buf, pl.ds(g, p, stride=n_kv), :] for g in range(n_kv)]
+    words = buf_ref.bitcast(jnp.uint32).reshape(
+        buf_ref.shape[0], p * n_kv // 2, width)
+    out = []
+    for j in range(n_kv // 2):
+        pair = words[buf, pl.ds(j, p, stride=n_kv // 2), :]
+        out += [jax.lax.bitcast_convert_type(pair << 16, jnp.float32),
+                jax.lax.bitcast_convert_type(
+                    pair & jnp.uint32(0xffff0000), jnp.float32)]
+    return out
+
+
+COPIES_AHEAD = 2    # page copies in flight while a page is multiplied
+#                     (one more buffer a pool than that): with one, the
+#                     copy engine idles from a copy's end to the next
+#                     trip's start, 610 against 738 GB/s of live pages at
+#                     the parallel block's shapes; three gave no more
+#                     (PERF.md 6, PR 37)
+
+
+def _slot_softmax(q_ref, sink_ref, o_ref, m_scr, l_scr, acc_scr, pool_dtype,
+                  n_kv, *, p, scale, rep, window, k_flat):
+    """(clear, query, fold, emit): one slot's online softmax over its
+    pages, the arithmetic both decode kernels share. `clear()` resets the
+    running maximum, sum and weighted values; `query()` is the query
+    operand; `fold(q, k_ref, v_ref, buf, page, seq_len)` folds logical
+    page `page`, held in buffer `buf` of the two refs, into them;
+    `emit()` closes the softmax (with the sink's logit in the
+    denominator) and writes the slot's rows."""
+    # NOTE: every integer the body compares or selects with is a typed
+    # int32 (interpret mode re-discharges the kernel under an outer jit,
+    # outside the enable_x64(False) window, where a weak python literal
+    # becomes int64: decode_megakernel.py has the long form)
+    i32 = jnp.int32
+    mxu, precision = mxu_operands(pool_dtype)
+
+    def clear():
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    seq_len = seq_lens_ref[b]
-    if window is None:
-        page_start = pi * p
-    else:
-        # a windowed layer walks only the pages its window touches: grid
-        # column pi is logical page first + pi (same rule as the index
-        # map), the query sits at seq_len - 1
-        page_start = (window_first_page(seq_len - window, p) + pi) * p
-    # whole page beyond the sequence — or a retired slot in a continuous-
-    # batching step (active == 0)? skip its compute (its DMA still
-    # happened — the table clamps to a valid page id, and an inactive
-    # slot's index map pins every page fetch to block 0)
-    run = jnp.logical_and(active_ref[b] > 0, page_start < seq_len)
+    def query():
+        # the scale applied in float32, the operand cast once
+        q = q_ref[0].astype(jnp.float32) * jnp.float32(scale)
+        if k_flat:
+            # flat keys: spread the rows over the h_kv * d lanes of a
+            # flat page, head i's d values in its kv head's stretch and
+            # zeros elsewhere, so that ONE product gives every head's
+            # logits with no per-head slice at a lane offset that is no
+            # multiple of 128 (built here: spread by XLA the query is
+            # h_kv times the bytes, 196 KB a slot a layer at 8 kv heads
+            # x 192)
+            zeros = jnp.zeros((rep, q.shape[1]), jnp.float32)
+            q = jnp.concatenate([
+                jnp.concatenate([q[g * rep:(g + 1) * rep] if col == g
+                                 else zeros for col in range(n_kv)], axis=1)
+                for g in range(n_kv)], axis=0)             # [h, h_kv * d]
+        return q.astype(mxu)
 
-    def _weigh(logits):
-        """Mask the page's [h, p] logits (positions past seq_len, and
-        behind the window: keys j with qpos - window < j <= qpos), fold
-        them into the running max and sum; returns (weights, the old
-        accumulators' rescale, the new max — stored by the caller after
-        the accumulator's update)."""
-        pos = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1) + page_start
+    def product(a, b, contract):
+        return jax.lax.dot_general(
+            a, b.astype(mxu), (contract, ((), ())),
+            precision=precision, preferred_element_type=jnp.float32)
+
+    def fold(q, k_ref, v_ref, buf, page, seq_len):
+        if k_flat:
+            logits = product(q, k_ref[buf], ((1,), (1,)))      # [h, p]
+        else:
+            # per-head contraction over d as unrolled 2-D dots (Mosaic's
+            # dot lowering rejects BATCHED dimension numbers). GQA-
+            # native: the rep query heads of kv head g share ONE
+            # [rep, d] x [d, p] dot against that head's keys
+            logits = jnp.concatenate([
+                product(q[g * rep:(g + 1) * rep], k_g, ((1,), (1,)))
+                for g, k_g in enumerate(_head_matrices(k_ref, buf, n_kv))],
+                axis=0)                                        # [h, p]
+        # mask positions past seq_len and behind the window (keys j with
+        # qpos - window < j <= qpos), fold into the running max and sum
+        pos = jax.lax.broadcasted_iota(i32, logits.shape, 1) + page * i32(p)
         ok = pos < seq_len
         if window is not None:
-            ok = jnp.logical_and(ok, pos >= seq_len - window)
+            ok = jnp.logical_and(ok, pos >= seq_len - i32(window))
         logits = jnp.where(ok, logits, jnp.float32(NEG_INF))
         m_prev = m_scr[:, :1]
         l_prev = l_scr[:, :1]
@@ -116,73 +224,152 @@ def _decode_kernel(page_table_ref, seq_lens_ref, active_ref, q_ref, k_ref,
         alpha = jnp.exp(m_prev - m_new)
         l_scr[...] = jnp.broadcast_to(
             alpha * l_prev + jnp.sum(w, axis=-1, keepdims=True), l_scr.shape)
-        return w, alpha, m_new
-
-    def _compute_flat():
-        # keys stored FLAT, a page is [p, h_kv * d], and the query rows
-        # arrive spread over the same h_kv * d lanes (head i's d values
-        # in its kv head's stretch, zeros elsewhere): ONE product gives
-        # every head's logits, no per-head slice at a lane offset that
-        # is no multiple of 128. Operands reach the MXU in the pool's
-        # dtype (bf16 on the chip: one pass, named here because the
-        # package-wide "highest" reaches into kernels), the scale
-        # applied in float32 first, sums in float32
-        mxu = jnp.bfloat16 if k_ref.dtype == jnp.bfloat16 else jnp.float32
-        q = (q_ref[0].astype(jnp.float32)
-             * jnp.float32(scale)).astype(mxu)              # [h, h_kv*d]
-        logits = jax.lax.dot_general(
-            q, k_ref[0].astype(mxu), (((1,), (1,)), ((), ())),
-            precision=jax.lax.Precision.DEFAULT,
-            preferred_element_type=jnp.float32)             # [h, p]
-        w, alpha, m_new = _weigh(logits)
-        v = v_ref[0].astype(mxu)                            # [p, h_kv, dv]
+        # [h, dv] accumulation: sum_p w[h, p] * v[p, kv head of h, dv]
+        w = w.astype(mxu)
         acc_scr[...] = alpha * acc_scr[...] + jnp.concatenate([
-            jax.lax.dot_general(
-                w[g * rep:(g + 1) * rep].astype(mxu), v[:, g, :],
-                (((1,), (0,)), ((), ())),
-                precision=jax.lax.Precision.DEFAULT,
-                preferred_element_type=jnp.float32)         # [rep, dv]
-            for g in range(v.shape[1])], axis=0)
+            product(w[g * rep:(g + 1) * rep], v_g, ((1,), (0,)))
+            for g, v_g in enumerate(_head_matrices(v_ref, buf, n_kv))],
+            axis=0)                                            # [h, dv]
         m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
 
-    def _compute():
-        q = q_ref[0].astype(jnp.float32) * jnp.float32(scale)  # [h, d]
-        k = k_ref[0].astype(jnp.float32)                       # [p, h, d]
-        v = v_ref[0].astype(jnp.float32)
-        # [h, p] logits: per-head contraction over d. Unrolled 2-D dots
-        # over the head dim — Mosaic's dot lowering rejects BATCHED
-        # dot_general dimension numbers (caught by the round-5 TPU
-        # lowering sweep, tests/test_mosaic_lowering.py); h is small and
-        # static at decode, so the unroll is free.
-        # GQA-native: q heads [g*rep, (g+1)*rep) attend kv head g — the
-        # cache stays at h_kv heads (1/rep the HBM of an expanded cache)
-        # and the rep heads of a group share ONE [rep, d] x [d, p] dot
-        # (single-row dots would waste MXU rows, code-review r5).
-        # Per-head SLICES (k[:, g]) rather than a swapaxes of the whole
-        # block: Mosaic's transpose lowering rejects the 3-D permutation
-        # on older toolchains, the slice lowers everywhere.
-        h_kv = k.shape[1]
-        logits = jnp.concatenate([
-            jax.lax.dot_general(
-                q[g * rep:(g + 1) * rep], k[:, g, :],
-                (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)            # [rep, p]
-            for g in range(h_kv)], axis=0)                     # [h, p]
-        w, alpha, m_new = _weigh(logits)
-        # [h, d] accumulation: sum_p w[h, p] * v[p, h_kv, d]
-        acc_scr[...] = alpha * acc_scr[...] + wv_diag(w, v, d, rep=rep)
-        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-
-    pl.when(run)(_compute_flat if k_flat else _compute)
-
-    @pl.when(pi == n_pages_max - 1)
-    def _emit():
+    def emit():
+        # a slot with no live page leaves l = 0, acc = 0: exact zeros
         acc, l_fin = acc_scr[...], l_scr[:, :1]
-        if has_sink:
+        if sink_ref is not None:
             acc, l_fin = _sink_finish(m_scr[:, :1], l_fin, acc,
                                       sink_ref[:, :1])
         l_fin = jnp.maximum(l_fin, jnp.float32(1e-30))
         o_ref[0] = (acc / l_fin).astype(o_ref.dtype)
+
+    return clear, query, fold, emit
+
+
+def _decode_kernel(pages_ref, walk_ref, q_ref, k_hbm, v_hbm, *rest,
+                   has_sink=False, **how):
+    """One grid step a slot, a loop over its live pages, the kernel's
+    own copies from the pools in HBM (the module's docstring)."""
+    sink_ref = rest[0] if has_sink else None
+    o_ref, m_scr, l_scr, acc_scr, kbuf, vbuf, sem = rest[-7:]
+    i32 = jnp.int32
+    slot = pl.program_id(0)
+    n_buf = kbuf.shape[0]
+    clear, query, fold, emit = _slot_softmax(
+        q_ref, sink_ref, o_ref, m_scr, l_scr, acc_scr, kbuf.dtype,
+        vbuf.shape[2], **how)
+    # the slot's column of `live_walk`: its live logical pages are
+    # [first, last), walked in ascending order, and its first page is
+    # number `start` of the `total` live pages of all slots
+    seq_len, first, last, start, total = (
+        walk_ref[i, slot] for i in range(5))
+
+    def page_copies(index):
+        # live page number `index` of the whole walk, pool (HBM) -> the
+        # buffer its number gives it: the buffers of a pool are taken in
+        # turn along the walk, whatever slot a page belongs to. The SAME
+        # descriptors start a copy and wait on it
+        pg = pages_ref[index]
+        buf = jax.lax.rem(index, i32(n_buf))
+        return (pltpu.make_async_copy(k_hbm.at[pg], kbuf.at[buf],
+                                      sem.at[0, buf]),
+                pltpu.make_async_copy(v_hbm.at[pg], vbuf.at[buf],
+                                      sem.at[1, buf]))
+
+    def start_page(index):
+        @pl.when(index < total)
+        def _():
+            for cp in page_copies(index):
+                cp.start()
+
+    # the walk's first pages have no trip before them to start their
+    # copies: the step of the first slot that has a page does
+    @pl.when(jnp.logical_and(start == 0, first < last))
+    def _():
+        for ahead in range(n_buf - 1):
+            start_page(i32(ahead))
+
+    clear()
+    q = query()
+
+    def _page(page, carry):
+        index = start + page - first
+        # the copies of the NEXT live pages of the walk fly while this
+        # one is multiplied, this slot's or a later slot's alike: copies
+        # are in flight over every step boundary
+        start_page(index + i32(n_buf - 1))
+        for cp in page_copies(index):
+            cp.wait()
+        fold(q, kbuf, vbuf, jax.lax.rem(index, i32(n_buf)), page, seq_len)
+        return carry
+
+    jax.lax.fori_loop(first, last, _page, i32(0))
+    emit()
+
+
+def _decode_kernel_blocks(table_ref, walk_ref, q_ref, k_ref, v_ref, *rest,
+                          n_grid, has_sink=False, **how):
+    """The same walk for pools whose pages the kernel cannot copy itself
+    (`_copyable`): a grid step a (slot, page of its walk), the page
+    brought by Pallas' own pipeline through the index map. The steps
+    past a slot's last live page compute nothing and, their index map
+    staying on that page, fetch nothing new."""
+    sink_ref = rest[0] if has_sink else None
+    o_ref, m_scr, l_scr, acc_scr = rest[-4:]
+    slot, pi = pl.program_id(0), pl.program_id(1)
+    clear, query, fold, emit = _slot_softmax(
+        q_ref, sink_ref, o_ref, m_scr, l_scr, acc_scr, k_ref.dtype,
+        v_ref.shape[2], **how)
+    seq_len, first, last = (walk_ref[i, slot] for i in range(3))
+
+    @pl.when(pi == 0)
+    def _():
+        clear()
+
+    @pl.when(first + pi < last)
+    def _():
+        fold(query(), k_ref, v_ref, 0, first + pi, seq_len)
+
+    @pl.when(pi == n_grid - 1)
+    def _():
+        emit()
+
+
+def live_walk(page_table, seq_lens, active, window, p):
+    """What the decode kernel reads of the walk, two int32 arrays for its
+    scalar memory.
+
+    `walk` [5, b], a column a slot: (seq_len, first, last, start, total).
+    The slot's live logical pages are [first, last): `last` =
+    ceil(seq_len / p), `first` the page of the oldest key a window still
+    shows (0 without one); none for a retired slot of a continuous-
+    batching step (active == 0: seq_len 0) or an empty one. `start` is
+    the number of the slot's first page in the walk of ALL slots' live
+    pages, in slot order, `total` their count.
+
+    `pages` [b * max_pages]: the page id of live page number i of that
+    walk, which is all the kernel reads of the table: it copies page
+    i + 2 while it multiplies page i without asking which slot either
+    belongs to. Entries from `total` on are never read by the kernel,
+    and no table entry outside a slot's [first, last) reaches an entry
+    before it."""
+    b, max_pages = page_table.shape
+    seq_len = seq_lens.astype(jnp.int32)
+    if active is not None:
+        seq_len = jnp.where(active.astype(jnp.int32) > 0, seq_len, 0)
+    last = jnp.minimum(-(-seq_len // p), max_pages)
+    first = jnp.zeros_like(last) if window is None else \
+        window_first_page(seq_len - window, p)
+    n_live = jnp.maximum(last - first, 0)
+    end = jnp.cumsum(n_live, dtype=jnp.int32)
+    start = end - n_live
+    number = jnp.arange(b * max_pages, dtype=jnp.int32)
+    # the slot of live page i: how many slots end at or before i
+    slot = jnp.minimum(jnp.sum(number[:, None] >= end[None, :], axis=1,
+                               dtype=jnp.int32), b - 1)
+    column = jnp.clip(first[slot] + number - start[slot], 0, max_pages - 1)
+    pages = page_table.astype(jnp.int32)[slot, column]
+    walk = jnp.stack([seq_len, first, last, start,
+                      jnp.broadcast_to(end[-1], (b,))])
+    return pages, walk.astype(jnp.int32)
 
 
 def wv_diag(w, v, d, rep=1):
@@ -230,17 +417,20 @@ def paged_attention(q, k_pages, v_pages, page_table, seq_lens, scale=None,
     the key width d. page_table: [b, max_pages] int32; seq_lens: [b]
     int32.
 
+    One grid step a slot; inside it a loop over the slot's LIVE pages
+    [first, last) with the kernel's own copies from the pools in HBM,
+    two in flight (the module's docstring): table entries outside that
+    range are never dereferenced and may hold anything.
+
     active: optional [b] mask (bool/int) for continuous batching — slots
-    whose request has retired stay in the batch shape but skip every
-    page's compute AND every page fetch (the index map pins their DMA to
-    block 0), so a mostly-drained decode batch costs roughly its live
-    rows. None means all slots live. Inactive rows emit zeros.
+    whose request has retired stay in the batch shape, walk no page and
+    fetch none, so a mostly-drained decode batch costs its live rows.
+    None means all slots live. Inactive and empty rows emit exact zeros.
 
     window: None = every key up to the query (causal); W = a sliding
     window, the query at position seq_len - 1 sees keys j with
-    qpos - W < j <= qpos. The grid then walks only the
-    ceil(W / p) + 1 logical pages the window can touch, whatever
-    max_pages is; table entries behind the window are never read (the
+    qpos - W < j <= qpos: the walk starts at the page of key
+    seq_len - W, and table entries behind the window are never read (the
     engine frees those pages).
     sinks: optional [h] learned per-head sink logits, added to the
     softmax's denominator only (out = sum_j e^{l_j} v_j / (sum_j e^{l_j}
@@ -269,79 +459,95 @@ def paged_attention(q, k_pages, v_pages, page_table, seq_lens, scale=None,
                                                      v_pages.shape)
     rep = h // h_kv
     max_pages = page_table.shape[1]
-    s = scale if scale is not None else 1.0 / math.sqrt(d)
-    n_grid = max_pages if window is None else \
-        min(max_pages, -(-int(window) // p) + 1)
-
-    # clamp table entries so skipped pages still index a real page
-    table = jnp.clip(page_table.astype(jnp.int32), 0, n_pages - 1)
-    lens = seq_lens.astype(jnp.int32)
-    if active is None:
-        act = jnp.ones((b,), jnp.int32)
-    else:
-        act = active.astype(jnp.int32)
-
-    def page_of(bb, pi, tbl, ln, ac):
-        if window is not None:
-            pi = jnp.minimum(window_first_page(ln[bb] - window, p) + pi,
-                             max_pages - 1)
-        return (tbl[bb, pi] * ac[bb], 0, 0, 0)
-
-    kernel = functools.partial(_decode_kernel, p=p, d=d,
-                               n_pages_max=n_grid, scale=s, rep=rep,
-                               window=window, has_sink=sinks is not None,
-                               k_flat=k_flat)
-    if k_flat:
-        # head i's d values into the lanes of kv head i // rep
-        onehot = (jnp.arange(h)[:, None] // rep
-                  == jnp.arange(h_kv)[None, :]).astype(q.dtype)
-        q = (q[:, :, None, :] * onehot[None, :, :, None]).reshape(
-            b, h, h_kv * d)
-        q_block, k_block = (1, h, h_kv * d), (1, p, h_kv * d)
-        page3 = lambda *a: page_of(*a)[:3]      # noqa: E731
-    else:
-        q_block, k_block, page3 = (1, h, d), (1, p, h_kv, d), page_of
-    in_specs = [
-        pl.BlockSpec(q_block, lambda bb, pi, tbl, ln, ac: (bb, 0, 0)),
-        pl.BlockSpec(k_block, page3),
-        pl.BlockSpec((1, p, h_kv, dv), page_of),
-    ]
-    args = [table, lens, act, q, k_pages, v_pages]
-    if sinks is not None:
-        in_specs.append(pl.BlockSpec(
-            (h, 128), lambda bb, pi, tbl, ln, ac: (0, 0)))
-        args.append(_sink_rows(sinks, h))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(b, n_grid),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, h, dv),
-                               lambda bb, pi, tbl, ln, ac: (bb, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((h, 128), jnp.float32),
-            pltpu.VMEM((h, 128), jnp.float32),
-            pltpu.VMEM((h, dv), jnp.float32),
-        ],
-    )
+    how = dict(p=p, scale=scale if scale is not None else 1.0 / math.sqrt(d),
+               rep=rep, window=window, k_flat=k_flat,
+               has_sink=sinks is not None)
+    pages, walk = live_walk(page_table, seq_lens, active, window, p)
+    k_page, v_page = k_pages.shape[1:], v_pages.shape[1:]
     f32 = jnp.float32
-    limit = vmem_limit(
-        blocks=[((h, d), q.dtype), ((h, dv), q.dtype),
-                ((p, h_kv, d), k_pages.dtype),
-                ((p, h_kv, dv), v_pages.dtype)],
-        scratch=[((h, 128), f32)] * 2 + [((h, dv), f32)],
-        temps=[((p, h_kv, d), f32), ((p, h_kv, dv), f32)])
+    softmax_scratch = [((h, 128), f32), ((h, 128), f32), ((h, dv), f32)]
+    # the body's [h, p] logits, weights and mask, a head's matrix of the
+    # page in float32 and in the operand type, the spread query
+    temps = [((h, p), f32)] * 4 + [((p, max(d, dv)), f32)] * 3 \
+        + [((h, h_kv * d), f32)] * 2 * bool(k_flat)
+    if _copyable(k_pages, interpret) and _copyable(v_pages, interpret):
+        kernel = functools.partial(_decode_kernel, **how)
+        grid = (b,)
+        # the pools stay in HBM: the kernel copies the live pages itself
+        pools = [pl.BlockSpec(memory_space=pl.ANY)] * 2
+        scalars = [pages, walk]
+        scratch = softmax_scratch + [
+            ((COPIES_AHEAD + 1,) + k_page, k_pages.dtype),
+            ((COPIES_AHEAD + 1,) + v_page, v_pages.dtype)]
+        semaphores = [pltpu.SemaphoreType.DMA((2, COPIES_AHEAD + 1))]
+        # the page buffers of a pool are scratch: counted once
+        limit = vmem_limit(blocks=[((h, d), q.dtype), ((h, dv), q.dtype)],
+                           scratch=scratch, temps=temps)
+        # in order: a slot's trips start the next slots' copies
+        semantics = ("arbitrary",)
+    else:
+        n_grid = max_pages if window is None else \
+            min(max_pages, -(-int(window) // p) + 1)
+        kernel = functools.partial(_decode_kernel_blocks, n_grid=n_grid,
+                                   **how)
+        grid = (b, n_grid)
+
+        def page_of(bb, pi, tbl, wk):
+            # the slot's live page first + pi; past its last one the
+            # index stays put (no new fetch). The table is clipped: the
+            # column of a slot without a page may name none
+            column = jnp.minimum(wk[1, bb] + pi,
+                                 jnp.maximum(wk[2, bb] - 1, wk[1, bb]))
+            return (tbl[bb, jnp.minimum(column, max_pages - 1)], 0, 0, 0)
+
+        pools = [pl.BlockSpec((1,) + k_page,
+                              (lambda *a: page_of(*a)[:3]) if k_flat
+                              else page_of),
+                 pl.BlockSpec((1,) + v_page, page_of)]
+        scalars = [jnp.clip(page_table.astype(jnp.int32), 0, n_pages - 1),
+                   walk]
+        scratch, semaphores = softmax_scratch, []
+        limit = vmem_limit(
+            blocks=[((h, d), q.dtype), ((h, dv), q.dtype),
+                    (k_page, k_pages.dtype), (v_page, v_pages.dtype)],
+            scratch=scratch,
+            temps=temps + [(k_page, f32), (v_page, f32)])
+        semantics = ("parallel", "arbitrary")
+    at_slot = lambda bb, *_: (bb, 0, 0)                 # noqa: E731
+    in_specs = [pl.BlockSpec((1, h, d), at_slot)] + pools
+    args = scalars + [q, k_pages, v_pages]
+    if sinks is not None:
+        in_specs.append(pl.BlockSpec((h, 128), lambda *a: (0, 0)))
+        args.append(_sink_rows(sinks, h))
     with jax.enable_x64(False):
-        out = pl.pallas_call(
+        return pl.pallas_call(
             kernel,
-            grid_spec=grid_spec,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2,
+                grid=grid,
+                in_specs=in_specs,
+                out_specs=pl.BlockSpec((1, h, dv), at_slot),
+                scratch_shapes=[pltpu.VMEM(*x) for x in scratch]
+                + semaphores),
             out_shape=jax.ShapeDtypeStruct((b, h, dv), q.dtype),
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary"),
-                vmem_limit_bytes=limit),
+                dimension_semantics=semantics, vmem_limit_bytes=limit),
             interpret=interpret,
             name="paged_attention_decode",
         )(*args)
-    return out
+
+
+def _copyable(pool, interpret):
+    """Can a kernel copy ONE page of this pool out of HBM by itself?
+    Mosaic takes a dynamic slice of a ref only in whole tiles: the minor
+    dimension a multiple of the 128 lanes, and of a 16-bit pool an even
+    number of rows in the dimension before it (two share a sublane). A
+    64-wide head or a lone bf16 KV head (multi-query attention, or one
+    head a shard under tp) is not: such a pool's pages come through the
+    pipelined index map (`_decode_kernel_blocks`). Under interpret every
+    pool is."""
+    return interpret or (pool.shape[-1] % 128 == 0 and (
+        pool.dtype.itemsize >= 4 or pool.shape[-2] % 2 == 0))
 
 
 def ragged_causal_mask(shape, tq, q_start, page_start, ctx_len,

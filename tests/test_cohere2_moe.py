@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 import paddle_tpu as paddle
+from paddle_tpu import profiler
 from paddle_tpu.inference import ContinuousBatchingEngine
 from paddle_tpu.inference import sparse_heads
 from paddle_tpu.inference.description import (AttentionSpec, IndexerSpec,
@@ -622,8 +623,21 @@ def test_two_groups_of_one_head_shape_that_differ_only_in_window(
     # 8 tokens a query, 2 full layers the whole context
     steps = 3 * 6 - 3           # a request's first token is its prefill's
     assert hw["kv_tokens_read"] == 6 * 8 * steps
-    ctx = sum(n + j for n in (40, 9, 25) for j in range(1, 6))
-    assert hf["kv_tokens_read"] == 2 * ctx
+    ctxs = [n + j for n in (40, 9, 25) for j in range(1, 6)]
+    assert hf["kv_tokens_read"] == 2 * sum(ctxs)
+    # pages the paged decode kernel walked (pages of 4): a query's live
+    # pages [first, last) a layer, from the same lengths; beside the
+    # tokens read they give the walk's overhead in page bytes
+    assert hf["kv_pages_walked"] == 2 * sum(-(-c // 4) for c in ctxs)
+    assert hw["kv_pages_walked"] == 6 * sum(
+        -(-c // 4) - (c - 8) // 4 for c in ctxs)
+    eng.health()                # samples the engine's counters
+    sample = profiler.counter_history("engine")[-1][1]
+    assert sample["group0.kv_pages_walked"] == hw["kv_pages_walked"]
+    assert sample["group1.kv_pages_walked"] == hf["kv_pages_walked"]
+    assert h["paged_decode"] == {
+        "grid_steps_per_layer": 2, "pages": "live",
+        "mm_operand_dtype": "float32"}
     assert h["experts"]["decode_steps"] == eng.decode_steps
 
 

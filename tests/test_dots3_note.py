@@ -171,6 +171,9 @@ def test_engine_counts_the_selection_and_leaks_no_page(served):
             win["window"]) == ("latent", 40, 0, 13)
     assert h["pages_free"] == h["pages_total"]
     assert win["freed_behind_window"] > 0
+    # no layer runs the paged decode kernel: nothing walked, no plan
+    assert h["paged_decode"] is None
+    assert full["kv_pages_walked"] == win["kv_pages_walked"] == 0
     # decode queries of the two full layers: position t sees t + 1 keys
     # and attends to min(t + 1, 12) of them
     visible = attended = queries = 0

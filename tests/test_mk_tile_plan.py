@@ -377,9 +377,16 @@ class TestEngineReportsThePlan:
         # 2 slots: a step each, not one per column of the 6-wide table
         assert plan["layer_steps"] == {"matmul": 7, "attention": 2}
         assert plan["attention_pages"] == "live"
+        assert eng.health()["paged_decode"] is None
 
     def test_op_chain_reports_none(self, tiny):
         eng = ContinuousBatchingEngine(
             tiny, max_len=48, page_size=8, max_batch=2, quant="int8",
             slot_buckets=(2,), megakernel=False)
-        assert eng.health()["mk_tile_plan"] is None
+        h = eng.health()
+        assert h["mk_tile_plan"] is None
+        # the op chain's twin of the plan (PR 37): the paged decode
+        # kernel takes a grid step a slot and walks its live pages
+        assert h["paged_decode"] == {
+            "grid_steps_per_layer": 2, "pages": "live",
+            "mm_operand_dtype": "float32"}

@@ -158,15 +158,43 @@ class TestOtherKernelsLowering:
                 lambda a, b_: jnp.sum(rms(a, b_, 1e-6) ** 2),
                 argnums=(0, 1))(x_, w_), x, w)
 
-    def test_paged_attention_decode(self):
+    @pytest.mark.parametrize(
+        "b,cols,h,h_kv,d,dv,p,flat,window,sinks", [
+            (4, 8, 8, 8, 128, 128, 16, False, None, False),
+            # serve-parallel-window-ragdoc: 128Q / 8KV x 128, its full
+            # layer and its window layers
+            (48, 128, 128, 8, 128, 128, 128, False, None, False),
+            (48, 128, 128, 8, 128, 128, 128, False, 4096, False),
+            # serve-moe-window-mixedlen: 64Q x 192 / 128, flat keys
+            (128, 32, 64, 4, 192, 128, 128, True, None, True),
+            (128, 32, 64, 8, 192, 128, 128, True, 128, True),
+            # pools whose pages Mosaic cannot slice out of HBM (a 64-wide
+            # head, a lone bf16 KV head): the pipelined transport
+            (8, 8, 16, 4, 64, 64, 128, False, None, False),
+            (8, 8, 16, 1, 128, 128, 128, False, 256, True),
+        ], ids=["small", "ragdoc_full", "ragdoc_window4096",
+                "mixedlen_full_4kv", "mixedlen_window128_8kv",
+                "blocks_head64", "blocks_one_kv_head"])
+    def test_paged_attention_decode(self, b, cols, h, h_kv, d, dv, p, flat,
+                                    window, sinks):
+        """One grid step a slot, the pools in HBM, the kernel's own page
+        copies and strided loads of a head's rows out of the page buffers
+        (PR 37), at the geometries of the two cells that run this
+        kernel; and the pipelined transport of the pools it cannot
+        copy."""
         from paddle_tpu.ops.pallas.paged_attention import paged_attention
-        b, h, d, p, n_pages, max_pages = 4, 8, 128, 16, 32, 8
+        n_pages = 64
         q = _sds((b, h, d), jnp.bfloat16)
-        pages = _sds((n_pages, p, h, d), jnp.bfloat16)
-        table = _sds((b, max_pages), jnp.int32)
+        kp = _sds((n_pages, p, h_kv * d) if flat else (n_pages, p, h_kv, d),
+                  jnp.bfloat16)
+        vp = _sds((n_pages, p, h_kv, dv), jnp.bfloat16)
+        table = _sds((b, cols), jnp.int32)
         lens = _sds((b,), jnp.int32)
 
-        _lower_tpu(paged_attention, q, pages, pages, table, lens)
+        _lower_tpu(lambda q_, k_, v_, t_, l_, a_, s_: paged_attention(
+            q_, k_, v_, t_, l_, active=a_, window=window,
+            sinks=s_ if sinks else None, k_flat=flat),
+            q, kp, vp, table, lens, lens, _sds((h,), jnp.float32))
 
     def test_quantized_matmul_int8(self):
         from paddle_tpu.ops.pallas.quantized_matmul import quantized_matmul

@@ -227,6 +227,9 @@ ENGINE_HEALTH_KEYS = frozenset({
     "mm_operand_dtype",
     # PR 27: the megakernel's tile plan, static (None on the op chain)
     "mk_tile_plan",
+    # PR 37: what the paged decode attention kernel does a layer call,
+    # static (None under the megakernel or where no layer calls it)
+    "paged_decode",
 })
 
 ROUTER_HEALTH_KEYS = frozenset({

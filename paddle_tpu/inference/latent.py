@@ -11,9 +11,11 @@ scores the cached rows directly, W_uv and the gate come after the sum.
                  positions, gathers THOSE rows by page table and attends
                  to them; a WINDOW layer gathers the pages its window
                  touches.
-  prefill_layer  one chunk of one sequence over key blocks of the
-                 sequence's LIVE pages (online softmax): no tensor
-                 against all `pages_per_seq` pages exists. A full
+  prefill_layer  one chunk of one sequence over the sequence's LIVE
+                 pages (online softmax): the Pallas kernel
+                 `paged_latent_chunk_attention`, or where Mosaic cannot
+                 tile the shape the XLA key blocks (`key_blocks`); no
+                 tensor against all `pages_per_seq` pages exists. A full
                  layer first fills a [chunk, max_len] float32 buffer of
                  index scores block by block and marks each query's top-k
                  in it (a radix select for the k-th value, no sort).
@@ -34,6 +36,8 @@ import jax.numpy as jnp
 
 from ..ops import latent_attention as la
 from ..ops import sparse_attention as sa
+from ..ops.pallas.chunk_attention import (latent_plan,
+                                          paged_latent_chunk_attention)
 from ..ops.sparse_attention import SPARSE_COUNTS
 from ..profiler import phase
 from .serving import _rms
@@ -82,6 +86,44 @@ def decode_selection(ix_pool, tab, q_i, w_i, n_vis, active, ix, p):
     return idx, valid, counts
 
 
+def selection_width(mp, p):
+    """Columns of a chunk's selection for a table of mp pages of p: whole
+    key blocks."""
+    return -(-mp // KEY_BLOCK_PAGES) * KEY_BLOCK_PAGES * p
+
+
+def prefill_plan(eng, li, chunk):
+    """What runs the chunk attention of latent layer li: the Pallas
+    kernel's plan (`chunk_attention.latent_plan`), or None where it cannot
+    tile the shape and the XLA key blocks run. Read off the shapes."""
+    a = eng.desc.layers[li].attn
+    g = eng.groups[eng.desc.layer_group[li]]
+    masked = a.indexer is not None
+    return latent_plan(
+        chunk, a.n_heads, g.row_pad, a.latent.kv_rank, eng.page_size,
+        eng.kv_dtype, None if masked else a.window,
+        selection_width(eng.pages_per_seq, eng.page_size) if masked
+        else None, eng.interpret)
+
+
+def prefill_plans(eng):
+    """{page group index: `prefill_plan` of its layers} over the latent
+    groups (a group's layers share their shape)."""
+    return {g.index: prefill_plan(eng, g.layers[0], eng.prefill_chunk)
+            for g in eng.groups if g.latent}
+
+
+def prefill_facts(eng, plans):
+    """{"full" | "window": what a chunk of the group's layers runs: the
+    kernel or the fallback, its query block, the pages a grid step covers
+    and its VMEM limit}, None without a latent layer (static;
+    `health()["latent_prefill"]`). plans: `prefill_plans(eng)`."""
+    return {"full" if eng.groups[i].window is None else "window": {
+        "kernel": "attend_key_blocks" if plan is None
+        else "paged_latent_chunk_attention", **(plan or {})}
+        for i, plan in plans.items()} or None
+
+
 def prefill_selection(ix_pool, tab, q_i, w_i, qpos, hi_blk, ix, p):
     """A chunk's queries (q_i [chunk, Hi, di], w_i [chunk, Hi], at qpos
     [chunk, 1]) over the sequence's LIVE index-key blocks 0..hi_blk-1:
@@ -99,12 +141,37 @@ def prefill_selection(ix_pool, tab, q_i, w_i, qpos, hi_blk, ix, p):
                 buf, sc, (jnp.zeros((), j.dtype), j * kb))
 
         # columns past the live blocks stay -inf: not visible
-        width = -(-tab.shape[0] // KEY_BLOCK_PAGES) * kb
+        width = selection_width(tab.shape[0], p)
         buf = jax.lax.fori_loop(
             0, hi_blk, score_block,
             jnp.full((chunk, width), -jnp.inf, jnp.float32))
     with jax.named_scope("sparse_select"):
         return sa.top_mask(buf, ix.top_k)
+
+
+def key_blocks(rows_pool, tab, pos, t_end, p, window=None, chosen=None):
+    """(block, lo, hi) for `la.attend_key_blocks`: a chunk at positions pos
+    [chunk] over key blocks of the sequence's live pages, from the block
+    the first query's window starts in (0 in a full layer) to that of the
+    chunk's last real position, masked by causality and the window or the
+    selection chosen [chunk, width]. The fallback of the Pallas kernel,
+    and its reference in tests."""
+    chunk, kb = pos.shape[0], KEY_BLOCK_PAGES * p
+    qpos = pos[:, None]
+    last = jnp.minimum(pos[0] + chunk, t_end) - 1       # a real position
+
+    def block(j):
+        pages, kpos = block_pages(tab, j, p)
+        seen = kpos[None, :] <= qpos
+        if window is not None:
+            seen = seen & (kpos[None, :] > qpos - window)
+        if chosen is not None:
+            seen = seen & jax.lax.dynamic_slice(
+                chosen, (jnp.zeros((), j.dtype), j * kb), (chunk, kb))
+        return rows_pool[pages].reshape(kb, -1), seen
+
+    lo = 0 if window is None else jnp.maximum(pos[0] - window + 1, 0) // kb
+    return block, lo, last // kb + 1
 
 
 def _front(eng, W, wset, h, pos_ids, li):
@@ -215,37 +282,29 @@ def prefill_layer(eng, W, wset, h, rows_pool, ix_pool, tab, pos, t_end, li):
         if a.indexer is not None:
             ix_pool = _write(ix_pool, slots, ix[1][0])
     with phase("attend"):
-        kb = KEY_BLOCK_PAGES * p
-        qpos = pos[:, None]
-        last = jnp.minimum(pos[0] + chunk, t_end) - 1  # last real position
+        plan = prefill_plan(eng, li, chunk)
+        chosen = None
         if a.indexer is not None:
             q_i, _, w_i = ix
-            hi_blk = last // kb + 1
-            chosen = prefill_selection(ix_pool, tab, q_i[0], w_i[0], qpos,
-                                       hi_blk, a.indexer, p)
-
-            def block(j):
-                pages, kpos = block_pages(tab, j, p)
-                sel = jax.lax.dynamic_slice(
-                    chosen, (jnp.zeros((), j.dtype), j * kb), (chunk, kb))
-                return rows_pool[pages].reshape(kb, -1), \
-                    sel & (kpos[None, :] <= qpos)
-
-            lo_blk, scope = 0, "sparse_attend"
-        else:
-            def block(j):
-                pages, kpos = block_pages(tab, j, p)
-                return rows_pool[pages].reshape(kb, -1), \
-                    (kpos[None, :] <= qpos) \
-                    & (kpos[None, :] > qpos - a.window)
-
-            # from the page the first query's window starts in
-            lo_blk = jnp.maximum(pos[0] - a.window + 1, 0) // kb
-            hi_blk, scope = last // kb + 1, "window_latent_attend"
-        with jax.named_scope(scope):
-            o_lat = la.attend_key_blocks(
-                q_abs[0], block, lo_blk, hi_blk, a.latent.kv_rank,
-                la.softmax_scale(a))
+            last = jnp.minimum(pos[0] + chunk, t_end) - 1  # a real position
+            chosen = prefill_selection(
+                ix_pool, tab, q_i[0], w_i[0], pos[:, None],
+                last // (KEY_BLOCK_PAGES * p) + 1, a.indexer, p)
+        window = None if chosen is not None else a.window
+        with jax.named_scope("sparse_attend" if chosen is not None
+                             else "window_latent_attend"):
+            if plan is not None:
+                # the chunk's live pages straight out of the pool, logits
+                # and the running sum in VMEM
+                o_lat = paged_latent_chunk_attention(
+                    q_abs[0], rows_pool, tab, pos[0], t_end,
+                    a.latent.kv_rank, la.softmax_scale(a), window=window,
+                    chosen=chosen, plan=plan, interpret=eng.interpret)
+            else:
+                o_lat = la.attend_key_blocks(
+                    q_abs[0], *key_blocks(rows_pool, tab, pos, t_end, p,
+                                          window, chosen),
+                    a.latent.kv_rank, la.softmax_scale(a))
     with phase("attn_proj"):
         o = la.expand_values(o_lat[None], wset["w_uv"], a)
         return _gated(o, gate, eng.kv_dtype), rows_pool, ix_pool

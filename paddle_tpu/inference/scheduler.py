@@ -58,6 +58,7 @@ from .description import UnsupportedByDescription
 from .serving import LLMEngine, EngineFullError, _rms, _mm, _mm_f32
 from .speculative import resolve_drafter
 
+from ..ops.pallas.chunk_attention import latent_live_steps
 from ..ops.pallas.paged_attention import (expand_kv_heads, mxu_operands,
                                           paged_attention,
                                           ragged_paged_attention,
@@ -706,6 +707,13 @@ class ContinuousBatchingEngine(LLMEngine):
                 "grid_steps_per_layer": self.max_batch, "pages": "live",
                 "mm_operand_dtype": jnp.dtype(
                     mxu_operands(self.kv_dtype)[0]).name}
+        # what a chunk of a latent layer's attention runs, the full and
+        # the window geometry: the Pallas kernel's query block, pages a
+        # grid step and VMEM limit, or the XLA key blocks (static;
+        # health()["latent_prefill"]); the kernel's plan by page group
+        self._latent_plans = latent.prefill_plans(self)
+        self.latent_prefill = latent.prefill_facts(self, self._latent_plans)
+        self.latent_prefill_live_steps = 0
         if self.megakernel:
             with _span("setup.engine.mk_pack"):
                 self._build_mk_pack()
@@ -1520,6 +1528,9 @@ class ContinuousBatchingEngine(LLMEngine):
         if sparse is not None:
             for k, v in sparse.items():
                 counters[f"sparse.{k}"] = v
+        if self.latent_prefill is not None:
+            counters["latent.prefill_live_steps"] = \
+                self.latent_prefill_live_steps
         record_counters("engine", counters)
         return {
             "queued": len(self._queue),
@@ -1575,6 +1586,11 @@ class ContinuousBatchingEngine(LLMEngine):
             # megakernel, a description whose every layer keeps one row
             # a token). Static
             "paged_decode": self.paged_decode,
+            # what a latent layer's prefill chunk runs, by geometry: the
+            # kernel `paged_latent_chunk_attention` (its query block,
+            # pages a grid step, VMEM limit) or the XLA key blocks; None
+            # without a latent layer. Static
+            "latent_prefill": self.latent_prefill,
             # tensor parallelism (inference/tp.py): shard count, tail
             # mode, and whether the per-token reduce rides int8
             "tp": self.tp,
@@ -2212,6 +2228,13 @@ class ContinuousBatchingEngine(LLMEngine):
             g.prefill_pairs += len(g.layers) * int(
                 seen.sum() if g.window is None
                 else np.minimum(seen, g.window).sum())
+            plan = self._latent_plans.get(g.index)
+            if plan is not None:
+                # grid steps of the latent chunk kernel that compute
+                self.latent_prefill_live_steps += len(g.layers) * \
+                    latent_live_steps(start, r.t0, chunk, self.page_size,
+                                      plan["tq"], plan["pages_per_step"],
+                                      g.window)
         self._group_release(r, end)
         last = end >= r.t0
         if last:

@@ -322,6 +322,52 @@ def test_window_group_frees_pages_behind_an_odd_window(model):
     assert h["page_groups"][1]["freed_behind_window"] > 0
 
 
+def test_the_key_blocks_serve_where_the_kernel_cannot_tile(model, served,
+                                                          monkeypatch):
+    """Where Mosaic cannot tile a shape, the XLA key blocks run a chunk's
+    attention (forced here): the fixture's prompts give the kernel's
+    tokens."""
+    from paddle_tpu.inference import latent
+    _, results, _ = served
+    monkeypatch.setattr(latent, "prefill_plan", lambda *a: None)
+    eng = ContinuousBatchingEngine(model, max_len=96, page_size=8,
+                                   max_batch=4, prefill_chunk=16,
+                                   prefix_cache=False)
+    assert {v["kernel"] for v in eng.health()["latent_prefill"].values()} \
+        == {"attend_key_blocks"}
+    rng = np.random.default_rng(5)
+    uids = [eng.add_request(rng.integers(0, 96, n), max_new_tokens=12)
+            for n in (7, 19, 42, 61)]
+    eng.drain()
+    for u, want in zip(uids, results.values()):
+        np.testing.assert_array_equal(eng.result(u), want)
+    assert eng.latent_prefill_live_steps == 0
+
+
+def test_the_chunk_kernel_is_named_and_its_live_steps_counted(model):
+    """`health()["latent_prefill"]` names the Pallas chunk kernel for both
+    geometries, and `latent.prefill_live_steps` counts what the host books
+    for a known prompt: 40 tokens in chunks of 16 over pages of 8."""
+    from paddle_tpu import profiler
+    eng = ContinuousBatchingEngine(model, max_len=96, page_size=8,
+                                   max_batch=1, prefill_chunk=16,
+                                   prefix_cache=False)
+    facts = eng.health()["latent_prefill"]
+    assert {k: (v["kernel"], v["tq"], v["pages_per_step"])
+            for k, v in facts.items()} == {
+        "full": ("paged_latent_chunk_attention", 16, 4),
+        "window": ("paged_latent_chunk_attention", 16, 5)}
+    eng.add_request(np.arange(40) % 96, max_new_tokens=1)
+    eng.drain()
+    eng.health()
+    # one query block a chunk. Full layers walk from page 0 to the page
+    # of the chunk's last position (1, 3, 4) four pages a step: 1 + 1 + 2
+    # steps. Window layers (13) cover the pages of a block's window in
+    # ONE step: 1 + 1 + 1. Two layers of each
+    assert profiler.counter_history("engine")[-1][1][
+        "latent.prefill_live_steps"] == 2 * 4 + 2 * 3
+
+
 def test_the_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer():
     """Four chips hold two experts each: the routed parts of all four plus
     the shared expert counted ONCE give the reference's uncut layer."""

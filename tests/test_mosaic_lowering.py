@@ -326,6 +326,134 @@ class TestParallelBlockKernelsLowering:
             _sds((b, mp), jnp.int32), _sds((b,), jnp.int32))
 
 
+class TestLatentChunkKernelLowering:
+    """The latent chunk kernel at the two layer geometries of the latent
+    sparse cell (dots3-note-prev): a full layer of 128 heads over rows of
+    640 (values the leading 512) masked by its selection, a window layer
+    of 64 heads over rows of 1,152 (values 1,024) behind a window of 513;
+    pages of 128, a chunk of 512, 144 table columns."""
+    GEOMETRY = {"full": (128, 640, 512, None, 9216),
+                "window": (64, 1152, 1024, 513, 388)}
+
+    @pytest.mark.parametrize("kind", ["full", "window"])
+    def test_chunk_attention_through_the_page_table(self, kind):
+        from paddle_tpu.inference.latent import selection_width
+        from paddle_tpu.ops.pallas.chunk_attention import (
+            latent_plan, paged_latent_chunk_attention)
+        h, row, rank, window, n_pages = self.GEOMETRY[kind]
+        chunk, p, mp = 512, 128, 144
+        width = None if window else selection_width(mp, p)
+        plan = latent_plan(chunk, h, row, rank, p, jnp.bfloat16, window,
+                           width)
+        assert plan is not None and plan["tq"] >= 16
+        args = [_sds((chunk, h, row), jnp.bfloat16),
+                _sds((n_pages, p, row), jnp.bfloat16),
+                _sds((mp,), jnp.int32), _sds((), jnp.int32),
+                _sds((), jnp.int32)]
+        if window is None:
+            _lower_tpu(lambda q, r, t, a, b, c: paged_latent_chunk_attention(
+                q, r, t, a, b, rank, 0.07, chosen=c), *args,
+                _sds((chunk, width), jnp.bool_))
+        else:
+            _lower_tpu(lambda q, r, t, a, b: paged_latent_chunk_attention(
+                q, r, t, a, b, rank, 0.06, window=window), *args)
+
+    def test_health_names_the_kernel_at_the_cells_geometries(self):
+        """`health()["latent_prefill"]` of the cell's engine, from its
+        configuration file's layers and the engine's shapes (no weights):
+        the kernel for both geometries, with its plan."""
+        import types
+        from paddle_tpu.inference import latent
+        from paddle_tpu.inference.description import ModelDescription
+        from paddle_tpu.inference.serving import PageGroup
+        cfg = _perf_config("dots3-note-prev-ep16-serve")
+        conf = _perf_reference(cfg["reference"]).model_config(cfg)
+        desc = ModelDescription(
+            hidden_size=conf.hidden_size, vocab_size=conf.vocab_size,
+            eps=conf.rms_norm_eps,
+            layers=tuple(conf.layer_spec(l)
+                         for l in range(conf.num_hidden_layers)))
+        e = cfg["serving"]["engine"]
+        p, chunk = e["page_size"], e["prefill_chunk"]
+        mp = e["max_len"] // p
+        groups = [PageGroup(gi, key, [li for li, g in enumerate(
+            desc.layer_group) if g == gi], p, e["max_batch"], mp, chunk,
+            plain=False) for gi, key in enumerate(desc.groups)]
+        eng = types.SimpleNamespace(
+            desc=desc, groups=groups, page_size=p, pages_per_seq=mp,
+            prefill_chunk=chunk, kv_dtype=jnp.bfloat16, interpret=False)
+        facts = latent.prefill_facts(eng, latent.prefill_plans(eng))
+        assert set(facts) == {"full", "window"}
+        for kind, fact in facts.items():
+            assert fact["kernel"] == "paged_latent_chunk_attention", kind
+            assert chunk % fact["tq"] == 0 and fact["pages_per_step"] >= 1
+            assert 16 << 20 <= fact["vmem_limit_bytes"] <= 100 << 20
+
+    def test_the_kernel_sits_under_attend_and_its_scope(self):
+        """Lowered for the chip from `latent.prefill_layer` itself (lane-
+        filling widths, the engine steered off interpret): the kernel's
+        custom call carries the `attend` phase and the `sparse_attend` /
+        `window_latent_attend` scope in its name stack, which is what
+        `prefill_attend_ms`, `latent_prefill_attn_share` and
+        `latent_prefill_chunk_ms` read it by."""
+        import re
+        from paddle_tpu.inference import ContinuousBatchingEngine, latent
+        from paddle_tpu.models import Dots3NoteConfig, Dots3NoteForCausalLM
+        from harness import phase_times
+        model = Dots3NoteForCausalLM(Dots3NoteConfig.tiny(
+            num_hidden_layers=2, layers_kept=[0, 2], num_attention_heads=8,
+            kv_lora_rank=128, swa_num_attention_heads=8,
+            swa_kv_lora_rank=128))
+        model.eval()
+        eng = ContinuousBatchingEngine(model, max_len=128, page_size=16,
+                                       max_batch=1, prefill_chunk=32,
+                                       prefix_cache=False)
+        eng.interpret = False
+        shape = (lambda a: _sds(a.shape, a.dtype)
+                 if hasattr(a, "shape") else a)
+        W = jax.tree_util.tree_map(shape, eng.weights)
+        for li, scope in ((0, "sparse_attend"), (1, "window_latent_attend")):
+            def layer(W, h, rows, ix, tab, pos, t_end, li=li):
+                return latent.prefill_layer(eng, W, W["layers"][li], h, rows,
+                                            ix, tab, pos, t_end, li)[0]
+            text = jax.jit(layer).trace(
+                W, _sds((1, 32, 64), jnp.float32), shape(eng.k_pages[li]),
+                shape(eng.v_pages[li]), _sds((8,), jnp.int32),
+                _sds((32,), jnp.int32), _sds((), jnp.int32)).lower(
+                    lowering_platforms=("tpu",)).as_text(debug_info=True)
+            locs = dict(re.findall(r"^(#loc\d+) = loc\((.*)\)$", text,
+                                   re.M))
+            calls = re.findall(r"tpu_custom_call.*loc\((#loc\d+)\)\s*$",
+                               text, re.M)
+            assert len(calls) == 1, (li, len(calls))
+            stack = re.match(r'"([^"]*)"', locs[calls[0]]).group(1)
+            assert f"/{scope}/paged_latent_chunk_attention" in stack, stack
+            assert phase_times.phase_of(stack) == "attend", stack
+
+
+def _perf_config(name):
+    import json
+    import os
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "perf", "configs", f"{name}.json")) as f:
+        return json.load(f)
+
+
+def _perf_reference(name):
+    import importlib.util
+    import os
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if os.path.join(root, "perf") not in sys.path:
+        sys.path.insert(0, os.path.join(root, "perf"))
+    spec = importlib.util.spec_from_file_location(
+        f"perf_reference_{name}",
+        os.path.join(root, "perf", "references", f"{name}.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 class TestDecodeMegakernelLowering:
     """decode_megakernel layer/multi x dense/int8 at a lane-aligned
     geometry (what megakernel_supported admits on a chip)."""

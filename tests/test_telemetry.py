@@ -230,6 +230,9 @@ ENGINE_HEALTH_KEYS = frozenset({
     # PR 37: what the paged decode attention kernel does a layer call,
     # static (None under the megakernel or where no layer calls it)
     "paged_decode",
+    # what a latent layer's prefill chunk runs, static (None without a
+    # latent layer)
+    "latent_prefill",
 })
 
 ROUTER_HEALTH_KEYS = frozenset({
@@ -590,7 +593,7 @@ LATENT_SPARSE_NAMES = (
     "sparse_index_scores", "sparse_select", "sparse_attend",
     "window_latent_attend", "sparse.keys_visible", "sparse.keys_attended",
     "sparse.index_keys_scored", "sparse.decode_queries", "row_width",
-    "index_width")
+    "index_width", "latent_prefill", "latent.prefill_live_steps")
 
 
 @pytest.mark.parametrize("name", LATENT_SPARSE_NAMES)
